@@ -751,8 +751,8 @@ def ratchet_status(root: Optional[str] = None,
                    baseline_path: str = DEFAULT_BASELINE
                    ) -> Dict[str, Any]:
     """Concurrency counterpart of ``lint.ratchet_status`` — DLT2xx
-    findings vs the shared baseline. Feeds ``bench.py``'s
-    ``concurrency_clean`` and the obs_report posture line."""
+    findings vs the shared baseline. Feeds the obs_report posture
+    line."""
     findings, n_files = lint_tree(root)
     baseline = _lint.load_baseline(baseline_path)
     new = _lint.new_findings(findings, baseline)

@@ -4,13 +4,16 @@ Drops into the data pipeline as a fast path: ``decode_jpeg`` replaces
 PIL for single images (datasets.load_image), ``decode_resize_batch``
 decodes+resizes a whole batch off the GIL with a C++ thread pool — the
 native input-path analog of the reference's cv2/torchvision decode
-underneath its DataLoaders. Falls back cleanly when g++/libjpeg are
-absent: ``available()`` gates every call site.
+underneath its DataLoaders. Where g++/libjpeg are absent the library
+cannot be built: ``available()`` gates every call site, and the first
+check says so in one warning line instead of switching to the Python
+decode path silently.
 """
 
 from __future__ import annotations
 
 import ctypes
+import warnings
 from typing import List, Optional
 
 import numpy as np
@@ -23,7 +26,13 @@ _CACHE = {"lib": False}  # False = not tried, None = unavailable
 def _lib():
     if _CACHE["lib"] is False:
         lib = load("imagedec")
-        if lib is not None:
+        if lib is None:
+            warnings.warn(
+                "native JPEG decoder (native/imagedec.cpp) could not be "
+                "built or loaded — is g++/libjpeg installed? Decoding "
+                "falls back to the Python (PIL) path.", RuntimeWarning,
+                stacklevel=3)
+        else:
             lib.decode_jpeg_info.restype = ctypes.c_int
             lib.decode_jpeg_info.argtypes = [
                 ctypes.c_char_p, ctypes.c_long,

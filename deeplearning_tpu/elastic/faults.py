@@ -24,7 +24,7 @@ Each spec fires at most once per process. Actions:
 - ``crash``: raise :class:`InjectedCrash` (a non-Preempted exception →
   non-75 exit → the supervisor counts a crash).
 - ``wedge``: block in ``time.sleep`` while the heartbeat writer thread
-  keeps the file fresh — exactly the wedged-device-tunnel signature
+  keeps the file fresh — exactly the wedged-device signature
   (process alive, loop stuck) the supervisor must classify and kill.
 
 The self-healing kinds (``nan``, ``bad_sample``, ``ckpt_corrupt``) are

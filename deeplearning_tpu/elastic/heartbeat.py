@@ -11,7 +11,7 @@ to distinguish *slow* (activity advancing, steps not) from *wedged*
 The writer thread keeps writing wall time even while the main thread is
 wedged — deliberately. File freshness proves the *process* is alive;
 only ``step``/``activity`` prove the *training loop* is. A supervisor
-keying on mtime alone would never catch a wedged tunnel.
+keying on mtime alone would never catch a wedged device.
 """
 
 from __future__ import annotations

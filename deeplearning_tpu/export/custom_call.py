@@ -27,16 +27,6 @@ _LOCK = threading.Lock()
 _REGISTERED = False
 
 
-def _ffi():
-    """``jax.ffi`` graduated from ``jax.extend.ffi`` after 0.4.x; the
-    two expose the same register/pycapsule/ffi_call surface."""
-    try:
-        import jax.ffi as ffi
-    except ImportError:
-        import jax.extend.ffi as ffi
-    return ffi
-
-
 def _build() -> Optional[str]:
     src = os.path.join(_DIR, "my_add.cc")
     out = os.path.join(_DIR, "libmy_add.so")
@@ -44,7 +34,7 @@ def _build() -> Optional[str]:
             os.path.getmtime(out) >= os.path.getmtime(src):
         return out
     cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-           f"-I{_ffi().include_dir()}", src, "-o", out]
+           f"-I{jax.ffi.include_dir()}", src, "-o", out]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=180)
         return out
@@ -64,9 +54,8 @@ def register() -> bool:
         if path is None:
             return False
         lib = ctypes.CDLL(path)
-        ffi = _ffi()
-        ffi.register_ffi_target(
-            "my_add", ffi.pycapsule(lib.MyAdd), platform="cpu")
+        jax.ffi.register_ffi_target(
+            "my_add", jax.ffi.pycapsule(lib.MyAdd), platform="cpu")
         _REGISTERED = True
         return True
 
@@ -75,6 +64,6 @@ def my_add(a: jax.Array, b: jax.Array) -> jax.Array:
     """3a + 2b via the native handler (my_add.cpp semantics)."""
     if not register():
         raise RuntimeError("no host toolchain to build the FFI demo")
-    call = _ffi().ffi_call(
+    call = jax.ffi.ffi_call(
         "my_add", jax.ShapeDtypeStruct(a.shape, jnp.float32))
     return call(a.astype(jnp.float32), b.astype(jnp.float32))

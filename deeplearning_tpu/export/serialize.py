@@ -18,7 +18,6 @@ import os
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
-import jax.export  # noqa: F401  (0.4.x: submodule not loaded by jax/__init__)
 import jax.numpy as jnp
 import numpy as np
 

@@ -15,7 +15,8 @@ ways:
   request path.
 
 Cost discipline (same budget as ``obs/spans.py``, enforced by the
-bench ``metrics_overhead`` A/B and by DLT100 coverage of this module):
+``tools/bench_util.metrics_overhead`` A/B and by DLT100 coverage of
+this module):
 - **Disabled** (the default): each helper is one module-pointer load
   plus an ``is None`` check — no lock, no allocation.
 - **Enabled**: a dict lookup and one O(1) add under the metric's own

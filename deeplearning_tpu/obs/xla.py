@@ -4,8 +4,8 @@ Every AOT lowering in the library (Trainer.precompile, the serving
 engine's bucket warmup, ``utils.profiling.compiled_flops``) funnels
 through ``tracked_compile``: the compile is timed, XLA's
 ``cost_analysis`` (FLOPs) and ``memory_analysis`` (peak HBM) are read
-off the executable, a persistent-cache hit/miss verdict is taken from
-the cache directory, and the event lands in three places at once — the
+off the executable, the persistent-cache hit/miss verdict is JAX's own
+(its monitoring events), and the event lands in three places at once — the
 bounded ``compile_events()`` ring (the ``/stats`` surface), the span
 timeline (a ``compile/<name>`` span with FLOPs/HBM args), and the
 flight recorder (so a crash dump shows what was compiled when).
@@ -30,8 +30,8 @@ from . import flight, metrics, spans
 from . import threads as obs_threads
 
 __all__ = ["tracked_compile", "compile_events", "compile_stats",
-           "memory_analysis_dict", "hbm_snapshot", "HbmWatermark",
-           "set_hbm_alert_frac"]
+           "jax_cache_counts", "memory_analysis_dict", "hbm_snapshot",
+           "HbmWatermark", "set_hbm_alert_frac"]
 
 # bounded ring of compile-event dicts (module-wide: compiles are rare
 # and the ring is the natural join point for /stats and obs_report)
@@ -68,18 +68,52 @@ def memory_analysis_dict(compiled) -> Dict[str, float]:
     return out
 
 
-def _cache_entries() -> Optional[int]:
-    """File count in the persistent compile cache (None when disabled)."""
-    import os
+# JAX's own verdicts on its persistent compilation cache. Its monitoring
+# events fire synchronously on the compiling thread, so process totals
+# serve ``jax_cache_counts`` and a per-thread hit count tells
+# ``tracked_compile`` about exactly its own compile. (Counting files in
+# the cache directory does not: with a size cap set the cache evicts, and
+# a 30 s compile on the chip was once reported as a hit.)
+_JAX_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "requests",
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+_jax_cache_totals = dict.fromkeys(_JAX_CACHE_EVENTS.values(), 0)
+_jax_cache_thread = threading.local()
+_jax_cache_listening = False
 
-    from ..core.compile_cache import active_cache_dir
-    cache_dir = active_cache_dir()
-    if not cache_dir or not os.path.isdir(cache_dir):
-        return None
-    try:
-        return len(os.listdir(cache_dir))
-    except OSError:
-        return None
+
+def _on_jax_event(event: str, **_) -> None:
+    key = _JAX_CACHE_EVENTS.get(event)
+    if key is None:
+        return
+    with _EVENTS_LOCK:
+        _jax_cache_totals[key] += 1
+    if key == "hits":
+        _jax_cache_thread.hits = _thread_cache_hits() + 1
+
+
+def _thread_cache_hits() -> int:
+    return getattr(_jax_cache_thread, "hits", 0)
+
+
+def _listen_to_jax_cache() -> None:
+    global _jax_cache_listening
+    with _EVENTS_LOCK:
+        if _jax_cache_listening:
+            return
+        _jax_cache_listening = True
+    import jax.monitoring
+    jax.monitoring.register_event_listener(_on_jax_event)
+
+
+def jax_cache_counts() -> Dict[str, int]:
+    """Process totals of {requests, hits, misses} as JAX reports them,
+    counted from the first call of this or of ``tracked_compile``."""
+    _listen_to_jax_cache()
+    with _EVENTS_LOCK:
+        return dict(_jax_cache_totals)
 
 
 def tracked_compile(lowered, name: str):
@@ -87,7 +121,9 @@ def tracked_compile(lowered, name: str):
     records {fn, seconds, flops, peak_hbm_bytes, cache_hit} everywhere
     the observability stack looks. Never raises past the compile itself
     — a telemetry failure must not fail a warmup path."""
-    before = _cache_entries()
+    from ..core.compile_cache import active_cache_dir
+    _listen_to_jax_cache()
+    hits_before = _thread_cache_hits()
     t0 = time.perf_counter()
     compiled = lowered.compile()
     seconds = time.perf_counter() - t0
@@ -95,11 +131,8 @@ def tracked_compile(lowered, name: str):
         from ..utils.profiling import cost_analysis_dict
         cost = cost_analysis_dict(compiled)
         mem = memory_analysis_dict(compiled)
-        after = _cache_entries()
-        # no new cache entry materialized -> the persistent cache (or
-        # jit's in-memory executable cache) served this lowering
-        cache_hit = (None if before is None or after is None
-                     else after <= before)
+        cache_hit = (None if active_cache_dir() is None
+                     else _thread_cache_hits() > hits_before)
         event = {
             "fn": name,
             "seconds": round(seconds, 4),

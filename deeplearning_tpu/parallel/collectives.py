@@ -92,8 +92,7 @@ def quantized_psum(x: jax.Array, axis_name: Any = (DATA_AXIS, FSDP_AXIS),
     (like ``jax.lax.psum``). Exact when per-replica values and their sums
     are integers within [-127, 127]; otherwise relative error is bounded
     by ~2/127 per block (two quantization stages)."""
-    from ._compat import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     flat = x.astype(jnp.float32).reshape(-1)
     size = flat.shape[0]
     flat, _ = _pad_to(flat, n * block)
@@ -124,8 +123,7 @@ def quantized_reduce_scatter(x: jax.Array,
     leaves here when their zero1 spec shards dim 0. Skips the second
     quantization stage entirely (the scattered shard never rides the
     wire again), so only one stage of error applies."""
-    from ._compat import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if x.shape[0] % n != 0:
         raise ValueError(
             f"quantized_reduce_scatter needs dim0 % {n} == 0, "

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._compat import SHARD_MAP_NATIVE, shard_map
-
 PIPE_AXIS = "model"     # reuse the model axis for stages by default
 
 
@@ -59,41 +57,20 @@ def _pipeline_schedule(
             f"({s}): the (M,...) input is sharded P({axis_name!r}) for "
             "storage, so a non-multiple silently truncates outputs")
 
-    # On 0.4.x JAX, a traced-intermediate operand whose in_spec shards one
-    # axis of a multi-axis mesh while leaving another unmentioned reaches
-    # the shard_map body summed over the unmentioned axis (partitioner
-    # bug at the jit->manual boundary, observed on 0.4.37; fully
-    # replicated P() operands arrive intact). So on legacy JAX every
-    # operand enters replicated and the body slices out its own stage;
-    # on modern JAX params/microbatches enter sharded as designed.
-    if SHARD_MAP_NATIVE:
-        param_specs = jax.tree.map(lambda _: P(axis_name), stage_params)
-        x_spec = P(axis_name)
-    else:
-        param_specs = jax.tree.map(lambda _: P(), stage_params)
-        x_spec = P()
+    param_specs = jax.tree.map(lambda _: P(axis_name), stage_params)
 
     @functools.partial(
-        shard_map, mesh=mesh,
-        in_specs=(param_specs, x_spec),
-        out_specs=P(axis_name), check_vma=SHARD_MAP_NATIVE)
+        jax.shard_map, mesh=mesh,
+        in_specs=(param_specs, P(axis_name)),
+        out_specs=P(axis_name))
     def run(params, xs):
         idx = jax.lax.axis_index(axis_name)
-        if SHARD_MAP_NATIVE:
-            # params: leaves (1, ...) — this device's stage; xs
-            # (ceil(M/S), ...) microbatches sharded over the axis for
-            # storage; gather to a local queue (M is small; activations
-            # are microbatch-sized)
-            params = jax.tree.map(lambda p: p[0], params)
-            all_x = jax.lax.all_gather(xs, axis_name, tiled=True)
-        else:
-            # legacy path: everything arrived replicated; slice this
-            # device's stage (S x params resident per device — the
-            # workaround's cost)
-            params = jax.tree.map(
-                lambda p: jax.lax.dynamic_index_in_dim(
-                    p, idx, 0, keepdims=False), params)
-            all_x = xs
+        # params: leaves (1, ...) — this device's stage; xs
+        # (ceil(M/S), ...) microbatches sharded over the axis for
+        # storage; gather to a local queue (M is small; activations
+        # are microbatch-sized)
+        params = jax.tree.map(lambda p: p[0], params)
+        all_x = jax.lax.all_gather(xs, axis_name, tiled=True)
         n_ticks = s + m - 1
         perm = [(i, (i + 1) % s) for i in range(s)]
 
@@ -109,9 +86,7 @@ def _pipeline_schedule(
             is_last = idx == s - 1
             valid = (out_slot >= 0) & (out_slot < m) & is_last
             # select, not lax.cond: both arms always run (the update is
-            # microbatch-sized, so this costs nothing) and the replication
-            # checker tracks plain selects on every JAX release, whereas
-            # 0.4.x's pre-vma checker rejects device-varying cond here
+            # microbatch-sized, so this costs nothing)
             updated = jax.lax.dynamic_update_index_in_dim(
                 outputs, y, jnp.maximum(out_slot, 0), 0)
             outputs = jnp.where(valid, updated, outputs)

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._compat import axis_size as _axis_size
 from .mesh import SEQ_AXIS
 
 
@@ -111,7 +110,7 @@ def _ring_forward(q, k, v, axis_name, sm_scale, use_flash,
                   kv_mask=None):
     """Ring forward; returns (out, global_lse). ``kv_mask`` (Nlocal,)
     bool rotates around the ring with its KV chunk (lax path only)."""
-    axis_size = _axis_size(axis_name)
+    axis_size = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
 
     def chunk_stats(q, kk, vv, mm):
@@ -163,7 +162,7 @@ def _ring_flash_bwd(axis_name, sm_scale, res, dout):
     from ..ops.pallas.flash_attention import flash_chunk_grads
 
     q, k, v, out, lse = res
-    axis_size = _axis_size(axis_name)
+    axis_size = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
@@ -194,14 +193,13 @@ def make_ring_attention(mesh: Mesh, axis_name: str = SEQ_AXIS,
     """shard_map-wrapped ring attention over a live mesh: takes globally
     sharded (B, H, N, D) arrays (sequence dim sharded over ``axis_name``)
     and returns the same sharding."""
-    from ._compat import shard_map
 
     spec = P(None, None, axis_name, None)
 
     # pallas_call out_shapes carry no varying-mesh-axes info, so the
     # flash-backed path needs shard_map's vma check off
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec, check_vma=not use_flash)
     def fn(q, k, v):
@@ -224,7 +222,6 @@ def make_ring_attn_fn(mesh: Mesh, axis_name: str = SEQ_AXIS,
     so inputs are zero-padded to a multiple and a KV validity mask rides
     the ring with its chunk (lax path). ``use_flash=True`` requires the
     unpadded length to divide the axis exactly."""
-    from ._compat import shard_map
 
     from ._seq_adapter import batch_axes, seq_attn_adapter
 
@@ -239,7 +236,7 @@ def make_ring_attn_fn(mesh: Mesh, axis_name: str = SEQ_AXIS,
                      None)
 
             @functools.partial(
-                shard_map, mesh=mesh,
+                jax.shard_map, mesh=mesh,
                 in_specs=(spec, spec, spec, P(axis_name)),
                 out_specs=spec, check_vma=not use_flash)
             def ring(q, k, v, mask):

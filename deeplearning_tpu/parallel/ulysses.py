@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._compat import axis_size as _axis_size
 from .mesh import SEQ_AXIS
 
 
@@ -52,7 +51,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     long-context blocks). If it accepts an ``sm_scale`` keyword the
     scale is forwarded; plain ``attn_fn(q, k, v)`` callables are allowed
     only with the default scale."""
-    p_size = _axis_size(axis_name)
+    p_size = jax.lax.axis_size(axis_name)
     b, h, nl, d = q.shape
     if h % p_size:
         raise ValueError(f"heads={h} must divide over axis size {p_size}")
@@ -105,12 +104,11 @@ def make_ulysses_attention(mesh: Mesh, axis_name: str = SEQ_AXIS,
     (B, H, N, D) arrays (sequence sharded over ``axis_name``) and returns
     the same sharding. Set check_vma=False when attn_fn is a pallas_call
     (its out_shapes carry no varying-mesh-axes info)."""
-    from ._compat import shard_map
 
     spec = P(None, None, axis_name, None)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec, check_vma=check_vma)
     def fn(q, k, v):
         return ulysses_attention(q, k, v, axis_name, attn_fn=attn_fn)
@@ -127,7 +125,6 @@ def make_ulysses_attn_fn(mesh: Mesh, axis_name: str = SEQ_AXIS,
     sequence's tail, so the inner attention masks it with a static
     bound. ``use_flash=True`` runs each head block through the Pallas
     flash kernel and requires N to divide the axis exactly."""
-    from ._compat import shard_map
 
     from ._seq_adapter import batch_axes, seq_attn_adapter
 
@@ -149,7 +146,7 @@ def make_ulysses_attn_fn(mesh: Mesh, axis_name: str = SEQ_AXIS,
             spec = P(b_axes if sharded else None, None, axis_name, None)
 
             @functools.partial(
-                shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+                jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
                 out_specs=spec, check_vma=not use_flash)
             def fn(q, k, v):
                 return ulysses_attention(q, k, v, axis_name,

@@ -27,7 +27,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core import rng as rng_mod
 from ..obs import flight
 from ..parallel import collectives
-from ..parallel._compat import shard_map
 from ..parallel.mesh import DATA_AXIS, FSDP_AXIS
 from ..parallel.sharding import (batch_spec, opt_state_shardings,
                                  shard_params_tree, zero1_partition_spec,
@@ -266,7 +265,7 @@ def _int8_value_and_grad(loss_fn, state, batch, rng, mesh, zero1, block):
     # params/opt_state/ema are stripped so shard_map only threads the
     # leaves the loss actually reads (step, batch_stats)
     slim = state.replace(params=None, opt_state=None, ema_params=None)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local_grad, mesh=mesh,
         in_specs=(P(), P(), batch_spec(), P()),
         out_specs=((P(), P()), g_out_specs),

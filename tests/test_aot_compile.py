@@ -1,0 +1,107 @@
+"""The main path's Pallas kernels compile for the real chip — without one.
+
+libtpu's compiler is installed wherever JAX's TPU wheel is, and compiles
+for a chip that is described, not attached (on-chip-measurement guide,
+§2.3). Interpret-mode tests cannot see what Mosaic refuses (unaligned
+slices, too much VMEM, an unsupported op); these cases can, for a second
+or two each and no chip time. Nothing runs, so they say nothing about
+results or speed — ``chip_smoke.py`` does that on the chip.
+
+``interpret_mode`` is patched to ``False`` here, in the test: the kernels
+pick interpret mode from the backend, and this process's backend is the
+CPU. The persistent compile cache is off around the compiles (an entry
+written for a described chip cannot be read back without one).
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning_tpu.ops.pallas import flash_attention as flash
+from deeplearning_tpu.ops.pallas import nms as pallas_nms
+from deeplearning_tpu.ops.pallas import window_attention as window
+
+TOPOLOGY = "v5e:2x2"
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one described v5e chip; skips where libtpu cannot
+    describe the topology (not installed, or its lock file is held by
+    another process)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name=TOPOLOGY)
+    except Exception as exc:  # noqa: BLE001 - any refusal means skip
+        pytest.skip(f"cannot describe a {TOPOLOGY} topology here: {exc}")
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compiled_mode(monkeypatch):
+    for module in (flash, pallas_nms, window):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+
+
+def _window_case(windows, heads, n_mask, dtype):
+    return (window.window_attention,
+            [((windows, 49, 3, heads, 32), dtype),
+             ((heads, 49, 49), jnp.float32)]
+            + ([((n_mask, 49, 49), jnp.float32)] if n_mask else []))
+
+
+def _flash_grad(q, k, v):
+    return jax.grad(lambda *qkv: jnp.sum(
+        flash.flash_attention(*qkv).astype(jnp.float32)), (0, 1, 2))(q, k, v)
+
+
+_NMS = functools.partial(pallas_nms.nms_pallas, iou_threshold=0.5,
+                         max_out=100)
+_VIT_QKV = [((8, 12, 197, 64), jnp.bfloat16)] * 3
+_LONG_QKV = [((2, 12, 1024, 64), jnp.bfloat16)] * 3
+
+CASES = {
+    # Swin-T stage 1 at batch 32: 56x56 tokens, 3 heads of 32
+    "window_swin_t_s1_bf16_masked": _window_case(2048, 3, 64, jnp.bfloat16),
+    "window_swin_t_s1_bf16": _window_case(2048, 3, 0, jnp.bfloat16),
+    "window_swin_t_s1_f32": _window_case(2048, 3, 0, jnp.float32),
+    # Swin-B stage 3 at batch 32: 14x14 tokens, 16 heads of 32
+    "window_swin_b_s3_bf16_masked": _window_case(128, 16, 4, jnp.bfloat16),
+    "nms_pallas_n1024": (_NMS, [((1024, 4), jnp.float32),
+                                ((1024,), jnp.float32)]),
+    "nms_pallas_n4096": (_NMS, [((4096, 4), jnp.float32),
+                                ((4096,), jnp.float32)]),
+    # ViT-B/16's attention shape: 197 tokens, 12 heads of 64
+    "flash_fwd_n197": (flash.flash_attention, _VIT_QKV),
+    "flash_grad_n197": (_flash_grad, _VIT_QKV),
+    # 1,024 tokens: whole 128-wide blocks, no padded keys
+    "flash_fwd_n1024": (flash.flash_attention, _LONG_QKV),
+    "flash_grad_n1024": (_flash_grad, _LONG_QKV),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, chip, compiled_mode):
+    fn, shapes = CASES[name]
+    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+             for shape, dtype in shapes]
+    # conftest pins matmul precision to "highest" for the CPU suite; the
+    # chip runs the kernels at the default, and Mosaic refuses an fp32
+    # contraction of bf16 operands
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn).lower(*specs).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        f"{name}: the compiled program holds no Mosaic kernel")

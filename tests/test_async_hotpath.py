@@ -1,6 +1,7 @@
 """Sync-free hot path: DeferredMetrics staleness, device-side divergence
 guard, zero-sync eval, retrace guard, and the persistent compile cache."""
 
+import os
 import time
 import warnings
 from collections import defaultdict
@@ -324,15 +325,25 @@ class TestRetraceGuard:
 
 
 class TestCompileCache:
-    def test_enable_points_jax_at_dir(self, tmp_path, monkeypatch):
+    def test_cache_dir_env_wins_else_checkout(self, tmp_path, monkeypatch):
         import deeplearning_tpu.core.compile_cache as cc
+        monkeypatch.delenv("DLTPU_COMPILE_CACHE", raising=False)
+        # unset: the fixed <checkout>/.jax_cache, and idempotent
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         monkeypatch.setattr(cc, "_enabled_dir", None)
+        fixed = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache")
+        assert cc.enable_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert cc.enable_compile_cache() == fixed
+        # JAX_COMPILATION_CACHE_DIR set: JAX owns its flag (it read the
+        # variable at import) and the library leaves it alone
         target = str(tmp_path / "cache")
-        assert cc.enable_compile_cache(target) == target
-        assert jax.config.jax_compilation_cache_dir == target
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", target)
+        monkeypatch.setattr(cc, "_enabled_dir", None)
+        assert cc.enable_compile_cache() == target
         assert cc.active_cache_dir() == target
-        # idempotent
-        assert cc.enable_compile_cache(target) == target
+        assert jax.config.jax_compilation_cache_dir == fixed
 
     def test_env_disable(self, monkeypatch):
         import deeplearning_tpu.core.compile_cache as cc

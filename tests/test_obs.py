@@ -610,8 +610,8 @@ class TestObsReportCheck:
 
 class TestObsOverheadHelper:
     def test_ab_helper_reports_and_restores_tracer_state(self):
-        """Structural check of the bench obs-overhead row (the <2%
-        assertion itself runs in bench.py where timing is meaningful)."""
+        """Structural check of the obs-overhead A/B row (the <2% budget
+        itself is a chip measurement: ``perf_sweep.py --set obs``)."""
         from bench_util import obs_overhead
         fn = jax.jit(lambda x: (x @ x).sum())
         x = jnp.ones((64, 64), jnp.float32)

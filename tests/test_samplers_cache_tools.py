@@ -14,7 +14,7 @@ from deeplearning_tpu.data.samplers import (aspect_ratio_groups,
 from deeplearning_tpu.data.zip_cache import MemmapCache, ZipImageSource
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ENV = dict(os.environ, DLTPU_PLATFORM="cpu")
+ENV = dict(os.environ, JAX_PLATFORMS="cpu")
 
 
 class TestSamplers:

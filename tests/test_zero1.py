@@ -17,7 +17,6 @@ import pytest
 
 from deeplearning_tpu.core.registry import MODELS
 from deeplearning_tpu.parallel import MeshConfig, build_mesh
-from deeplearning_tpu.parallel._compat import shard_map
 from deeplearning_tpu.parallel.collectives import (
     quantized_psum, quantized_psum_tree, quantized_reduce_scatter)
 from deeplearning_tpu.parallel.sharding import (
@@ -198,7 +197,7 @@ class TestQuantizedCollectives:
         g = np.random.default_rng(0)
         vals = jnp.asarray(g.integers(-7, 8, (n, 96)), jnp.float32)
 
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda x: (quantized_psum(x[0], AXES, block=16),
                        jax.lax.psum(x[0], AXES)),
             mesh=mesh, in_specs=(P(AXES),), out_specs=(P(), P()),
@@ -216,7 +215,7 @@ class TestQuantizedCollectives:
         tree = {"a": jnp.asarray(g.normal(size=(n, 4096)), jnp.float32),
                 "b": jnp.asarray(g.normal(size=(n, 33, 7)), jnp.float32)}
 
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda t: (quantized_psum_tree(
                            jax.tree.map(lambda x: x[0], t), AXES),
                        jax.tree.map(lambda x: jax.lax.psum(x[0], AXES), t)),
@@ -239,7 +238,7 @@ class TestQuantizedCollectives:
         g = np.random.default_rng(2)
         vals = jnp.asarray(g.integers(-5, 6, (n, 2 * n, 5)), jnp.float32)
 
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda x: (quantized_reduce_scatter(x[0], AXES, block=16),
                        jax.lax.psum(x[0], AXES)),
             mesh=mesh, in_specs=(P(AXES),),
@@ -252,7 +251,7 @@ class TestQuantizedCollectives:
         mesh = self._mesh()
         n = mesh.shape[DATA_AXIS] * mesh.shape[FSDP_AXIS]
         vals = jnp.ones((n, n + 1, 3), jnp.float32)
-        f = shard_map(
+        f = jax.shard_map(
             lambda x: quantized_reduce_scatter(x[0], AXES),
             mesh=mesh, in_specs=(P(AXES),), out_specs=P(AXES),
             check_vma=False)

@@ -26,14 +26,8 @@ import numpy as np
 
 import bench_util  # noqa: F401  (side effect: persistent compile cache)
 
-
-def peak_flops(device) -> float:
-    kind = getattr(device, "device_kind", "").lower()
-    for key, val in {"v6": 918e12, "v5p": 459e12, "v5": 197e12,
-                     "v4": 275e12, "v3": 123e12, "v2": 45e12}.items():
-        if key in kind:
-            return val
-    return 197e12
+from deeplearning_tpu.utils.profiling import (cost_analysis_dict,
+                                              device_peak_flops)
 
 
 def bf16_softmax_attention(q, k, v, dropout_rate=0.0, deterministic=True,
@@ -80,6 +74,7 @@ def patch_embed_as_conv():
 def time_variant(name, batch, attn_fn=None, remat=False, n_steps=20,
                  model_name="vit_base_patch16_224", image_size=224,
                  results_path=None):
+    peak = device_peak_flops()       # an unknown device raises up front
     from deeplearning_tpu.core.registry import MODELS
     from deeplearning_tpu.train import TrainState, make_train_step
     from deeplearning_tpu.train.classification import make_loss_fn
@@ -113,28 +108,25 @@ def time_variant(name, batch, attn_fn=None, remat=False, n_steps=20,
     compiled = jax.jit(lambda s, b, r: step(s, b, r),
                        donate_argnums=(0,)).lower(state, data,
                                                   rng).compile()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older JAX: list of dicts
-        cost = cost[0] if cost else {}
-    step_flops = float(cost.get("flops", 0.0)) if cost else 0.0
+    step_flops = float(cost_analysis_dict(compiled).get("flops", 0.0))
 
     # drive the ALREADY-compiled executable (re-calling step would pay a
     # second identical XLA compile, minutes on TPU)
     state, metrics = compiled(state, data, rng)
-    float(metrics["loss"])  # D2H sync (block_until_ready unreliable here)
+    jax.block_until_ready(metrics)
     t0 = time.perf_counter()
     for _ in range(n_steps):
         state, metrics = compiled(state, data, rng)
-    float(metrics["loss"])
+    jax.block_until_ready(metrics)
     dt = (time.perf_counter() - t0) / n_steps
-    mfu = step_flops / dt / peak_flops(jax.devices()[0]) * 100.0
+    mfu = step_flops / dt / peak * 100.0
     # per-step-synced tail stats: the pipelined mean above hides stalls
-    # (a wedged iteration, host jitter); p50/p90 make regressions visible
+    # (a stalled iteration, host jitter); p50/p90 make regressions visible
     per_step = []
     for _ in range(min(n_steps, 10)):
         t1 = time.perf_counter()
         state, metrics = compiled(state, data, rng)
-        float(metrics["loss"])
+        jax.block_until_ready(metrics)
         per_step.append(time.perf_counter() - t1)
     p50, p90 = np.percentile(per_step, [50, 90])
     print(f"{name:40s} batch={batch:4d} step={dt * 1e3:8.2f}ms "
@@ -164,6 +156,7 @@ def time_feed_variant(name, batch, n_steps=20, depth=2,
     import numpy as np
 
     from bench_util import feed_stats
+    peak = device_peak_flops()       # an unknown device raises up front
     from deeplearning_tpu.core.registry import MODELS
     from deeplearning_tpu.data import ArraySource, DataLoader
     from deeplearning_tpu.train import TrainState, make_train_step
@@ -171,7 +164,6 @@ def time_feed_variant(name, batch, n_steps=20, depth=2,
     from deeplearning_tpu.train.optim import build_optimizer
     from deeplearning_tpu.train.schedules import build_schedule
     from deeplearning_tpu.train.trainer import Trainer
-    from deeplearning_tpu.utils.profiling import cost_analysis_dict
 
     model = MODELS.build(model_name, num_classes=1000)
     variables = model.init(jax.random.key(0),
@@ -206,8 +198,7 @@ def time_feed_variant(name, batch, n_steps=20, depth=2,
     ips = trainer.throughput(n_iters=n_steps)
     stats = trainer.throughput_stats
     dt = stats["step_ms_mean"] / 1e3
-    mfu = flops / dt / peak_flops(jax.devices()[0]) * 100.0 if flops \
-        else 0.0
+    mfu = flops / dt / peak * 100.0 if flops else 0.0
     feed = feed_stats(stats)
     print(f"{name:40s} batch={batch:4d} step={dt * 1e3:8.2f}ms "
           f"img/s={ips:8.1f} mfu={mfu:6.2f}% "

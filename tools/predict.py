@@ -26,10 +26,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 import jax
-
-if os.environ.get("DLTPU_PLATFORM"):
-    jax.config.update("jax_platforms", os.environ["DLTPU_PLATFORM"])
-
 import numpy as np
 
 

@@ -35,10 +35,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 import jax
-
-if os.environ.get("DLTPU_PLATFORM"):
-    jax.config.update("jax_platforms", os.environ["DLTPU_PLATFORM"])
-
 import numpy as np
 
 
@@ -481,7 +477,7 @@ def build_zoo(spec: dict, args):
     return zoo
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--model", default=None,
                     help="single-model mode: architecture to serve")
@@ -519,6 +515,35 @@ def main(argv=None) -> int:
     ap.add_argument("--wedge-deadline-s", type=float, default=30.0,
                     help="healthz reports wedged after this many seconds "
                          "of queued-but-frozen dispatch")
+    return ap
+
+
+def build_engine(args):
+    """Single-model mode's warmed ``InferenceEngine`` from parsed CLI
+    args (every bucket AOT-compiled before this returns)."""
+    from deeplearning_tpu.serve import InferenceEngine
+    return InferenceEngine(
+        args.model, num_classes=args.num_classes, ckpt=args.ckpt,
+        image_size=args.size,
+        batch_buckets=tuple(int(b) for b in args.buckets.split(",")),
+        tta=args.tta, score_thresh=args.score, max_det=args.max_det,
+        nms_impl=args.nms_impl)
+
+
+def build_batcher(args, engine=None, zoo=None, heartbeat=None):
+    """The request front door every serve path shares (not started:
+    use it as a context manager)."""
+    from deeplearning_tpu.serve import MicroBatcher
+    return MicroBatcher(engine, zoo=zoo,
+                        max_wait_ms=args.max_wait_ms,
+                        max_queue=args.max_queue,
+                        default_timeout_s=args.timeout_s,
+                        heartbeat=heartbeat,
+                        standby=os.environ.get("DLTPU_STANDBY") == "1")
+
+
+def main(argv=None) -> int:
+    ap = build_parser()
     args = ap.parse_args(argv)
     if (args.model is None) == (args.zoo is None):
         ap.error("pass exactly one of --model or --zoo")
@@ -528,7 +553,6 @@ def main(argv=None) -> int:
     from deeplearning_tpu.analysis import strict as strict_mod
     from deeplearning_tpu.elastic import heartbeat as hb
     from deeplearning_tpu.obs import spans
-    from deeplearning_tpu.serve import InferenceEngine, MicroBatcher
 
     # DLTPU_STRICT=threads: instrument the fleet's locks BEFORE the
     # zoo/batcher/heartbeat objects below create them
@@ -551,12 +575,7 @@ def main(argv=None) -> int:
               flush=True)
         task, size = "classify", 0     # resolved per model per request
     else:
-        engine = InferenceEngine(
-            args.model, num_classes=args.num_classes, ckpt=args.ckpt,
-            image_size=args.size,
-            batch_buckets=tuple(int(b) for b in args.buckets.split(",")),
-            tta=args.tta, score_thresh=args.score, max_det=args.max_det,
-            nms_impl=args.nms_impl)
+        engine = build_engine(args)
         print(json.dumps({"ready": engine.stats()}), file=sys.stderr,
               flush=True)
         task, size = engine.task, args.size
@@ -575,13 +594,7 @@ def main(argv=None) -> int:
         beat = hb.Heartbeat()
         writer = hb.HeartbeatWriter(beat_path, beat).start()
     try:
-        with MicroBatcher(engine, zoo=zoo,
-                          max_wait_ms=args.max_wait_ms,
-                          max_queue=args.max_queue,
-                          default_timeout_s=args.timeout_s,
-                          heartbeat=beat,
-                          standby=os.environ.get("DLTPU_STANDBY")
-                          == "1") as batcher:
+        with build_batcher(args, engine, zoo, beat) as batcher:
             if args.http is not None:
                 server = serve_http(batcher, task, size,
                                     names, args.topk, args.timeout_s,

@@ -2,7 +2,7 @@
 """Detection training CLI: RetinaNet / YOLOX / FCOS with COCO evaluation.
 
   python tools/train_detection.py [--cfg FILE] [key value ...]
-  DLTPU_PLATFORM=cpu python tools/train_detection.py train.steps=60
+  JAX_PLATFORMS=cpu python tools/train_detection.py train.steps=60
   ... model.name=yolox_s train.multiscale=true   # bucketed random_resize
 
 The detection successor of the per-project train entries
@@ -28,10 +28,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 import jax
-
-if os.environ.get("DLTPU_PLATFORM"):
-    jax.config.update("jax_platforms", os.environ["DLTPU_PLATFORM"])
-
 import jax.numpy as jnp
 import numpy as np
 
