@@ -22,6 +22,10 @@ harnesses, this package provides ONE shared TPU-first core:
                 jaxpr structural auditor, runtime strict mode.
 """
 
+import time as _time
+
+_T0 = _time.perf_counter()    # start of the "setup/import" phase, below
+
 __version__ = "0.1.0"
 
 # Importing the subpackages populates the registries (models, optimizers,
@@ -29,3 +33,10 @@ __version__ = "0.1.0"
 # a bare `import deeplearning_tpu`.
 from . import core, ops, parallel, data, train, models, evaluation  # noqa: E402,F401
 from . import analysis  # noqa: E402,F401  (lint is stdlib-only; jaxpr/strict lazy)
+
+# The package's own import cost (every model family, flax, optax, orbax),
+# as a run-level phase: `obs.spans.phases()` and the benchmark's
+# `program_import_s` read it. JAX itself may have been imported before.
+from .obs import spans as _spans  # noqa: E402
+
+_spans.record_phase("setup/import", _T0)

@@ -25,10 +25,20 @@ Telemetry (feeds Trainer ``data_time``/``throughput_stats``):
   device arrays (the cost the pipeline hides).
 - ``occupancy_mean`` / ``stats()``: queue depth observed at each get —
   near ``depth`` means the feed keeps up, near 0 means input-bound.
+  ``stats()`` and ``reset_stats()`` mean "this epoch" (the Trainer resets
+  at every epoch end); ``totals()`` never resets.
+
+Timeline (when the span ring is on): the worker numbers its batches and
+records ``feed/decode``, ``feed/h2d`` and ``feed/put_wait`` (blocked on a
+full queue) with ``batch=<n>`` — the three tile its loop — and
+``last_batch`` is the number of the batch the consumer last received, which
+the Trainer puts on its ``data_wait`` span: one identifier from decode to
+the step that used the batch.
 """
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time
@@ -42,6 +52,9 @@ from ..obs import threads as obs_threads
 from ..parallel.sharding import make_global_array
 
 _END = object()          # producer exhausted its epoch normally
+# the per-epoch counters that ``totals()`` carries across ``reset_stats()``
+_COUNTERS = ("data_wait_total", "h2d_wait_total", "source_wait_total",
+             "batches_fed")
 
 
 class _WorkerError:
@@ -94,6 +107,11 @@ class DevicePrefetcher:
         self.batches_fed = 0
         self._occ_sum = 0
         self._occ_n = 0
+        self._carried = dict.fromkeys(_COUNTERS, 0.0)   # of epochs reset
+        # one number per batch for the whole run (across epochs and
+        # restarted pipelines), given by the worker, read by the consumer
+        self._batch_ids = itertools.count()
+        self.last_batch: Optional[int] = None
         self._active: Optional[Dict[str, Any]] = None   # started pipeline
 
     # ------------------------------------------------- loader protocol
@@ -121,9 +139,24 @@ class DevicePrefetcher:
         fn = getattr(self.loader, "reseed", None)
         if fn is not None:
             fn(salt)
+        self._drop_started()
+
+    def _drop_started(self) -> None:
         if self._active is not None:
             self._shutdown(self._active)
             self._active = None
+
+    @property
+    def infinite(self) -> bool:
+        """The wrapped loader's endless mode (one epoch cycles the set,
+        reshuffled every pass). Setting it drops a pipeline that was
+        already started, whose batches came from the other mode."""
+        return bool(getattr(self.loader, "infinite", False))
+
+    @infinite.setter
+    def infinite(self, value: bool) -> None:
+        self.loader.infinite = bool(value)
+        self._drop_started()
 
     @property
     def quarantine(self):
@@ -157,19 +190,24 @@ class DevicePrefetcher:
                 t2 = time.perf_counter()
                 self.source_wait_total += t1 - t0
                 self.h2d_wait_total += t2 - t1
+                n = next(self._batch_ids)
                 # trace lanes from the worker thread — reuses the clock
                 # reads above, so the disabled path costs one None check
                 tracer = spans.get_tracer()
                 if tracer is not None:
-                    tracer.record("feed/decode", t0, t1 - t0)
-                    tracer.record("feed/h2d", t1, t2 - t1)
+                    tracer.record("feed/decode", t0, t1 - t0, {"batch": n})
+                    tracer.record("feed/h2d", t1, t2 - t1, {"batch": n})
                 # bounded put that stays responsive to shutdown
                 while not stop.is_set():
                     try:
-                        q.put(batch, timeout=0.1)
+                        q.put((n, batch), timeout=0.1)
                         break
                     except queue.Full:
                         continue
+                if tracer is not None:
+                    # blocked on a full queue: completes the worker's lane
+                    tracer.record("feed/put_wait", t2,
+                                  time.perf_counter() - t2, {"batch": n})
             if not stop.is_set():
                 q.put(_END)
         except BaseException as exc:  # noqa: BLE001 - relayed to consumer
@@ -230,7 +268,8 @@ class DevicePrefetcher:
                 self._occ_sum += q.qsize()
                 self._occ_n += 1
                 self.batches_fed += 1
-                yield item
+                self.last_batch, batch = item
+                yield batch
         finally:
             self._shutdown(pipe)
 
@@ -255,7 +294,14 @@ class DevicePrefetcher:
             out["quarantined"] = float(self.quarantine.quarantined)
         return out
 
+    def totals(self) -> Dict[str, float]:
+        """The counters over the whole run: what ``reset_stats()`` has
+        wiped at epoch ends plus the epoch in progress."""
+        return {k: self._carried[k] + getattr(self, k) for k in _COUNTERS}
+
     def reset_stats(self) -> None:
+        for k in _COUNTERS:
+            self._carried[k] += getattr(self, k)
         self.last_data_wait = None
         self.data_wait_total = 0.0
         self.h2d_wait_total = 0.0

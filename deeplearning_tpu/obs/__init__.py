@@ -4,9 +4,9 @@ flight recorder.
 Three pieces, one policy (README "Observability policy"):
 
 - ``spans``  — thread-aware ring-buffered host span tracer; emits
-  Chrome trace-event JSON (``trace.json``) and correlates with XLA
-  device traces via ``jax.profiler`` annotations. Near-zero cost when
-  disabled.
+  Chrome trace-event JSON (``trace.json``); run-level ``phase``s of the
+  set-up that are always recorded; ``last()`` keeps a run's ring readable
+  after ``disable()``. Near-zero cost when disabled.
 - ``xla``    — compile telemetry (seconds / FLOPs / peak HBM /
   persistent-cache hit per lowering, via ``tracked_compile``) and
   device-memory watermarking (``hbm_snapshot`` + the ``HbmWatermark``

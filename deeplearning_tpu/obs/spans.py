@@ -17,31 +17,45 @@ extended to instrumentation):
 - **Enabled**: one ``perf_counter`` read on enter, one on exit, and a
   bounded ``deque.append`` under a lock. Never a device sync.
 
-XLA correlation: ``enable(xla_annotate=True)`` makes every span also
-enter a ``jax.profiler.TraceAnnotation`` so that when a device trace is
-active (``utils.profiling.trace``), host spans land on the same
-TensorBoard/XPlane timeline as the XLA ops they bracket.
-``step_span(step_num)`` additionally wraps
-``jax.profiler.StepTraceAnnotation`` — the annotation the profiler's
-step-time analysis keys on.
+Two things outlive the ring being on. ``phase(name)`` marks the rare,
+run-level phases of a run's set-up (``setup/*``): always recorded, into
+the ring when it is on and into the flight recorder either way, and
+read back with ``phases()``. ``last()`` is the tracer most recently
+uninstalled by ``disable()``: a run's timeline after whoever enabled the
+ring has closed it.
+
+XLA correlation: the ring's spans are put on a device trace's clock
+afterwards, by anchoring the k-th ``dispatch`` span to the k-th module
+event (``benchmarks/harness/trace.py::place_host_spans``). Writing them
+into the profiler's own trace (``jax.profiler.TraceAnnotation``) was tried
+on the v5e and removed: it needs host events on, and at
+``host_tracer_level=1`` the feed's layout change still writes 400,000
+``Transpose`` events a batch, which starves the feed (PERF.md, PR 25).
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import json
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterator, List, Optional
+
+from . import flight
 
 __all__ = ["SpanTracer", "enable", "disable", "get_tracer", "enabled",
-           "span", "step_span", "traced"]
+           "last", "span", "step_span", "traced", "phase", "record_phase",
+           "phases"]
 
 # module-level pointer: the `is None` check is the entire disabled-path
 # cost, so spans stay near-free in un-instrumented processes
 _TRACER: Optional["SpanTracer"] = None
+# the tracer most recently uninstalled, so a run's spans can be read after
+# whoever enabled the ring has disabled it and gone
+_LAST: Optional["SpanTracer"] = None
 
 
 class SpanTracer:
@@ -52,11 +66,10 @@ class SpanTracer:
     processes can be merged by a viewer without re-basing.
     """
 
-    def __init__(self, capacity: int = 65536, xla_annotate: bool = False):
+    def __init__(self, capacity: int = 65536):
         self._ring: collections.deque = collections.deque(maxlen=capacity)
         self._lock = threading.Lock()
         self.capacity = capacity
-        self.xla_annotate = xla_annotate
         self.dropped = 0          # spans evicted from the ring
         self.recorded = 0
         # perf_counter -> wall-clock anchor, taken once
@@ -146,24 +159,33 @@ class SpanTracer:
 
 
 # --------------------------------------------------------------- toggles
-def enable(capacity: int = 65536,
-           xla_annotate: bool = False) -> SpanTracer:
+def enable(capacity: int = 65536) -> SpanTracer:
     """Install (or return) the process-wide tracer. Idempotent: a second
     enable keeps the existing ring so layered callers (Trainer + tests)
     share one timeline."""
     global _TRACER
     if _TRACER is None:
-        _TRACER = SpanTracer(capacity=capacity, xla_annotate=xla_annotate)
-    elif xla_annotate:
-        _TRACER.xla_annotate = True
+        _TRACER = SpanTracer(capacity=capacity)
     return _TRACER
 
 
 def disable() -> Optional[SpanTracer]:
-    """Uninstall the tracer; returns it (un-dumped spans stay readable)."""
-    global _TRACER
+    """Uninstall the tracer; returns it (un-dumped spans stay readable,
+    also through ``last()``)."""
+    global _TRACER, _LAST
     t, _TRACER = _TRACER, None
+    if t is not None:
+        _LAST = t
     return t
+
+
+def last() -> Optional[SpanTracer]:
+    """The tracer most recently uninstalled by ``disable()``, or None.
+    Its ``events()`` are that run's timeline: what to look at after
+    ``Trainer.train()`` has returned without a ``workdir``, and what a
+    benchmark reader sees once the driver has closed its window. Held
+    until the next ``disable()`` replaces it (one bounded ring)."""
+    return _LAST
 
 
 def get_tracer() -> Optional[SpanTracer]:
@@ -177,84 +199,68 @@ def enabled() -> bool:
 class span:
     """``with span("data_wait"): ...`` — records one host span.
 
-    Slotted, lock-free and clock-free when tracing is disabled; when
-    ``enable(xla_annotate=True)`` is active it also brackets the block
-    in a ``jax.profiler.TraceAnnotation`` so the device trace shows it.
-    """
+    Slotted, lock-free and clock-free when tracing is disabled. ``args``
+    may be added to inside the block; they are recorded at exit."""
 
-    __slots__ = ("name", "args", "_t0", "_ann")
+    __slots__ = ("name", "args", "_t0")
 
     def __init__(self, name: str, **args: Any):
         self.name = name
         self.args = args or None
         self._t0 = None
-        self._ann = None
 
     def __enter__(self) -> "span":
-        tracer = _TRACER
-        if tracer is None:
-            return self
-        if tracer.xla_annotate:
-            try:
-                import jax
-                self._ann = jax.profiler.TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:  # noqa: BLE001 - annotation is best-effort
-                self._ann = None
-        self._t0 = time.perf_counter()
+        if _TRACER is not None:
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         tracer = _TRACER
         if tracer is not None and self._t0 is not None:
-            t1 = time.perf_counter()
-            if self._ann is not None:
-                try:
-                    self._ann.__exit__(*exc)
-                except Exception:  # noqa: BLE001
-                    pass
-            tracer.record(self.name, self._t0, t1 - self._t0, self.args)
+            tracer.record(self.name, self._t0,
+                          time.perf_counter() - self._t0, self.args)
         self._t0 = None
-        self._ann = None
         return False
 
 
-class step_span:
-    """Per-training-step span: a host ``span`` plus
-    ``jax.profiler.StepTraceAnnotation`` (the marker XLA's step-time
-    tooling groups device ops under). Annotation only happens while the
-    tracer is enabled with ``xla_annotate`` so the disabled hot loop
-    never constructs profiler objects."""
+def step_span(name: str, step_num: int) -> span:
+    """Per-training-step span: a ``span`` that carries ``step``."""
+    return span(name, step=step_num)
 
-    __slots__ = ("_span", "_ann", "step_num")
 
-    def __init__(self, name: str, step_num: int):
-        self.step_num = step_num
-        self._span = span(name, step=step_num)
-        self._ann = None
+def record_phase(name: str, t0: float, **args: Any) -> float:
+    """Record the phase ``name`` that began at the ``perf_counter`` read
+    ``t0`` and ends now; returns its seconds. The form for a start taken
+    before this module could be imported (the package's own import)."""
+    seconds = time.perf_counter() - t0
+    tracer = _TRACER
+    if tracer is not None:
+        tracer.record(name, t0, seconds, args or None)
+    flight.record("phase", name=name, seconds=seconds, t0=t0, **args)
+    return seconds
 
-    def __enter__(self) -> "step_span":
-        tracer = _TRACER
-        if tracer is not None and tracer.xla_annotate:
-            try:
-                import jax
-                self._ann = jax.profiler.StepTraceAnnotation(
-                    "train", step_num=self.step_num)
-                self._ann.__enter__()
-            except Exception:  # noqa: BLE001
-                self._ann = None
-        self._span.__enter__()
-        return self
 
-    def __exit__(self, *exc) -> bool:
-        self._span.__exit__(*exc)
-        if self._ann is not None:
-            try:
-                self._ann.__exit__(*exc)
-            except Exception:  # noqa: BLE001
-                pass
-            self._ann = None
-        return False
+@contextlib.contextmanager
+def phase(name: str, **args: Any) -> Iterator[None]:
+    """``with phase("setup/model_init"): ...`` — a rare, run-level phase
+    that is ALWAYS recorded: into the span ring when the ring is on (so
+    ``trace.json`` shows it) and, on or off, into the flight recorder as
+    ``{"kind": "phase", "name", "seconds", "t0"}`` (``t0``: the
+    ``perf_counter`` read at entry), where ``phases()`` finds it. Two
+    clock reads, a lock and a dict: for the dozen phases of a run's set-up,
+    never inside the step loop (that is what ``span`` is for). The flight
+    ring is bounded (256 events), so a long run with obs on (one ``step``
+    event per step) pushes the set-up's phases out of it."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        record_phase(name, t0, **args)
+
+
+def phases() -> List[Dict[str, Any]]:
+    """The recorded phase events, oldest first."""
+    return flight.get_recorder().events("phase")
 
 
 def traced(name: Optional[str] = None):

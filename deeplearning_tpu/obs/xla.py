@@ -11,9 +11,11 @@ timeline (a ``compile/<name>`` span with FLOPs/HBM args), and the
 flight recorder (so a crash dump shows what was compiled when).
 
 HBM watermarking: ``hbm_snapshot()`` reads ``device.memory_stats()``
-(TPU runtimes report ``bytes_in_use``/``peak_bytes_in_use``; CPU
-returns nothing) plus a ``jax.live_arrays()`` census — count and total
-bytes of every live buffer the process holds. ``HbmWatermark`` samples
+(TPU runtimes report ``bytes_in_use``/``peak_bytes_in_use`` and, for
+the region a running program's temporaries live in,
+``bytes_reserved``/``peak_bytes_reserved``; CPU returns nothing) plus a
+``jax.live_arrays()`` census — count and total bytes of every live
+buffer the process holds. ``HbmWatermark`` samples
 that snapshot from its own thread ("obs-metrics") on an interval,
 tracking run-peak values; its samples are spans, so the timeline shows
 memory next to the phases that allocated it.
@@ -192,7 +194,7 @@ def clear_compile_events() -> None:
 # int-converted — one odd field must not drop the whole entry.
 _HBM_FIELDS = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit",
                "largest_alloc_size", "bytes_reserved",
-               "pool_bytes", "num_allocs")
+               "peak_bytes_reserved", "pool_bytes", "num_allocs")
 
 # alert when bytes_in_use crosses this fraction of bytes_limit (None =
 # off). Process-wide because hbm_snapshot is called from crash dumps and
@@ -236,6 +238,15 @@ def _mem_entry(dev, stats, alert_frac: Optional[float]) -> Dict[str, Any]:
                 entry[key] = int(stats[key])
             except (TypeError, ValueError):
                 pass           # generation reports a non-numeric field
+    # On the v5e's client ``peak_bytes_in_use`` leaves a running step's
+    # temporaries out: they live in a region of their own that
+    # ``peak_bytes_reserved`` counts (PERF.md §5: 1.86 GB in use beside
+    # 7.38 GB reserved for the ViT-B/16 step). The device's peak is the sum
+    # of the two, an upper bound as the peaks need not coincide; where the
+    # client reports no reserved region it is the in-use peak alone.
+    if "peak_bytes_in_use" in entry:
+        entry["peak_bytes"] = (entry["peak_bytes_in_use"]
+                               + entry.get("peak_bytes_reserved", 0))
     in_use, limit = entry.get("bytes_in_use"), entry.get("bytes_limit")
     if in_use is not None and limit:
         frac = in_use / limit
@@ -301,6 +312,7 @@ class HbmWatermark:
         self.samples = 0
         self.peak_live_bytes = 0
         self.peak_bytes_in_use = 0
+        self.peak_bytes = 0          # in use + reserved, see _mem_entry
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
@@ -313,6 +325,8 @@ class HbmWatermark:
         for dev in snap.get("devices", []):
             in_use = dev.get("bytes_in_use", 0)
             self.peak_bytes_in_use = max(self.peak_bytes_in_use, in_use)
+            self.peak_bytes = max(self.peak_bytes,
+                                  dev.get("peak_bytes", in_use))
         tracer = spans.get_tracer()
         if tracer is not None:
             tracer.record("hbm_sample", t0,
@@ -326,6 +340,7 @@ class HbmWatermark:
                           float(self.peak_live_bytes))
         metrics.set_gauge("dltpu_hbm_peak_bytes_in_use",
                           float(self.peak_bytes_in_use))
+        metrics.set_gauge("dltpu_hbm_peak_bytes", float(self.peak_bytes))
 
     def _run(self) -> None:
         self._sample()                       # guaranteed first point
@@ -352,6 +367,7 @@ class HbmWatermark:
             "hbm_samples": float(self.samples),
             "peak_live_bytes": float(self.peak_live_bytes),
             "peak_bytes_in_use": float(self.peak_bytes_in_use),
+            "peak_bytes": float(self.peak_bytes),
         }
 
     def __enter__(self) -> "HbmWatermark":

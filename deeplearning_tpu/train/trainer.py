@@ -37,7 +37,7 @@ from ..elastic.preempt import (Preempted, PreemptionGuard,
                                agree_preempt_step)
 from ..obs import flight
 from ..obs import metrics as obs_metrics
-from ..obs.spans import span, step_span
+from ..obs.spans import phase, span, step_span
 from ..utils.profiling import RetraceGuard
 from . import recovery as recovery_mod
 from .async_metrics import DeferredMetrics
@@ -234,6 +234,8 @@ class Trainer:
         self.eval_fetches = 0        # host materializations per evaluate()
         self._host_step: Optional[int] = None  # host mirror of state.step
         self._batches = None         # live epoch iterator (rollback hook)
+        self._aot_step = None        # the AOT-compiled step (precompile)
+        self._stop_requested = False
         self.ckpt = (CheckpointManager(f"{workdir}/ckpt",
                                        async_save=async_checkpoint)
                      if workdir else None)
@@ -296,13 +298,37 @@ class Trainer:
             return None
         from ..obs.xla import tracked_compile
         t0 = time.perf_counter()
-        self._aot_step = tracked_compile(
-            fn.lower(self.state, batch_spec, self.rng), "train_step")
+        # trace + lower and the XLA compile are timed apart: the phase
+        # here, ``compile/train_step`` inside tracked_compile
+        with phase("setup/lower"):
+            lowered = fn.lower(self.state, batch_spec, self.rng)
+        self._aot_step = tracked_compile(lowered, "train_step")
         dt = time.perf_counter() - t0
         self.precompile_seconds = dt
         self.logger.info(f"precompile: train step AOT-compiled in "
                          f"{dt:.2f}s (overlapped with feed warmup)")
         return dt
+
+    def compiled_step_text(self) -> Optional[str]:
+        """Text of the AOT-compiled train step (its ``op_name`` metadata
+        carries the Flax module paths a device trace's op events lack);
+        None before ``precompile()`` has compiled one."""
+        return None if self._aot_step is None else self._aot_step.as_text()
+
+    def close_feed(self) -> None:
+        """Shut down the live epoch iterator's prefetch pipeline (its
+        worker thread), for a caller that leaves an epoch part-way."""
+        close = getattr(self._batches, "close", None)
+        if close is not None:
+            close()
+
+    def request_stop(self) -> None:
+        """Ask ``train()`` to return: the flag is looked at once per step,
+        at the elastic step boundary, after which the epoch's metrics are
+        drained, the feed is closed, ``after_train`` fires and ``train()``
+        returns the state (no further eval or checkpoint). Safe from a
+        callback or another thread."""
+        self._stop_requested = True
 
     # ----------------------------------------------------- observability
     def _obs_config(self) -> Dict[str, Any]:
@@ -490,6 +516,10 @@ class Trainer:
                 self.epoch = epoch
                 self.callbacks.fire("before_epoch", self)
                 self._train_one_epoch(epoch)
+                if self._stop_requested:
+                    self._stop_requested = False   # a later train() runs
+                    self.close_feed()
+                    break
                 self.callbacks.fire("after_epoch", self)
                 if self.eval_step and self.eval_loader is not None and \
                         (epoch + 1) % self.eval_every == 0:
@@ -548,11 +578,17 @@ class Trainer:
             # data-wait phase: host blocked on the (possibly prefetched)
             # loader — on the span timeline this is the slice the feed
             # follow-ups in ROADMAP.md need to see shrink
-            with span("data_wait", epoch=epoch):
+            with span("data_wait", epoch=epoch,
+                      step=self.host_step) as waited:
                 try:
                     batch = next(batches)
                 except StopIteration:
                     break
+                # the feed's number for this batch: joins this wait and
+                # the step to the worker's feed/* spans of the same batch
+                fed = getattr(self.train_loader, "last_batch", None)
+                if fed is not None:
+                    waited.args["batch"] = fed
             wall_wait = time.time() - t_data
             # prefer the loader's own queue-empty estimate (actual
             # starvation) over wall-clock-between-iterations, which
@@ -583,9 +619,8 @@ class Trainer:
                         prev_params = recovery_mod.snapshot_state(
                             self.state.params)
                 # dispatch phase: enqueue the jitted step (async — this
-                # span measures host dispatch, not device compute;
-                # StepTrace-annotated so a concurrent XLA trace aligns
-                # device ops)
+                # span measures host dispatch, not device compute; a
+                # device trace is aligned to the k-th of these afterwards)
                 with step_span("dispatch", self.host_step):
                     self.state, metrics = self.train_step(
                         self.state, batch, self.rng)
@@ -615,6 +650,8 @@ class Trainer:
                 # detection and recovery run end to end, not shortcut
                 self.state = recovery_mod.poison_state(self.state)
             self._check_preempted()
+            if self._stop_requested:
+                break
             t_data = time.time()
             it += 1
         # epoch-end barrier: one bulk fetch lands every remaining entry,
@@ -712,9 +749,7 @@ class Trainer:
         meta, host = d.meta, d.host
         bad_step = int(meta.get("step") or self.host_step)
         # the failed pass's prefetch pipeline must die before we restart
-        close = getattr(self._batches, "close", None)
-        if close is not None:
-            close()
+        self.close_feed()
         try:
             anchor_step, state = self._recovery.on_divergence(bad_step)
         except RecoveryExhausted as exc:

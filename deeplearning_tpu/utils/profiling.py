@@ -1,4 +1,4 @@
-"""Profiling: step timing, XLA-FLOPs MFU meter, jax.profiler traces.
+"""Profiling: step timing, XLA-FLOPs MFU meter.
 
 The reference's ad-hoc timing stack (SURVEY.md §5: cuda-synchronized
 time_sync, thop-based layer profilers, swin throughput mode) becomes:
@@ -6,13 +6,10 @@ time_sync, thop-based layer profilers, swin throughput mode) becomes:
   ``jax.block_until_ready`` before ``stop()``.
 - ``mfu``: measured step time vs compiled-graph FLOPs vs chip peak — the
   BASELINE.md headline metric.
-- ``trace``: context manager around jax.profiler for TensorBoard's
-  profile plugin.
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
 import warnings
 from typing import Callable, Dict, Optional
@@ -160,18 +157,6 @@ def measure_mfu(step_fn: Callable, args: tuple, n_steps: int = 10
     return {"step_time_s": dt, "flops_per_step": flops,
             "mfu": flops / dt / peak if flops else 0.0,
             "peak_flops": peak}
-
-
-@contextlib.contextmanager
-def trace(logdir: str):
-    """jax.profiler trace for TensorBoard's profile plugin."""
-    import os
-    os.makedirs(logdir, exist_ok=True)   # fresh run dirs must not fail
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 def model_info(model, *example_args, train: bool = False,

@@ -115,6 +115,72 @@ class TestDevicePrefetcher:
         pf.reset_stats()
         assert pf.batches_fed == 0 and pf.stats()["data_wait_total"] == 0.0
 
+    def test_totals_survive_the_epoch_reset(self):
+        pf = DevicePrefetcher(CountingLoader(n=5, delay=0.001), depth=2)
+        seen = []
+        for epoch in range(2):
+            pf.set_epoch(epoch)
+            assert sum(1 for _ in pf) == 5
+            seen.append(pf.stats())
+            before = pf.totals()
+            pf.reset_stats()           # what the Trainer does per epoch
+            assert pf.totals() == before
+        assert pf.stats()["batches_fed"] == 0        # "this epoch" stays
+        totals = pf.totals()
+        assert totals["batches_fed"] == 10
+        for key in ("data_wait_total", "h2d_wait_total"):
+            assert totals[key] == pytest.approx(
+                seen[0][key] + seen[1][key]) and totals[key] > 0
+        assert totals["source_wait_total"] >= 10 * 0.001
+        pf.set_epoch(2)
+        next(iter(pf))                 # an epoch in progress counts too
+        assert pf.totals()["batches_fed"] == 11
+
+    def test_batch_numbers_join_the_worker_lane_to_the_consumer(self):
+        from deeplearning_tpu.obs import spans
+        spans.disable()
+        tracer = spans.enable()
+        try:
+            pf = DevicePrefetcher(CountingLoader(n=4), depth=2)
+            received = []
+            for epoch in range(2):     # numbers run on across epochs
+                pf.set_epoch(epoch)
+                for _ in pf:
+                    received.append(pf.last_batch)
+        finally:
+            spans.disable()
+        assert received == list(range(8))
+        lanes = {}
+        for e in tracer.events():
+            if e["ph"] == "X" and e["name"].startswith("feed/"):
+                assert e["dur"] > 0
+                lanes.setdefault(e["name"], []).append(e["args"]["batch"])
+        assert lanes == {name: list(range(8)) for name in
+                         ("feed/decode", "feed/h2d", "feed/put_wait")}
+        # decode, h2d and put_wait of one batch tile the worker's loop
+        by_batch = {}
+        for e in tracer.events():
+            if e["ph"] == "X" and e["name"].startswith("feed/"):
+                by_batch.setdefault(e["args"]["batch"], {})[e["name"]] = e
+        for lane in by_batch.values():
+            dec, h2d, put = (lane[n] for n in
+                             ("feed/decode", "feed/h2d", "feed/put_wait"))
+            assert dec["ts"] + dec["dur"] == pytest.approx(h2d["ts"], abs=1)
+            assert h2d["ts"] + h2d["dur"] == pytest.approx(put["ts"], abs=1)
+
+    def test_infinite_property_drops_a_started_pipeline(self):
+        loader = make_loader(n=96, batch=32)
+        pf = DevicePrefetcher(loader, depth=2)
+        assert pf.infinite is False
+        pf.start()                     # the finite pipeline precompile starts
+        stale = pf._active
+        pf.infinite = True
+        assert loader.infinite is True and pf.infinite is True
+        assert pf._active is None and stale["stop"].is_set()
+        it = iter(pf)
+        assert sum(1 for _ in zip(range(7), it)) == 7   # past one 3-batch pass
+        it.close()
+
     def test_epoch_protocol_delegates_and_reshuffles(self):
         ref = make_loader(shuffle=True)
         ref.set_epoch(3)
@@ -270,7 +336,8 @@ class TestPrecompile:
         dt = trainer.precompile()
         assert dt is not None and dt > 0
         assert trainer.precompile_seconds == dt
-        assert hasattr(trainer, "_aot_step")
+        assert trainer._aot_step is not None
+        assert "HloModule" in trainer.compiled_step_text()
         trainer.train()                      # reuses the AOT executable
         assert trainer.deferred.pending == 0
 

@@ -125,6 +125,67 @@ class TestSpanTracer:
         assert fn() == 42                       # no tracer, plain call
 
 
+# ---------------------------------------------------------- run phases
+class TestPhases:
+    @pytest.mark.parametrize("ring_on", [False, True])
+    def test_phase_always_lands_in_the_flight_ring(self, ring_on):
+        tracer = spans.enable() if ring_on else None
+        t_before = time.perf_counter()
+        with spans.phase("setup/model_init", model="tiny"):
+            time.sleep(0.002)
+        (ev,) = spans.phases()
+        assert ev["kind"] == "phase" and ev["name"] == "setup/model_init"
+        assert ev["seconds"] >= 0.002
+        assert t_before <= ev["t0"] <= time.perf_counter()
+        assert ev["model"] == "tiny"
+        if ring_on:
+            (x,) = [e for e in tracer.events() if e["ph"] == "X"]
+            assert x["name"] == "setup/model_init"
+            assert x["dur"] == pytest.approx(ev["seconds"] * 1e6, rel=1e-3)
+        else:
+            assert spans.get_tracer() is None   # recording enabled nothing
+
+    def test_phase_records_when_the_block_raises(self):
+        with pytest.raises(KeyError):
+            with spans.phase("setup/data"):
+                raise KeyError("no such file")
+        assert [e["name"] for e in spans.phases()] == ["setup/data"]
+
+    def test_record_phase_takes_an_earlier_start(self):
+        t0 = time.perf_counter() - 1.5
+        assert spans.record_phase("setup/import", t0) >= 1.5
+        assert spans.phases()[-1]["t0"] == t0
+
+    def test_package_import_is_a_phase(self):
+        # the autouse fixture cleared the ring, so ask a fresh interpreter
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import deeplearning_tpu\n"
+             "from deeplearning_tpu.obs import spans\n"
+             "print([(e['name'], e['seconds'] > 0) "
+             "for e in spans.phases()])"],
+            capture_output=True, text=True, timeout=300,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"})
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip() == "[('setup/import', True)]"
+
+    def test_last_outlives_disable(self):
+        before = spans.last()
+        tracer = spans.enable()
+        with span("dispatch", step=1):
+            pass
+        assert spans.last() is before          # still installed
+        assert spans.disable() is tracer
+        assert spans.last() is tracer
+        names = [e["name"] for e in spans.last().events() if e["ph"] == "X"]
+        assert names == ["dispatch"]
+        spans.disable()                        # nothing installed: kept
+        assert spans.last() is tracer
+        other = spans.enable()
+        spans.disable()
+        assert spans.last() is other           # replaced by the next one
+
+
 # ----------------------------------------------------- compile telemetry
 class TestCompileTelemetry:
     def test_tracked_compile_records_flops_and_span(self):
@@ -159,6 +220,37 @@ class TestCompileTelemetry:
         assert snap["live_arrays"]["count"] >= 1
         assert snap["live_arrays"]["nbytes"] >= keep.nbytes
         assert isinstance(snap["devices"], list) and snap["devices"]
+
+    @pytest.mark.parametrize("stats, peak", [
+        # the v5e's client: temporaries in the reserved region (PERF.md §5)
+        ({"bytes_in_use": 1_627_000_000, "peak_bytes_in_use": 1_863_000_000,
+          "bytes_reserved": 7_377_000_000,
+          "peak_bytes_reserved": 7_377_000_000,
+          "bytes_limit": 16_900_000_000}, 9_240_000_000),
+        # a client without a reserved region: the in-use peak alone
+        ({"bytes_in_use": 100, "peak_bytes_in_use": 300}, 300),
+        # a client with no peak at all: the largest sample
+        ({"bytes_in_use": 100}, 100),
+    ])
+    def test_hbm_watermark_counts_the_reserved_region(self, monkeypatch,
+                                                      stats, peak):
+        class Dev:
+            id, device_kind = 0, "stub"
+
+            def memory_stats(self):
+                return stats
+        monkeypatch.setattr(jax, "devices", lambda *a, **k: [Dev()])
+        snap = obs_xla.hbm_snapshot()
+        entry = snap["devices"][0]
+        for key, value in stats.items():
+            assert entry[key] == value          # peak_bytes_reserved too
+        assert entry.get("peak_bytes") == (
+            peak if "peak_bytes_in_use" in stats else None)
+        wm = obs_xla.HbmWatermark(interval_s=0.01)
+        wm._sample()
+        assert wm.watermark()["peak_bytes"] == float(peak)
+        assert wm.watermark()["peak_bytes_in_use"] == float(
+            stats["bytes_in_use"])
 
     def test_hbm_watermark_samples_from_its_thread(self):
         tracer = spans.enable()
@@ -559,18 +651,6 @@ class TestProfilingSatellites:
         assert len(t.times) == 1
         t.stop()                               # unmatched stop: ignored
         assert len(t.times) == 1
-
-    def test_trace_creates_its_logdir(self, tmp_path, monkeypatch):
-        from deeplearning_tpu.utils import profiling
-        seen = {}
-        monkeypatch.setattr(
-            jax.profiler, "start_trace",
-            lambda d: seen.setdefault("dir_existed", os.path.isdir(d)))
-        monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
-        logdir = str(tmp_path / "fresh" / "profile")
-        with profiling.trace(logdir):
-            pass
-        assert seen["dir_existed"]             # created before start_trace
 
 
 class TestLoggerDirCache:

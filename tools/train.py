@@ -114,16 +114,96 @@ def load_data(cfg: DataCfg, num_classes: int
     return images, labels
 
 
+def _build_loaders(cfg: Config, mesh):
+    """Train and eval loaders from the folder, the npz or the synthetic
+    set: ``(loader, eval_loader, sample_shape, n_train)``."""
+    from deeplearning_tpu.data import ArraySource, DataLoader
+
+    if cfg.data.folder:
+        from deeplearning_tpu.data.build import (LoaderConfig,
+                                                 build_classification_loaders)
+        lcfg = LoaderConfig(global_batch=cfg.data.global_batch,
+                            image_size=cfg.data.image_size,
+                            val_rate=cfg.data.val_rate,
+                            num_workers=cfg.data.num_workers,
+                            seed=cfg.train.seed,
+                            augment=cfg.data.augment)
+        loader, eval_loader, class_to_idx = build_classification_loaders(
+            cfg.data.folder, lcfg, mesh=mesh,
+            class_indices_path=(os.path.join(cfg.train.workdir,
+                                             "class_indices.json")
+                                if cfg.train.workdir else None))
+        if len(class_to_idx) != cfg.model.num_classes:
+            raise ValueError(
+                f"model.num_classes={cfg.model.num_classes} but "
+                f"{cfg.data.folder} has {len(class_to_idx)} classes")
+        sample_shape = (1, cfg.data.image_size, cfg.data.image_size, 3)
+        n_train = len(loader) * cfg.data.global_batch
+        return loader, eval_loader, sample_shape, n_train
+    images, labels = load_data(cfg.data, cfg.model.num_classes)
+    hw = images.shape[1:3]
+    sample_shape = (1, hw[0], hw[1], cfg.data.channels)
+    tr_images, tr_labels = images, labels
+    ev_images, ev_labels = images, labels
+    gb = cfg.data.global_batch
+    if cfg.data.npz and cfg.data.val_rate > 0 and len(images) >= 2 * gb:
+        # held-out split for npz datasets, BEFORE the schedule is
+        # sized (total_steps must match the post-split loader) and
+        # never smaller than one eval batch (the loader floor-divides,
+        # so a sub-batch slice would silently eval nothing)
+        order = np.random.default_rng(cfg.train.seed).permutation(
+            len(images))
+        n_val = min(max(int(len(images) * cfg.data.val_rate), gb),
+                    len(images) - gb)
+        ev_images, ev_labels = (images[order[:n_val]],
+                                labels[order[:n_val]])
+        tr_images, tr_labels = (images[order[n_val:]],
+                                labels[order[n_val:]])
+    n_train = len(tr_images)
+
+    def _cls_source(imgs, labs):
+        """Per-sample uint8→f32 + channel expansion (lazy, so the
+        dataset stays in its compact storage dtype in RAM)."""
+        needs = (imgs.dtype == np.uint8 or imgs.ndim == 3
+                 or imgs.shape[-1] != cfg.data.channels)
+        if not needs:
+            return ArraySource(image=imgs, label=labs)
+        from deeplearning_tpu.data.loader import MapSource
+
+        def fetch(i):
+            img = imgs[i]
+            if img.dtype == np.uint8:
+                img = img.astype(np.float32) / 255.0
+            if img.ndim == 2:
+                img = img[..., None]
+            if img.shape[-1] == 1 and cfg.data.channels == 3:
+                img = np.repeat(img, 3, axis=-1)
+            return {"image": np.asarray(img, np.float32),
+                    "label": labs[i]}
+        return MapSource(len(imgs), fetch)
+
+    loader = DataLoader(_cls_source(tr_images, tr_labels),
+                        global_batch=cfg.data.global_batch, mesh=mesh,
+                        seed=cfg.train.seed)
+    eval_loader = DataLoader(_cls_source(ev_images, ev_labels),
+                             global_batch=cfg.data.global_batch,
+                             mesh=mesh, shuffle=False)
+    return loader, eval_loader, sample_shape, n_train
+
+
 def build_trainer(cfg: Config, devices=None):
     """Everything ``main`` does up to the first step: mesh (over
     ``devices``, default all of them), data, model, sharded state,
     jitted step, ``Trainer`` and (by default) the AOT step compile.
     Returned un-run so a caller can hook the loop — ``chip_smoke.py``
-    drives the chip through exactly this path."""
+    drives the chip through exactly this path. Its parts are run-level
+    phases (``setup/mesh`` ... ``setup/posture``, with ``setup/lower`` and
+    ``compile/train_step`` inside ``Trainer.precompile``): they tile the
+    call, and ``obs.spans.phases()`` says where its seconds went."""
     from deeplearning_tpu.core.compile_cache import enable_compile_cache
     enable_compile_cache()   # step compiles are once-per-machine, not per-run
     from deeplearning_tpu.core.registry import MODELS
-    from deeplearning_tpu.data import ArraySource, DataLoader
+    from deeplearning_tpu.obs.spans import phase
     from deeplearning_tpu.parallel import MeshConfig, build_mesh
     from deeplearning_tpu.train import (TrainState, make_eval_step,
                                         make_train_step, shard_state)
@@ -159,56 +239,19 @@ def build_trainer(cfg: Config, devices=None):
         raise ValueError("train.grad_comm=int8 requires "
                          "train.accum_steps=1 (quantizing microbatch "
                          "partial sums would stack quantization error)")
-    mesh = build_mesh(MeshConfig(
-        data=-1,
-        model=pp_stages if pp_stages > 1 else cfg.train.mesh_model_axis,
-        seq=cfg.train.mesh_seq_axis), devices=devices)
+    with phase("setup/mesh"):
+        mesh = build_mesh(MeshConfig(
+            data=-1,
+            model=pp_stages if pp_stages > 1 else cfg.train.mesh_model_axis,
+            seq=cfg.train.mesh_seq_axis), devices=devices)
     if pp_stages > 1 and mesh.shape["data"] > 1:
         print(f"WARNING: pipeline_stages={pp_stages} uses only the "
               f"{pp_stages}-device 'model' axis; the {mesh.shape['data']}"
               "-way 'data' axis replicates work (DPxPP composition not "
               "implemented yet) — set pipeline_stages = device count")
-    if cfg.data.folder:
-        from deeplearning_tpu.data.build import (LoaderConfig,
-                                                 build_classification_loaders)
-        lcfg = LoaderConfig(global_batch=cfg.data.global_batch,
-                            image_size=cfg.data.image_size,
-                            val_rate=cfg.data.val_rate,
-                            num_workers=cfg.data.num_workers,
-                            seed=cfg.train.seed,
-                            augment=cfg.data.augment)
-        loader, eval_loader, class_to_idx = build_classification_loaders(
-            cfg.data.folder, lcfg, mesh=mesh,
-            class_indices_path=(os.path.join(cfg.train.workdir,
-                                             "class_indices.json")
-                                if cfg.train.workdir else None))
-        if len(class_to_idx) != cfg.model.num_classes:
-            raise ValueError(
-                f"model.num_classes={cfg.model.num_classes} but "
-                f"{cfg.data.folder} has {len(class_to_idx)} classes")
-        sample_shape = (1, cfg.data.image_size, cfg.data.image_size, 3)
-        n_train = len(loader) * cfg.data.global_batch
-    else:
-        images, labels = load_data(cfg.data, cfg.model.num_classes)
-        hw = images.shape[1:3]
-        sample_shape = (1, hw[0], hw[1], cfg.data.channels)
-        tr_images, tr_labels = images, labels
-        ev_images, ev_labels = images, labels
-        gb = cfg.data.global_batch
-        if cfg.data.npz and cfg.data.val_rate > 0 and len(images) >= 2 * gb:
-            # held-out split for npz datasets, BEFORE the schedule is
-            # sized (total_steps must match the post-split loader) and
-            # never smaller than one eval batch (the loader floor-divides,
-            # so a sub-batch slice would silently eval nothing)
-            order = np.random.default_rng(cfg.train.seed).permutation(
-                len(images))
-            n_val = min(max(int(len(images) * cfg.data.val_rate), gb),
-                        len(images) - gb)
-            ev_images, ev_labels = (images[order[:n_val]],
-                                    labels[order[:n_val]])
-            tr_images, tr_labels = (images[order[n_val:]],
-                                    labels[order[n_val:]])
-        n_train = len(tr_images)
+    with phase("setup/data"):
+        loader, eval_loader, sample_shape, n_train = _build_loaders(
+            cfg, mesh)
     dtype = jnp.bfloat16 if cfg.model.precision == "bf16" else jnp.float32
     if cfg.model.exact_gelu:
         from deeplearning_tpu.core import numerics
@@ -231,131 +274,107 @@ def build_trainer(cfg: Config, devices=None):
             from deeplearning_tpu.parallel.ulysses import (
                 make_ulysses_attn_fn)
             model_kw["attn_fn"] = make_ulysses_attn_fn(mesh)
-    model = MODELS.build(cfg.model.name, num_classes=cfg.model.num_classes,
-                         dtype=dtype, **model_kw)
-    sample = jnp.zeros(sample_shape)
-    variables = model.init(jax.random.key(cfg.train.seed), sample,
-                           train=False)
-    params = variables["params"]
-    k_per_stage = 0
-    if pp_stages > 1:
-        from deeplearning_tpu.parallel.pipeline_train import \
-            split_vit_params
-        outer, stages, k_per_stage = split_vit_params(params, pp_stages)
-        params = {"outer": outer, "stages": stages}
-    steps_per_epoch = n_train // cfg.data.global_batch
-    sched = build_schedule(cfg.optim.schedule, base_lr=cfg.optim.lr,
-                           total_steps=cfg.train.epochs * steps_per_epoch,
-                           warmup_steps=cfg.optim.warmup_steps)
-    tx = build_optimizer(cfg.optim.name, sched,
-                         clip_grad_norm=cfg.optim.clip_grad_norm or None,
-                         weight_decay=cfg.optim.weight_decay,
-                         momentum=cfg.optim.momentum, params=params)
-    state = TrainState.create(
-        apply_fn=model.apply, params=params, tx=tx,
-        batch_stats=variables.get("batch_stats", {}),
-        use_ema=cfg.train.ema)
+    with phase("setup/model_init"):
+        model = MODELS.build(cfg.model.name,
+                             num_classes=cfg.model.num_classes,
+                             dtype=dtype, **model_kw)
+        sample = jnp.zeros(sample_shape)
+        variables = model.init(jax.random.key(cfg.train.seed), sample,
+                               train=False)
+        params = variables["params"]
+        k_per_stage = 0
+        if pp_stages > 1:
+            from deeplearning_tpu.parallel.pipeline_train import \
+                split_vit_params
+            outer, stages, k_per_stage = split_vit_params(params, pp_stages)
+            params = {"outer": outer, "stages": stages}
+    with phase("setup/state"):
+        steps_per_epoch = n_train // cfg.data.global_batch
+        sched = build_schedule(cfg.optim.schedule, base_lr=cfg.optim.lr,
+                               total_steps=cfg.train.epochs * steps_per_epoch,
+                               warmup_steps=cfg.optim.warmup_steps)
+        tx = build_optimizer(cfg.optim.name, sched,
+                             clip_grad_norm=cfg.optim.clip_grad_norm or None,
+                             weight_decay=cfg.optim.weight_decay,
+                             momentum=cfg.optim.momentum, params=params)
+        state = TrainState.create(
+            apply_fn=model.apply, params=params, tx=tx,
+            batch_stats=variables.get("batch_stats", {}),
+            use_ema=cfg.train.ema)
 
-    if pp_stages > 1:
-        from deeplearning_tpu.parallel.pipeline_train import \
-            shard_pipeline_state
-        state = shard_pipeline_state(state, mesh)
-    else:
-        state = shard_state(state, mesh, zero1=zero1)
+        if pp_stages > 1:
+            from deeplearning_tpu.parallel.pipeline_train import \
+                shard_pipeline_state
+            state = shard_pipeline_state(state, mesh)
+        else:
+            state = shard_state(state, mesh, zero1=zero1)
     has_bn = bool(variables.get("batch_stats"))
-    if not cfg.data.folder:
-        def _cls_source(imgs, labs):
-            """Per-sample uint8→f32 + channel expansion (lazy, so the
-            dataset stays in its compact storage dtype in RAM)."""
-            needs = (imgs.dtype == np.uint8 or imgs.ndim == 3
-                     or imgs.shape[-1] != cfg.data.channels)
-            if not needs:
-                return ArraySource(image=imgs, label=labs)
-            from deeplearning_tpu.data.loader import MapSource
-
-            def fetch(i):
-                img = imgs[i]
-                if img.dtype == np.uint8:
-                    img = img.astype(np.float32) / 255.0
-                if img.ndim == 2:
-                    img = img[..., None]
-                if img.shape[-1] == 1 and cfg.data.channels == 3:
-                    img = np.repeat(img, 3, axis=-1)
-                return {"image": np.asarray(img, np.float32),
-                        "label": labs[i]}
-            return MapSource(len(imgs), fetch)
-
-        loader = DataLoader(_cls_source(tr_images, tr_labels),
-                            global_batch=cfg.data.global_batch, mesh=mesh,
-                            seed=cfg.train.seed)
-        eval_loader = DataLoader(_cls_source(ev_images, ev_labels),
-                                 global_batch=cfg.data.global_batch,
-                                 mesh=mesh, shuffle=False)
     if cfg.data.global_batch % max(cfg.train.accum_steps, 1):
         raise ValueError(
             f"data.global_batch={cfg.data.global_batch} must be divisible "
             f"by train.accum_steps={cfg.train.accum_steps}")
-    if pp_stages > 1:
-        from deeplearning_tpu.parallel.pipeline_train import \
-            make_pipeline_train_step
-        micro = cfg.train.microbatches or pp_stages
-        if micro % pp_stages:
-            raise ValueError(
-                f"train.microbatches={micro} must be divisible by "
-                f"train.pipeline_stages={pp_stages} (microbatch storage "
-                "shards over the pipe axis)")
-        if cfg.data.global_batch % micro:
-            raise ValueError(
-                f"data.global_batch={cfg.data.global_batch} must be "
-                f"divisible by train.microbatches={micro}")
-        base_step, pp_eval_step = make_pipeline_train_step(
-            model, mesh, tx, num_stages=pp_stages,
-            k_per_stage=k_per_stage, microbatches=micro,
-            label_smoothing=cfg.train.label_smoothing)
-    else:
-        base_step = make_train_step(
-            make_loss_fn(cfg.train.label_smoothing, has_bn), mesh=mesh,
-            accum_steps=cfg.train.accum_steps,
-            donate_batch=cfg.train.donate_batch,
-            weight_update=cfg.train.weight_update,
-            grad_comm=cfg.train.grad_comm)
-    if cfg.train.mixup:
-        from deeplearning_tpu.core import rng as rng_mod
-        from deeplearning_tpu.data.mixup import mixup_cutmix
+    with phase("setup/step_build"):
+        if pp_stages > 1:
+            from deeplearning_tpu.parallel.pipeline_train import \
+                make_pipeline_train_step
+            micro = cfg.train.microbatches or pp_stages
+            if micro % pp_stages:
+                raise ValueError(
+                    f"train.microbatches={micro} must be divisible by "
+                    f"train.pipeline_stages={pp_stages} (microbatch storage "
+                    "shards over the pipe axis)")
+            if cfg.data.global_batch % micro:
+                raise ValueError(
+                    f"data.global_batch={cfg.data.global_batch} must be "
+                    f"divisible by train.microbatches={micro}")
+            base_step, pp_eval_step = make_pipeline_train_step(
+                model, mesh, tx, num_stages=pp_stages,
+                k_per_stage=k_per_stage, microbatches=micro,
+                label_smoothing=cfg.train.label_smoothing)
+        else:
+            base_step = make_train_step(
+                make_loss_fn(cfg.train.label_smoothing, has_bn), mesh=mesh,
+                accum_steps=cfg.train.accum_steps,
+                donate_batch=cfg.train.donate_batch,
+                weight_update=cfg.train.weight_update,
+                grad_comm=cfg.train.grad_comm)
+        if cfg.train.mixup:
+            from deeplearning_tpu.core import rng as rng_mod
+            from deeplearning_tpu.data.mixup import mixup_cutmix
 
-        def train_step(s, batch, rng):
-            # fold the step in HERE: the Trainer hands the same run key
-            # every iteration (step-folding otherwise happens inside
-            # base_step, after augmentation would already have run)
-            aug_key = rng_mod.step_key(jax.random.fold_in(rng, 1), s.step)
-            batch = mixup_cutmix(batch, aug_key, cfg.model.num_classes,
-                                 smoothing=cfg.train.label_smoothing)
-            return base_step(s, batch, rng)
-        train_step = jax.jit(
-            train_step,
-            donate_argnums=(0, 1) if cfg.train.donate_batch else (0,))
-    else:
-        train_step = base_step
-    trainer = Trainer(
-        state=state,
-        train_step=train_step,
-        train_loader=loader,
-        eval_step=(pp_eval_step if pp_stages > 1
-                   else make_eval_step(make_metric_fn())),
-        eval_loader=eval_loader,
-        epochs=cfg.train.epochs,
-        seed=cfg.train.seed,
-        workdir=cfg.train.workdir,
-        async_checkpoint=cfg.train.async_checkpoint,
-        log_every=max(steps_per_epoch // 2, 1),
-        prefetch=cfg.data.prefetch,
-        recovery=(None if cfg.train.recovery in ("none", "")
-                  else cfg.train.recovery),
-        strict=cfg.train.strict or None,
-        weight_update=cfg.train.weight_update,
-        # full config into the flight recorder: a flightrec.json from a
-        # crashed run identifies the exact run that produced it
-        run_config=dataclasses.asdict(cfg))
+            def train_step(s, batch, rng):
+                # fold the step in HERE: the Trainer hands the same run key
+                # every iteration (step-folding otherwise happens inside
+                # base_step, after augmentation would already have run)
+                aug_key = rng_mod.step_key(jax.random.fold_in(rng, 1), s.step)
+                batch = mixup_cutmix(batch, aug_key, cfg.model.num_classes,
+                                     smoothing=cfg.train.label_smoothing)
+                return base_step(s, batch, rng)
+            train_step = jax.jit(
+                train_step,
+                donate_argnums=(0, 1) if cfg.train.donate_batch else (0,))
+        else:
+            train_step = base_step
+        trainer = Trainer(
+            state=state,
+            train_step=train_step,
+            train_loader=loader,
+            eval_step=(pp_eval_step if pp_stages > 1
+                       else make_eval_step(make_metric_fn())),
+            eval_loader=eval_loader,
+            epochs=cfg.train.epochs,
+            seed=cfg.train.seed,
+            workdir=cfg.train.workdir,
+            async_checkpoint=cfg.train.async_checkpoint,
+            log_every=max(steps_per_epoch // 2, 1),
+            prefetch=cfg.data.prefetch,
+            recovery=(None if cfg.train.recovery in ("none", "")
+                      else cfg.train.recovery),
+            strict=cfg.train.strict or None,
+            weight_update=cfg.train.weight_update,
+            # full config into the flight recorder: a flightrec.json from a
+            # crashed run identifies the exact run that produced it
+            run_config=dataclasses.asdict(cfg))
     if cfg.train.precompile:
         try:
             # AOT step compile runs while the prefetcher's worker thread
@@ -374,8 +393,10 @@ def build_trainer(cfg: Config, devices=None):
         aot = getattr(trainer, "_aot_step", None)
         if aot is not None:
             from deeplearning_tpu.analysis.jaxpr import hlo_collective_bytes
-            posture["collective_bytes"] = sum(
-                hlo_collective_bytes(aot).values())
+            # parses the compiled step's text: a phase of its own
+            with phase("setup/posture"):
+                posture["collective_bytes"] = sum(
+                    hlo_collective_bytes(aot).values())
         flight.record("sharding", **posture)
     # dltpu: allow(DLT104) posture is observability only, never fail a run
     except Exception:  # noqa: BLE001
