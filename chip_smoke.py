@@ -406,11 +406,12 @@ def phase_kernels(size, platform, cache):
     mask = jnp.asarray(wu.shift_window_mask(w["res"], w["res"], 7, 3))
     bw = w["batch"] * mask.shape[0]
     k1, k2 = jax.random.split(jax.random.key(0))
-    qkv = jax.random.normal(k1, (bw, 49, 3, w["heads"], w["d"]),
+    qkv = jax.random.normal(k1, (bw, 49, 3 * w["heads"] * w["d"]),
                             jnp.bfloat16)
     bias = 0.1 * jax.random.normal(k2, (w["heads"], 49, 49), jnp.float32)
-    fused = jax.jit(window_attention)
-    lax_path = jax.jit(wu.windowed_attention_reference)
+    fused = jax.jit(functools.partial(window_attention, heads=w["heads"]))
+    lax_path = jax.jit(lambda qkv, bias, m: wu.windowed_attention_reference(
+        qkv.reshape(bw, 49, 3, w["heads"], w["d"]), bias, m))
     attn = {}
     for label, m in (("masked", mask), ("unmasked", None)):
         lowered = fused.lower(qkv, bias, m)

@@ -7,10 +7,13 @@ BasicLayer, SwinTransformer (:410-411 gradient checkpointing), and the
 v2 variants (swin_transformer_v2.py: cosine attention with learned
 logit scale, log-spaced continuous position bias MLP).
 
-TPU-first: windows are processed as one batched matmul over
-(windows × heads); the fused Pallas kernel (ops/pallas/window_attention.py)
-replaces the reference's CUDA roll+partition kernel; roll/partition
-themselves are lax ops XLA fuses. NHWC throughout.
+TPU-first: v1 window attention runs the fused Pallas kernels
+(ops/pallas/window_attention.py: scores, bias, mask, softmax and their
+backward stay in VMEM) wherever they compile; v2 cosine attention, a CPU
+backend and the one eager pass of ``model.init`` run the lax path.
+``window_attention.select_path`` makes that choice from what the layer can
+see; there is no flag. Roll and partition are lax ops: XLA turns them into
+layout copies, which the fused path does not remove. NHWC throughout.
 """
 
 from __future__ import annotations
@@ -23,7 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...core.registry import MODELS
+from ...obs import flight
 from ...ops import window_utils as wu
+from ...ops.pallas import window_attention as fused_attention
 from .vit import DropPath, Mlp
 
 
@@ -36,17 +41,21 @@ class WindowAttention(nn.Module):
     qkv_bias: bool = True
     v2: bool = False
     dtype: Any = jnp.bfloat16
-    use_pallas: bool = False
 
     @nn.compact
     def __call__(self, x, mask: Optional[jax.Array] = None,
                  deterministic: bool = True):
         bw, n, c = x.shape
         d = c // self.num_heads
-        if self.v2 and self.use_pallas:
-            raise NotImplementedError(
-                "Pallas fused window attention supports the v1 "
-                "(bias-table) path only; cosine attention runs unfused.")
+        path = fused_attention.select_path(self.v2, self.is_initializing())
+        masked = mask is not None
+        # one flight event per path and per-window shape, whatever the batch
+        # and however often it is traced; ``shape`` is the first one seen
+        flight.tally("kernel", ("window_attention", path, n, c,
+                                self.num_heads, masked),
+                     member="/".join(self.path), name="window_attention",
+                     path=path, shape=[bw, n, self.num_heads, d],
+                     masked=masked)
         if self.v2 and self.qkv_bias:
             # v2 uses q/v biases only: a k bias is NOT softmax-invariant
             # under cosine attention (it shifts keys before normalization).
@@ -62,9 +71,9 @@ class WindowAttention(nn.Module):
         else:
             qkv = nn.Dense(3 * c, use_bias=self.qkv_bias, dtype=self.dtype,
                            name="qkv")(x)
-        qkv = qkv.reshape(bw, n, 3, self.num_heads, d)
 
         if self.v2:
+            qkv = qkv.reshape(bw, n, 3, self.num_heads, d)
             # swin v2: cosine attention + continuous position bias MLP over
             # log-spaced coords (swin_transformer_v2.py surface).
             logit_scale = self.param(
@@ -102,12 +111,12 @@ class WindowAttention(nn.Module):
             idx = wu.relative_position_index(self.window)
             bias = table[idx.reshape(-1)].reshape(n, n, self.num_heads)
             bias = bias.transpose(2, 0, 1)          # (heads, N, N)
-            if self.use_pallas:
-                from ...ops.pallas.window_attention import (
-                    window_attention_checkpointed)
-                out = window_attention_checkpointed(qkv, bias, mask)
+            if path == "fused":
+                out = fused_attention.window_attention(
+                    qkv, bias, mask, heads=self.num_heads)
             else:
-                out = wu.windowed_attention_reference(qkv, bias, mask)
+                out = wu.windowed_attention_reference(
+                    qkv.reshape(bw, n, 3, self.num_heads, d), bias, mask)
 
         out = nn.Dense(c, dtype=self.dtype, name="proj")(out)
         return out
@@ -134,7 +143,6 @@ class SwinBlock(nn.Module):
     drop_path_rate: float = 0.0
     v2: bool = False
     dtype: Any = jnp.bfloat16
-    use_pallas: bool = False
     moe: bool = False                 # MoE MLP (swin_transformer_moe)
     num_experts: int = 8
 
@@ -157,7 +165,7 @@ class SwinBlock(nn.Module):
         wins = wu.window_partition(x, window)          # (B*nW, win², C)
         wins = WindowAttention(self.dim, window, self.num_heads,
                                self.qkv_bias, self.v2, self.dtype,
-                               self.use_pallas, name="attn")(
+                               name="attn")(
             wins, mask, deterministic)
         x = wu.window_merge(wins, window, h, w)
         if shift > 0:
@@ -289,7 +297,6 @@ class SwinTransformer(nn.Module):
     v2: bool = False
     dtype: Any = jnp.bfloat16
     remat: bool = False
-    use_pallas: bool = False
     moe: bool = False                 # MoE MLP in every 2nd block
     num_experts: int = 8
     spatial_mlp: bool = False         # Swin-MLP (swin_mlp.py) blocks
@@ -341,7 +348,6 @@ class SwinTransformer(nn.Module):
                     x = blk(dim, res, heads, self.window, shift,
                             self.mlp_ratio, self.qkv_bias, self.drop_rate,
                             float(dpr[block_idx]), self.v2, self.dtype,
-                            self.use_pallas,
                             self.moe and i % 2 == 1, self.num_experts,
                             name=f"stage{stage}_block{i}")(x, deterministic)
                 block_idx += 1

@@ -28,8 +28,8 @@ import time
 import traceback
 from typing import Any, Dict, List, Optional
 
-__all__ = ["FlightRecorder", "get_recorder", "record", "configure",
-           "dump", "install_signal_handler", "flush_pending"]
+__all__ = ["FlightRecorder", "get_recorder", "record", "tally",
+           "configure", "dump", "install_signal_handler", "flush_pending"]
 
 
 def _jsonable(obj: Any, depth: int = 0) -> Any:
@@ -71,6 +71,7 @@ class FlightRecorder:
         self.config: Optional[Dict[str, Any]] = None
         self.dumps = 0
         self.recorded = 0
+        self._tallies: Dict[Any, Dict[str, Any]] = {}
 
     # ------------------------------------------------------- recording
     def record(self, kind: str, **data: Any) -> None:
@@ -79,6 +80,26 @@ class FlightRecorder:
         with self._lock:
             self.recorded += 1
             self._ring.append(event)
+
+    def tally(self, kind: str, key: Any, member: Optional[str] = None,
+              **data: Any) -> None:
+        """One event per ``key``, however often it happens: the first call
+        records it, every call bumps its ``calls`` in place and adds
+        ``member`` to its ``members`` (who it happened to, each once). For
+        what repeats per layer and per trace — which kernel a layer chose,
+        at what shape — without costing the ring an event each time."""
+        with self._lock:
+            event = self._tallies.get(key)
+            if event is None or not any(e is event for e in self._ring):
+                event = {"kind": kind, "time": time.time(),
+                         "thread": threading.current_thread().name, **data,
+                         "calls": 0, "members": []}
+                self._tallies[key] = event
+                self.recorded += 1
+                self._ring.append(event)
+            event["calls"] += 1
+            if member is not None and member not in event["members"]:
+                event["members"].append(member)
 
     def events(self, kind: Optional[str] = None) -> List[Dict[str, Any]]:
         with self._lock:
@@ -89,6 +110,7 @@ class FlightRecorder:
     def clear(self) -> None:
         with self._lock:
             self._ring.clear()
+            self._tallies.clear()
             self.recorded = 0
 
     # --------------------------------------------------------- dumping
@@ -160,6 +182,12 @@ def get_recorder() -> FlightRecorder:
 def record(kind: str, **data: Any) -> None:
     """Append one event to the default recorder (always cheap/bounded)."""
     _RECORDER.record(kind, **data)
+
+
+def tally(kind: str, key: Any, member: Optional[str] = None,
+          **data: Any) -> None:
+    """One event per ``key`` in the default recorder, counted in place."""
+    _RECORDER.tally(kind, key, member, **data)
 
 
 def configure(path: str, config: Optional[Any] = None) -> FlightRecorder:

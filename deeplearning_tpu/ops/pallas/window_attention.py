@@ -1,25 +1,34 @@
-"""Fused window attention — the Swin CUDA kernel's TPU-era successor.
+"""Fused v1 window attention, forward and backward — Swin's score path
+kept in VMEM.
 
 The reference hand-fuses roll+partition in CUDA (classification/
 swin_transformer/kernels/window_process/swin_window_process_kernel.cu:41-64)
 because torch dispatches each of roll/view/permute as a separate kernel. On
-TPU, XLA already fuses those copies; what XLA does NOT do is keep the
-per-window attention matrix out of HBM. So the Pallas kernel here fuses the
-ATTENTION: for a block of windows at once — QK^T, +relative-position bias,
-+shift mask, softmax, PV — entirely in VMEM, batched over (windows ×
-heads) so the MXU sees one big batched matmul per program.
+the TPU the cost sits elsewhere: the lax path writes the float32 scores
+``(B*nW, heads, N, N)`` to HBM four or five times forward and more backward,
+at a 49-wide minor dimension that the (8, 128) tiling pads threefold. Here
+``QK^T*scale + relative bias + shift mask -> softmax -> PV`` runs per block
+of windows in VMEM, and so does its backward: one ``jax.custom_vjp`` whose
+backward kernel recomputes the softmax from q, k and the combined bias and
+emits ``dqkv`` and the bias gradient, summed over windows inside the kernel
+(a resident accumulator over the grid's second axis, one partial sum per
+mask-row block). No array with an ``N x N`` trailing shape and a ``B*nW``
+leading one reaches HBM; what does is the combined bias + mask, at most
+``max(nW, windows a program)`` windows of it, and the bias gradient's
+partial sums.
 
-Works on pre-partitioned qkv (use ops/window_utils.window_partition, whose
-roll/reshape XLA fuses into the producing matmul's epilogue). The bias and
-shift mask are pre-combined host-side into one additive (nW, heads, Np, Np)
-tensor whose block is selected per program via the index map — no gather in
-the kernel.
+Layout: the kernels read ``qkv`` as the ``(BW, N, 3*C)`` rows the qkv matmul
+wrote and write ``(BW, N, C)`` rows; heads are lane slices inside the kernel.
+A block holds ``slot = 64`` rows of a window: the rows past ``N`` lie outside
+the array, arrive as whatever the buffer held and are zeroed in VMEM (so are
+the windows past ``BW`` in a ragged last block); as keys they carry -1e9 in
+the combined bias and vanish in the softmax, as queries their output rows are
+dropped by the write. Numbers as the lax path has them: ``q*scale`` in the
+input dtype, scores, bias, mask and softmax in float32, ``p`` cast to the
+input dtype for ``PV``, float32 accumulation.
 
-Token count N (e.g. 49) is padded to a sublane multiple; padded KEY
-positions carry -inf in the combined bias so they vanish in the softmax.
-Differentiable via jax.custom_vjp? Not needed: the kernel is re-derived by
-autodiff through a recompute wrapper (window N is tiny; recompute is free
-relative to HBM traffic), see ``window_attention`` below.
+``select_path`` is the one place that chooses between this and
+``ops/window_utils.windowed_attention_reference`` (the oracle).
 """
 
 from __future__ import annotations
@@ -29,115 +38,322 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..window_utils import windowed_attention_reference
 from .common import interpret_mode
 
-
-def _round_up(x: int, m: int) -> int:
-    return (x + m - 1) // m * m
-
-
-def _attn_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, *, scale: float):
-    # blocks: q/k/v (WB, heads, Np, d); bias (WB, heads, Np, Np).
-    # (WB, heads) collapse to ONE batch dim for the dots — Mosaic's
-    # tpu.matmul supports at most one batch dim (leading-dim reshapes are
-    # layout no-ops in VMEM, so this costs nothing)
-    wb, h, npad, d = q_ref.shape
-    q = q_ref[...].reshape(wb * h, npad, d)
-    k = k_ref[...].reshape(wb * h, npad, d)
-    v = v_ref[...].reshape(wb * h, npad, d)
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)          # (WB*heads, Np, Np)
-    s = s * scale + bias_ref[...].reshape(wb * h, npad, npad)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
-    o = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    o_ref[...] = o.reshape(wb, h, npad, d).astype(o_ref.dtype)
+# (pairs of heads) x windows one program unrolls: its code size, its compile
+# time and its live scores. Swin-T at batch 128 on a v5e (PR 26): 16 windows a
+# program is as fast as any size at 3 and 6 heads, 8 at 12 heads; under 8 the
+# programs are too short to fill the VLIW slots
+_UNITS_PER_PROGRAM = 64
+_MASKED = -1e9
+_VMEM_LIMIT = 64 * 2 ** 20
 
 
-def window_attention(qkv: jax.Array, bias: jax.Array,
-                     mask: Optional[jax.Array] = None,
-                     windows_per_block: int = 8) -> jax.Array:
-    """Fused attention over partitioned windows.
+def select_path(v2: bool, initializing: bool = False) -> str:
+    """Which window attention a layer runs, from what the code can see:
+    ``"fused"`` for v1 (bias-table) attention wherever the kernels compile,
+    ``"lax"`` (``windowed_attention_reference`` / the cosine path) for v2, on
+    a CPU backend, where the kernels would run interpreted, and while
+    ``model.init`` runs the layer once, eagerly, at batch 1: a kernel traced,
+    lowered and loaded for that one call costs set-up seconds and nothing is
+    trained or served by it."""
+    return "lax" if v2 or initializing or interpret_mode() else "fused"
 
-    qkv:  (BW, N, 3, heads, d) — BW = batch*num_windows, N = window².
-    bias: (heads, N, N) relative-position bias (trainable).
-    mask: (nW, N, N) additive shift mask or None.
-    Returns (BW, N, heads*d).
-    """
-    bw, n, three, heads, d = qkv.shape
-    assert three == 3
-    np_pad = _round_up(n, 8)
-    nw = mask.shape[0] if mask is not None else 1
-    wb = windows_per_block
-    while wb > 1 and bw % wb:
-        wb //= 2
 
-    # combined additive term, (nW, heads, Np, Np); padded keys get -1e9
-    comb = jnp.broadcast_to(bias[None].astype(jnp.float32),
-                            (nw, heads, n, n))
+def _slot(n: int) -> int:
+    """Rows (and key lanes) a window's ``n`` tokens take in VMEM: 64, so that
+    two heads' scores fill a 128-lane tile; larger windows a multiple of 16."""
+    return max(64, -(-n // 16) * 16)
+
+
+def windows_per_program(bw: int, nw: int, heads: int) -> int:
+    """Windows a program takes: a power of two from 4 to 16 by the number of
+    heads, then either a divisor of ``nw`` (a program stays inside one image's
+    mask rows) or a multiple of it (whole images a program)."""
+    target = min(16, max(4, _UNITS_PER_PROGRAM // -(-heads // 2)))
+    target = 1 << (target.bit_length() - 1)
+    if nw >= target:
+        return max(w for w in range(1, target + 1) if nw % w == 0)
+    return nw * max(1, min(target // nw, bw // nw))
+
+
+def _zero_outside(x, n: int, windows_left):
+    """Zero the rows past ``n`` and the windows past ``windows_left`` of a
+    ``(wb, slot, lanes)`` block: both lie outside the array."""
+    ok = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) < n
+    if windows_left is not None:
+        ok &= jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) < windows_left
+    return jnp.where(ok, x, jnp.zeros_like(x))
+
+
+def _windows_left(bw: int, wb: int, ragged: bool):
+    if not ragged:
+        return None
+    first = (pl.program_id(1) * pl.num_programs(0) + pl.program_id(0)) * wb
+    return bw - first
+
+
+def _stack_heads(x, d: int):
+    """Two heads' rows ``(wb, slot, 2d)`` as the block-diagonal
+    ``(wb, 2*slot, 2d)``: the first head's rows keep its ``d`` lanes, the
+    second's rows its own, so one contraction serves both heads and their
+    cross terms are exact zeros. One head ``(wb, slot, d)``: zero rows stand
+    in for the partner."""
+    zero = jnp.zeros_like(x)
+    if x.shape[-1] == d:
+        return jnp.concatenate([x, zero], axis=1)
+    first = jax.lax.broadcasted_iota(jnp.int32, x.shape, 2) < d
+    return jnp.concatenate([jnp.where(first, x, zero),
+                            jnp.where(first, zero, x)], axis=1)
+
+
+def _unstack_heads(x, d: int):
+    """Each head's own block of a ``(wb, 2*slot, lanes)`` product with a
+    ``_stack_heads`` operand, back as ``(wb, slot, lanes)``."""
+    slot = x.shape[1] // 2
+    if x.shape[-1] == d:
+        return x[:, :slot]
+    first = jax.lax.broadcasted_iota(jnp.int32, (1, slot, x.shape[-1]), 2) < d
+    return jnp.where(first, x[:, :slot], x[:, slot:])
+
+
+def _softmax_over_keys(s):
+    """Softmax down the rows (keys) of ``(wb, keys, queries)`` float32
+    scores: the reductions run across vregs and sublanes, not lanes."""
+    e = jnp.exp(s - jnp.max(s, axis=1, keepdims=True))
+    # the reciprocal on the column sums, not a division of the whole tile
+    return e * (1.0 / jnp.sum(e, axis=1, keepdims=True))
+
+
+def _pair_lanes(heads: int, d: int):
+    """(first lane, width) of each pair of heads inside q's, k's or v's
+    ``C`` lanes; an odd last head stands alone."""
+    return [(h * d, d * min(2, heads - h)) for h in range(0, heads, 2)]
+
+
+_NT = (((2,), (2,)), ((0,), (0,)))      # contract lanes with lanes
+_NN = (((2,), (1,)), ((0,), (0,)))      # lanes with the other's rows
+_TN = (((1,), (1,)), ((0,), (0,)))      # rows with rows
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+# Inside the kernels the scores are held transposed and two heads wide:
+# ``(wb, slot keys, 2*slot queries)``, head A's queries in the first ``slot``
+# lanes and head B's in the rest. Keys down the rows make the softmax's
+# reductions cheap; two heads across fill the 128 lanes that one head's 49
+# queries leave mostly empty, and halve the number of MXU passes. The work of
+# one pair of heads is a jitted function, so that a kernel's trace holds it
+# once however many heads there are (lowering inlines it; the step's set-up
+# time is the reason).
+
+@functools.partial(jax.jit, static_argnames="d")
+def _pair_forward(q, k, v, bias, d):
+    q = _stack_heads(q * (d ** -0.5), d)
+    p = _softmax_over_keys(_dot(k, q, _NT) + bias)
+    return _unstack_heads(_dot(p.astype(v.dtype), v, _TN), d)
+
+
+@functools.partial(jax.jit, static_argnames="d")
+def _pair_backward(q, k, v, do, bias, d):
+    """(dq, dk, dv, the bias gradient summed over the block's windows)."""
+    scale = d ** -0.5
+    q = _stack_heads(q * scale, d)
+    do = _stack_heads(do, d)
+    p = _softmax_over_keys(_dot(k, q, _NT) + bias)
+    dv = _dot(p.astype(do.dtype), do, _NN)
+    dp = _dot(v, do, _NT)
+    ds = p * (dp - jnp.sum(p * dp, axis=1, keepdims=True))
+    dbias = jnp.sum(ds, axis=0)
+    ds = ds.astype(k.dtype)
+    dq = _unstack_heads(_dot(ds, k, _TN), d) * scale
+    return dq, _dot(ds, q, _NN), dv, dbias
+
+
+def _fwd_kernel(qkv_ref, bias_ref, o_ref, *, heads, n, bw, ragged):
+    wb, _, c3 = qkv_ref.shape
+    c = c3 // 3
+    d = c // heads
+    qkv = _zero_outside(qkv_ref[...], n, _windows_left(bw, wb, ragged))
+    for j, (lo, w) in enumerate(_pair_lanes(heads, d)):
+        q, k, v = (qkv[:, :, at + lo:at + lo + w] for at in (0, c, 2 * c))
+        o = _pair_forward(q, k, v, bias_ref[:, j], d=d)
+        o_ref[:, :, lo:lo + w] = o.astype(o_ref.dtype)
+
+
+def _bwd_kernel(qkv_ref, bias_ref, do_ref, dqkv_ref, dbias_ref, *, heads, n,
+                bw, ragged):
+    wb, _, c3 = qkv_ref.shape
+    c = c3 // 3
+    d = c // heads
+    left = _windows_left(bw, wb, ragged)
+    qkv = _zero_outside(qkv_ref[...], n, left)
+    do = _zero_outside(do_ref[...], n, left)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dbias_ref[...] = jnp.zeros_like(dbias_ref)
+
+    for j, (lo, w) in enumerate(_pair_lanes(heads, d)):
+        q, k, v = (qkv[:, :, at + lo:at + lo + w] for at in (0, c, 2 * c))
+        *dqkv, dbias = _pair_backward(q, k, v, do[:, :, lo:lo + w],
+                                      bias_ref[:, j], d=d)
+        dbias_ref[0, j] += dbias
+        for at, grad in zip((0, c, 2 * c), dqkv):
+            dqkv_ref[:, :, at + lo:at + lo + w] = grad.astype(dqkv_ref.dtype)
+
+
+def _pack_bias(comb, slot: int):
+    """``(windows, heads, N queries, N keys)`` additive terms as the kernels
+    read them: ``(windows, pairs, slot keys, 2*slot queries)``, a pair of
+    heads side by side in the lanes. Padded key rows carry -1e9; padded
+    query columns and an odd head's absent partner 0 (their columns of the
+    scores are never read)."""
+    windows, heads, n, _ = comb.shape
+    pairs = -(-heads // 2)
+    comb = jnp.pad(comb, ((0, 0), (0, 0), (0, 0), (0, slot - n)),
+                   constant_values=_MASKED)
+    comb = jnp.pad(comb, ((0, 0), (0, 2 * pairs - heads), (0, slot - n),
+                          (0, 0)))
+    comb = comb.reshape(windows, pairs, 2, slot, slot)
+    # (windows, pairs, head, query, key) -> (windows, pairs, key, head, query)
+    return comb.transpose(0, 1, 4, 2, 3).reshape(windows, pairs, slot,
+                                                 2 * slot)
+
+
+def _unpack_bias(packed, heads: int, n: int):
+    """``_pack_bias``'s layout back to ``(windows, heads, N, N)``."""
+    windows, pairs, slot, _ = packed.shape
+    x = packed.reshape(windows, pairs, slot, 2, slot).transpose(0, 1, 3, 4, 2)
+    return x.reshape(windows, 2 * pairs, slot, slot)[:, :heads, :n, :n]
+
+
+def _combined_bias(bias, mask, slot: int, windows: int):
+    """bias + shift mask as one additive float32 term, packed for the
+    kernels (``windows`` 1 without a mask, else a multiple of ``nW``,
+    tiled)."""
+    comb = bias[None].astype(jnp.float32)
     if mask is not None:
         comb = comb + mask[:, None].astype(jnp.float32)
-    comb = jnp.pad(comb, ((0, 0), (0, 0), (0, np_pad - n),
-                          (0, np_pad - n)), constant_values=-1e9)
-    # tile so a WB-window block always sees its own mask rows: tiling to
-    # lcm(nW, wb) makes block i's rows [(i*wb) % nW, ...] line up with the
-    # index map's (i % (nb/wb)) block selection.
-    if nw % wb:
-        comb = jnp.tile(comb, (int(np.lcm(nw, wb) // nw), 1, 1, 1))
-    nb = comb.shape[0]
+        comb = jnp.tile(comb, (windows // mask.shape[0], 1, 1, 1))
+    return _pack_bias(comb, slot)
 
-    q = jnp.moveaxis(qkv[:, :, 0], 1, 2)   # (BW, heads, N, d)
-    k = jnp.moveaxis(qkv[:, :, 1], 1, 2)
-    v = jnp.moveaxis(qkv[:, :, 2], 1, 2)
-    pad = ((0, 0), (0, 0), (0, np_pad - n), (0, 0))
-    q, k, v = (jnp.pad(t, pad) for t in (q, k, v))
 
-    grid = (bw // wb,)
-    out = pl.pallas_call(
-        functools.partial(_attn_kernel, scale=d ** -0.5),
+def _plan(qkv, mask, heads: int):
+    """Block sizes from the shapes: windows a program, the grid (mask-row
+    block, image block: the second runs fastest, so a mask block is fetched
+    once), the combined bias's window count, and whether the last block is
+    ragged."""
+    bw, n, _ = qkv.shape
+    nw = 1 if mask is None else mask.shape[0]
+    wb = windows_per_program(bw, nw, heads)
+    tile = max(nw, wb)                      # windows of one pass over j
+    grid = (tile // wb, pl.cdiv(bw, tile))
+    return wb, grid, (1 if mask is None else tile), bool(bw % wb)
+
+
+def _specs(wb, c, grid, comb):
+    """Blocks of the qkv rows, of the packed bias (one shared block without
+    a mask) and of the output rows."""
+    nj = grid[0]
+    windows, pairs, slot, lanes = comb.shape
+    shared = windows == 1
+    rows = lambda width: pl.BlockSpec(           # noqa: E731
+        (wb, slot, width), lambda j, b: (b * nj + j, 0, 0))
+    bias = pl.BlockSpec(
+        (1 if shared else wb, pairs, slot, lanes),
+        (lambda j, b: (0, 0, 0, 0)) if shared
+        else (lambda j, b: (j, 0, 0, 0)))
+    return rows(3 * c), bias, rows(c)
+
+
+# jitted (as ``window_attention`` is) so that a stage's blocks share one trace
+# and one lowering of their kernel: the step's set-up time again
+@functools.partial(jax.jit, static_argnames=("heads", "plan"))
+def _forward(qkv, comb, heads, plan):
+    wb, grid, _, ragged = plan
+    bw, n, c3 = qkv.shape
+    c = c3 // 3
+    qkv_spec, bias_spec, out_spec = _specs(wb, c, grid, comb)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, heads=heads, n=n, bw=bw,
+                          ragged=ragged),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((wb, heads, np_pad, d), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((wb, heads, np_pad, d), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((wb, heads, np_pad, d), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((wb, heads, np_pad, np_pad),
-                         lambda i, _nb=nb // wb: (i % _nb, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((wb, heads, np_pad, d),
-                               lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bw, heads, np_pad, d), qkv.dtype),
+        in_specs=[qkv_spec, bias_spec],
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct((bw, n, c), qkv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret_mode(),
-    )(q, k, v, comb)
-    out = out[:, :, :n, :]                  # drop padded query rows
-    return jnp.moveaxis(out, 1, 2).reshape(bw, n, heads * d)
+        name="window_attention_fwd",
+    )(qkv, comb)
 
 
-def window_attention_checkpointed(qkv, bias, mask=None, **kw):
-    """Differentiable wrapper: forward runs the fused kernel; the custom
-    VJP recomputes the backward through the lax reference (which DOES
-    materialize per-window P matrices during the bwd pass — the fused
-    saving applies to the forward only)."""
+@functools.partial(jax.jit, static_argnames=("heads", "plan"))
+def _backward(qkv, comb, g, heads, plan):
+    wb, grid, _, ragged = plan
+    bw, n, c3 = qkv.shape
+    c = c3 // 3
+    qkv_spec, bias_spec, out_spec = _specs(wb, c, grid, comb)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=heads, n=n, bw=bw,
+                          ragged=ragged),
+        grid=grid,
+        in_specs=[qkv_spec, bias_spec, out_spec],
+        out_specs=[qkv_spec,
+                   pl.BlockSpec((1,) + comb.shape[1:],
+                                lambda j, b: (j, 0, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+                   jax.ShapeDtypeStruct((grid[0],) + comb.shape[1:],
+                                        jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret_mode(),
+        name="window_attention_bwd",
+    )(qkv, comb, g)
 
-    @jax.custom_vjp
-    def f(qkv, bias):
-        return window_attention(qkv, bias, mask, **kw)
 
-    def fwd(qkv, bias):
-        return f(qkv, bias), (qkv, bias)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _attend(qkv, bias, mask, heads):
+    return _attend_fwd(qkv, bias, mask, heads)[0]
 
-    def bwd(res, g):
-        qkv, bias = res
-        _, vjp = jax.vjp(
-            lambda a, b: windowed_attention_reference(a, b, mask), qkv, bias)
-        return vjp(g)
 
-    f.defvjp(fwd, bwd)
-    return f(qkv, bias)
+def _attend_fwd(qkv, bias, mask, heads):
+    plan = _plan(qkv, mask, heads)
+    comb = _combined_bias(bias, mask, _slot(qkv.shape[1]), plan[2])
+    return _forward(qkv, comb, heads, plan), (qkv, comb, bias, mask)
+
+
+def _attend_bwd(heads, residuals, g):
+    qkv, comb, bias, mask = residuals
+    n = qkv.shape[1]
+    dqkv, dbias = _backward(qkv, comb, g, heads, _plan(qkv, mask, heads))
+    dbias = jnp.sum(_unpack_bias(dbias, heads, n), axis=0).astype(bias.dtype)
+    return dqkv, dbias, None if mask is None else jnp.zeros_like(mask)
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+@functools.partial(jax.jit, static_argnames="heads")
+def window_attention(qkv: jax.Array, bias: jax.Array,
+                     mask: Optional[jax.Array] = None, *,
+                     heads: int) -> jax.Array:
+    """Fused attention over partitioned windows, differentiable in ``qkv``
+    and ``bias``.
+
+    qkv:  (BW, N, 3*C) — BW = batch*num_windows, N = window², the lanes
+          ordered (q | k | v) x heads x d as ``nn.Dense(3*C)`` writes them.
+    bias: (heads, N, N) relative-position bias (trainable).
+    mask: (nW, N, N) additive shift mask or None; window ``i`` takes row
+          ``i % nW``.
+    Returns (BW, N, C).
+    """
+    return _attend(qkv, bias, mask, heads)

@@ -56,9 +56,18 @@ def compiled_mode(monkeypatch):
         monkeypatch.setattr(module, "interpret_mode", lambda: False)
 
 
-def _window_case(windows, heads, n_mask, dtype):
-    return (window.window_attention,
-            [((windows, 49, 3, heads, 32), dtype),
+def _window_case(windows, heads, n_mask, dtype, grad=False):
+    """Fused window attention at a Swin stage's shape, forward or forward +
+    the fused backward (``dqkv`` and the bias gradient)."""
+    def forward(qkv, bias, mask=None):
+        return window.window_attention(qkv, bias, mask, heads=heads)
+
+    def backward(qkv, bias, mask=None):
+        return jax.grad(lambda a, b: jnp.sum(
+            forward(a, b, mask).astype(jnp.float32)), (0, 1))(qkv, bias)
+
+    return (backward if grad else forward,
+            [((windows, 49, 3 * heads * 32), dtype),
              ((heads, 49, 49), jnp.float32)]
             + ([((n_mask, 49, 49), jnp.float32)] if n_mask else []))
 
@@ -80,6 +89,15 @@ CASES = {
     "window_swin_t_s1_f32": _window_case(2048, 3, 0, jnp.float32),
     # Swin-B stage 3 at batch 32: 14x14 tokens, 16 heads of 32
     "window_swin_b_s3_bf16_masked": _window_case(128, 16, 4, jnp.bfloat16),
+    # Swin-T at batch 128, the benchmark's cell, forward + the fused backward:
+    # the first stage (3 heads: an odd one), the third (12 heads) and the
+    # last (24 heads, no mask)
+    "window_swin_t_s0_b128_grad_masked": _window_case(
+        8192, 3, 64, jnp.bfloat16, grad=True),
+    "window_swin_t_s2_b128_grad_masked": _window_case(
+        512, 12, 4, jnp.bfloat16, grad=True),
+    "window_swin_t_s3_b128_grad": _window_case(
+        128, 24, 0, jnp.bfloat16, grad=True),
     "nms_pallas_n1024": (_NMS, [((1024, 4), jnp.float32),
                                 ((1024,), jnp.float32)]),
     "nms_pallas_n4096": (_NMS, [((4096, 4), jnp.float32),

@@ -150,7 +150,7 @@ def stage3_attn_micro():
 
 def stage4_window():
     from deeplearning_tpu.ops.pallas.window_attention import (
-        window_attention, window_attention_checkpointed)
+        window_attention)
 
     # Swin-B stage-1 training shape: 224/4=56 → 64 windows of 7²=49
     # tokens, 4 heads d=32 (dim 128), batch 64 → BW=4096
@@ -169,8 +169,11 @@ def stage4_window():
         o = jnp.einsum("bhnm,bhmd->bhnd", p, v)
         return jnp.moveaxis(o, 1, 2).reshape(bw, n, heads * d)
 
-    variants = [("lax", lax_path), ("pallas", window_attention),
-                ("pallas_ckpt", window_attention_checkpointed)]
+    def fused(qkv, bias):
+        return window_attention(qkv.reshape(bw, n, 3 * heads * d), bias,
+                                heads=heads)
+
+    variants = [("lax", lax_path), ("pallas", fused)]
     for name, fn in variants:
         try:
             dt = bench(jax.jit(fn), (qkv, bias)) * 1e3
@@ -178,8 +181,7 @@ def stage4_window():
         except Exception as e:                       # noqa: BLE001
             print(f"[window fwd {name}] FAILED: {e}", flush=True)
     # training path: fwd+bwd through each variant
-    for name, fn in [("lax", lax_path),
-                     ("pallas_ckpt", window_attention_checkpointed)]:
+    for name, fn in variants:
         try:
             # grad w.r.t. qkv AND the trainable relative-position bias
             g = jax.jit(jax.grad(
