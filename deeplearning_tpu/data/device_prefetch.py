@@ -16,7 +16,15 @@ across epochs. It is also the single place that owns the transfer: when
 the wrapped loader is a ``DataLoader`` with a mesh, the prefetcher takes
 over its device-put (``loader.device_transfer = False``) so batches are
 transferred exactly once, on the worker thread (the double-transfer
-``build.py`` used to do is structurally impossible here).
+``build.py`` used to do is structurally impossible here). With the
+transfer it takes over the loader's ``device_transform``: the batch goes
+over the wire as the loader made it (uint8 images, a quarter of float32's
+bytes) and one jitted call makes it the batch the step was compiled for,
+with the put's sharding. That call is dispatched on the CONSUMER's side,
+as ``__iter__`` hands the batch over, not by the worker: a second
+dispatcher only queues behind the steps the training thread already has
+in flight, and on a v5e that wait read as 75-137 ms of ``feed/h2d`` a
+batch where the put itself takes under 1 ms (PERF.md, PR 28).
 
 Telemetry (feeds Trainer ``data_time``/``throughput_stats``):
 - ``last_data_wait`` / ``data_wait_total``: time the CONSUMER actually
@@ -29,8 +37,9 @@ Telemetry (feeds Trainer ``data_time``/``throughput_stats``):
   at every epoch end); ``totals()`` never resets.
 
 Timeline (when the span ring is on): the worker numbers its batches and
-records ``feed/decode``, ``feed/h2d`` and ``feed/put_wait`` (blocked on a
-full queue) with ``batch=<n>`` — the three tile its loop — and
+records ``feed/decode`` (the loader's ``next``: the gather or the
+per-sample fetches, and its host ``transform``), ``feed/h2d`` (the put)
+and ``feed/put_wait`` (blocked on a full queue) with ``batch=<n>`` — the three tile its loop — and
 ``last_batch`` is the number of the batch the consumer last received, which
 the Trainer puts on its ``data_wait`` span: one identifier from decode to
 the step that used the batch.
@@ -93,10 +102,15 @@ class DevicePrefetcher:
         if mesh is None and sharding is None:
             mesh = getattr(loader, "mesh", None)
         self.mesh = mesh
+        # what the loader would run on the batch after its own transfer;
+        # __iter__ dispatches it (see the module docstring for why)
+        self._device_transform = None
         if self.mesh is not None and \
                 getattr(loader, "device_transfer", None) is True and \
                 getattr(loader, "mesh", None) is self.mesh:
             loader.device_transfer = False
+            self._device_transform = getattr(loader, "device_transform",
+                                             None)
         self.epoch = getattr(loader, "epoch", 0)
         # consumer-side starvation telemetry (the DataLoader surface)
         self.last_data_wait: Optional[float] = None
@@ -269,6 +283,8 @@ class DevicePrefetcher:
                 self._occ_n += 1
                 self.batches_fed += 1
                 self.last_batch, batch = item
+                if self._device_transform is not None:
+                    batch = self._device_transform(batch)
                 yield batch
         finally:
             self._shutdown(pipe)

@@ -7,6 +7,18 @@ loads ONLY its slice of the global batch (the DistributedSampler
 successor), batches are fixed-shape (drop_last semantics so jit never
 retraces), and ``prefetch_to_device`` overlaps host→HBM transfer with
 compute — the DataPrefetcher analog without CUDA streams.
+
+Two routes make a batch. An ``ArraySource`` keeps its arrays in their
+storage dtype and hands over a batch in ONE fancy index (``images[idx]``);
+a ``MapSource``, a ``num_workers`` pool and a loader with a ``quarantine``
+log fetch sample by sample and stack. What a batch still needs once it is
+on the device — uint8 images scaled to float32, ``ScaleUint8`` — is the
+loader's ``device_transform``: one jitted call a batch, made after the
+transfer by whoever hands the batch to the consumer (the loader, or the
+``DevicePrefetcher`` that took the transfer over), so a quarter of the
+bytes cross the wire and ``element_spec`` still describes the batch the
+consumer gets. Each loader
+tallies one ``feed`` flight event that says which route its batches took.
 """
 
 from __future__ import annotations
@@ -16,16 +28,20 @@ import itertools
 from typing import Any, Callable, Dict, Iterator, Optional, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..elastic import faults
+from ..obs import flight
 from ..parallel.sharding import batch_spec, make_global_array
 from .quarantine import PoisonedData, QuarantineLog, quarantinable
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 class ArraySource:
-    """In-memory dataset of parallel arrays (images, labels, ...)."""
+    """In-memory dataset of parallel arrays (images, labels, ...), kept
+    in their storage dtype: an index array gathers a whole batch in one
+    call, an int one sample."""
 
     def __init__(self, **arrays: np.ndarray):
         sizes = {k: len(v) for k, v in arrays.items()}
@@ -59,6 +75,44 @@ class MapSource:
         return {k: np.stack([s[k] for s in samples]) for k in samples[0]}
 
 
+@jax.jit
+def _uint8_to_unit(x, r, c):
+    # TPU f32 division is not correctly rounded (255 of the 256 values
+    # differ from numpy's on a v5e) and XLA turns ``x / 255`` into
+    # ``x * (1 / 255)`` (126 differ, CPU and TPU). So: q = x * r, then
+    # the remainder x - 255 q = (x - 256 q) + q, exact because both
+    # operands of each sum lie within a factor two of each other, then
+    # q + rem * r. r and c are operands, not constants: XLA would fold
+    # ``(x * r) * 256`` into one product and the CPU contract it with
+    # the subtraction, which loses q's own rounding.
+    f = x.astype(jnp.float32)
+    q = f * r
+    rem = (f - q * c) + q
+    return q + rem * r
+
+
+def uint8_to_unit(x) -> jax.Array:
+    """uint8 → float32 in [0, 1] on the device ``x`` is on, keeping its
+    sharding: bit for bit ``x.astype(np.float32) / np.float32(255)`` as
+    numpy computes it, for all 256 values, on the CPU and on a TPU."""
+    return _uint8_to_unit(x, np.float32(1.0 / 255.0), np.float32(256.0))
+
+
+class ScaleUint8:
+    """``device_transform`` of a loader whose ``key`` leaf travels as
+    uint8: one jitted call a batch makes it the float32 image in [0, 1]
+    the step was compiled for."""
+
+    def __init__(self, key: str = "image"):
+        self.key = key
+
+    def __call__(self, batch: Dict[str, Any]) -> Dict[str, Any]:
+        return {**batch, self.key: uint8_to_unit(batch[self.key])}
+
+
+_FEED_IDS = itertools.count()    # one ``feed`` flight event per loader
+
+
 def epoch_indices(size: int, *, shuffle: bool, seed: int, epoch: int,
                   drop_last_to: Optional[int] = None) -> np.ndarray:
     """Deterministic per-epoch permutation — sampler.set_epoch(epoch)
@@ -78,11 +132,15 @@ class DataLoader:
       materializes only its ``global_batch / process_count`` slice.
     - with a mesh, batches are assembled into global jax.Arrays sharded
       over the data axes (multi-host DP); without, plain numpy dicts.
+    - ``transform`` works on the host batch, ``device_transform`` (a
+      jitted batch → batch function, ``ScaleUint8`` say) on the batch once
+      it is on the device; ``element_spec`` describes what comes out of it.
     """
 
     def __init__(self, source, global_batch: int, *, shuffle: bool = True,
                  seed: int = 0, mesh: Optional[Mesh] = None,
                  transform: Optional[Callable[[Dict], Dict]] = None,
+                 device_transform: Optional[Callable[[Dict], Dict]] = None,
                  infinite: bool = False, num_workers: int = 0,
                  lookahead: int = 4, quarantine=None):
         self.source = source
@@ -91,6 +149,8 @@ class DataLoader:
         self.seed = seed
         self.mesh = mesh
         self.transform = transform
+        self.device_transform = device_transform
+        self._feed_id = next(_FEED_IDS)
         self.infinite = infinite
         self.epoch = 0
         self.num_workers = num_workers
@@ -157,17 +217,40 @@ class DataLoader:
     def _finalize(self, batch: Dict[str, Any]) -> Dict[str, Any]:
         if self.transform:
             batch = self.transform(batch)
-        if self.mesh is not None and self.device_transfer:
-            batch = {k: make_global_array(np.asarray(v), self.mesh)
-                     for k, v in batch.items()}
+        self._tally_feed(batch)
+        if self.device_transfer:
+            if self.mesh is not None:
+                batch = {k: make_global_array(np.asarray(v), self.mesh)
+                         for k, v in batch.items()}
+            if self.device_transform is not None:
+                batch = self.device_transform(batch)
         return batch
+
+    def _tally_feed(self, batch: Dict[str, Any]) -> None:
+        """This loader's one ``feed`` flight event, its batches counted
+        in place (``calls``): the route they take and what goes over the
+        wire, so ``flightrec.json`` says what a run did without a trace."""
+        gathered = (isinstance(self.source, ArraySource)
+                    and self.quarantine is None and not self.num_workers)
+        wire = {k: v if hasattr(v, "nbytes") else np.asarray(v)
+                for k, v in batch.items()}
+        flight.tally(
+            "feed", ("feed", self._feed_id),
+            route="array_gather" if gathered else "per_sample",
+            scaled_on="device" if self.device_transform is not None
+            else "host",
+            wire_dtype={k: str(v.dtype) for k, v in wire.items()},
+            wire_bytes=sum(v.nbytes for v in wire.values()),
+            batch=self.global_batch)
 
     def element_spec(self) -> Optional[Dict[str, jax.ShapeDtypeStruct]]:
         """Abstract (shape, dtype, sharding) of one yielded batch — the
         AOT-warmup surface: ``Trainer.precompile()`` lowers the jitted
         step against these without materializing any data. Derived from
-        ONE source sample pushed through ``transform``, so it costs a
-        single decode, not a batch."""
+        ONE source sample pushed through ``transform`` and, abstractly,
+        through ``device_transform`` (a uint8 source still specs the
+        float32 batch the step gets), so it costs a single decode, not a
+        batch."""
         try:
             first = int(next(iter(self._local_indices(self.epoch)))[0])
         except StopIteration:       # fewer samples than one global batch
@@ -183,14 +266,16 @@ class DataLoader:
         lead = self.global_batch if self.mesh is not None else \
             self.host_batch
 
-        def spec(v):
-            v = np.asarray(v)
-            shape = (lead, *v.shape[1:])
-            if sharding is not None:
-                return jax.ShapeDtypeStruct(shape, v.dtype,
-                                            sharding=sharding)
-            return jax.ShapeDtypeStruct(shape, v.dtype)
-        return {k: spec(v) for k, v in sample.items()}
+        out = {k: jax.ShapeDtypeStruct((lead, *np.shape(v)[1:]),
+                                       np.asarray(v).dtype)
+               for k, v in sample.items()}
+        if self.device_transform is not None:
+            out = jax.eval_shape(self.device_transform, out)
+        if sharding is not None:
+            out = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                           sharding=sharding)
+                   for k, v in out.items()}
+        return out
 
     # ------------------------------------------------ per-sample fetch
     def _fetch_one(self, i: int) -> Dict[str, np.ndarray]:

@@ -382,9 +382,13 @@ class TestTrainerTraceAcceptance:
         args = compile_spans[0]["args"]
         assert args["flops"] > 0
         assert args["seconds"] >= 0
-        # feed stats reached the flight ring while it ran
+        # feed stats reached the flight ring while it ran, an event an
+        # epoch, beside each loader's one tally of the route its batches took
         feed_events = flight.get_recorder().events("feed")
-        assert feed_events and feed_events[0]["batches_fed"] == 5.0
+        epochs = [e for e in feed_events if "epoch" in e]
+        assert epochs and epochs[0]["batches_fed"] == 5.0
+        routes = [e for e in feed_events if "route" in e]
+        assert routes and routes[0]["calls"] >= 5
 
     def test_obs_report_renders_the_run(self, tmp_path):
         run_dir = str(tmp_path / "run")

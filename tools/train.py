@@ -101,7 +101,8 @@ def load_data(cfg: DataCfg, num_classes: int
     if cfg.npz:
         blob = np.load(cfg.npz)
         # raw storage (often uint8 single-channel); conversion to model
-        # f32/RGB happens per-sample in the loader source, NOT here — an
+        # f32/RGB happens a batch at a time in the feed (_build_loaders:
+        # channels on the host, uint8 scaled on the device), NOT here — an
         # eager convert would hold a 12x float copy of the whole dataset
         return blob["images"], blob["labels"]
     rng = np.random.default_rng(0)
@@ -117,7 +118,7 @@ def load_data(cfg: DataCfg, num_classes: int
 def _build_loaders(cfg: Config, mesh):
     """Train and eval loaders from the folder, the npz or the synthetic
     set: ``(loader, eval_loader, sample_shape, n_train)``."""
-    from deeplearning_tpu.data import ArraySource, DataLoader
+    from deeplearning_tpu.data import ArraySource, DataLoader, ScaleUint8
 
     if cfg.data.folder:
         from deeplearning_tpu.data.build import (LoaderConfig,
@@ -161,33 +162,32 @@ def _build_loaders(cfg: Config, mesh):
                                 labels[order[n_val:]])
     n_train = len(tr_images)
 
-    def _cls_source(imgs, labs):
-        """Per-sample uint8→f32 + channel expansion (lazy, so the
-        dataset stays in its compact storage dtype in RAM)."""
-        needs = (imgs.dtype == np.uint8 or imgs.ndim == 3
-                 or imgs.shape[-1] != cfg.data.channels)
-        if not needs:
-            return ArraySource(image=imgs, label=labs)
-        from deeplearning_tpu.data.loader import MapSource
-
-        def fetch(i):
-            img = imgs[i]
-            if img.dtype == np.uint8:
-                img = img.astype(np.float32) / 255.0
-            if img.ndim == 2:
+    def _cls_loader(imgs, labs, **kw):
+        """The set stays in its storage dtype inside an ``ArraySource``
+        and a batch is one gather. Grey-scale ``(N, H, W)`` sets and
+        1 → 3 channels are expanded a batch at a time on the host;
+        uint8 images cross the wire as uint8 and are scaled to float32
+        in [0, 1] on the device (``ScaleUint8``), so the step, mixup
+        and the eval step get the float32 batch they always got."""
+        def expand(batch):
+            img = batch["image"]
+            if img.dtype != np.uint8:
+                img = np.asarray(img, np.float32)
+            if img.ndim == 3:
                 img = img[..., None]
             if img.shape[-1] == 1 and cfg.data.channels == 3:
                 img = np.repeat(img, 3, axis=-1)
-            return {"image": np.asarray(img, np.float32),
-                    "label": labs[i]}
-        return MapSource(len(imgs), fetch)
+            return {**batch, "image": img}
+        is_u8 = imgs.dtype == np.uint8
+        needs = imgs.ndim == 3 or imgs.shape[-1] != cfg.data.channels
+        return DataLoader(ArraySource(image=imgs, label=labs),
+                          global_batch=cfg.data.global_batch, mesh=mesh,
+                          transform=expand if needs else None,
+                          device_transform=ScaleUint8() if is_u8 else None,
+                          **kw)
 
-    loader = DataLoader(_cls_source(tr_images, tr_labels),
-                        global_batch=cfg.data.global_batch, mesh=mesh,
-                        seed=cfg.train.seed)
-    eval_loader = DataLoader(_cls_source(ev_images, ev_labels),
-                             global_batch=cfg.data.global_batch,
-                             mesh=mesh, shuffle=False)
+    loader = _cls_loader(tr_images, tr_labels, seed=cfg.train.seed)
+    eval_loader = _cls_loader(ev_images, ev_labels, shuffle=False)
     return loader, eval_loader, sample_shape, n_train
 
 
