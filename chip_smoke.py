@@ -32,7 +32,9 @@ and synthetic data from the seed):
           answer against a direct ``model.apply`` on the same image.
 - kernels ``nms(impl="auto")`` under ``jax.vmap`` at YOLOX-s's 8,400
           candidates against ``nms_reference``; the fused Pallas
-          ``window_attention`` at Swin-T stage 1 against the lax path.
+          ``window_attention`` at Swin-T stage 1 and ``global_attention``
+          at ViT-B/16's 197 tokens (output and ``dqkv``), each against its
+          lax path.
 - dp      (``--chips 4`` only) the train phase's steps on a 4-way data
           mesh against the same steps on one chip, same seed, data and
           global batch; then a few steps at 128 images a chip.
@@ -64,6 +66,8 @@ FULL = {
     "nms": {"batch": 8, "n": 8400, "span": 640.0, "wh_max": 96.0},
     # Swin-T stage 1 at batch 32: 56x56 tokens in 7x7 windows
     "window": {"batch": 32, "res": 56, "heads": 3, "d": 32},
+    # ViT-B/16 at batch 30: 197 tokens, a ragged last block of images
+    "global": {"batch": 30, "tokens": 197, "heads": 12, "d": 64},
     "dp_user_batch": 512,
 }
 TINY = {
@@ -74,6 +78,7 @@ TINY = {
               "--size", "56"],
     "nms": {"batch": 2, "n": 1100, "span": 96.0, "wh_max": 24.0},
     "window": {"batch": 4, "res": 14, "heads": 3, "d": 32},
+    "global": {"batch": 3, "tokens": 50, "heads": 4, "d": 32},
     "dp_user_batch": 64,
 }
 
@@ -81,7 +86,7 @@ TINY = {
 # bits, 2**-8 = 3.9e-3 relative per rounding) by two differently fused
 # programs of the same mathematics.
 PROB_RTOL = 2e-2       # served softmax row vs direct model.apply
-ATTN_TOL = 3e-2        # fused vs lax window attention, outputs O(1)
+ATTN_TOL = 3e-2        # fused vs lax window / global attention, outputs O(1)
 DP_LOSS_ATOL = 1e-2    # per-step loss, 4-way mesh vs one chip (loss ~6.9)
 DP_GNORM_RTOL = 5e-2   # per-step global grad norm, same pair
 
@@ -429,14 +434,55 @@ def phase_kernels(size, platform, cache):
         attn[label] = {"compile_s": round(compile_s, 2), "run_s": run_s,
                        "max_err": err}
 
+    # --- global attention: fused kernels vs the lax path vit.py keeps
+    from deeplearning_tpu.models.classification.vit import \
+        dot_product_attention
+    from deeplearning_tpu.ops.pallas.global_attention import global_attention
+    gl = size["global"]
+    heads, d = gl["heads"], gl["d"]
+    rows = (gl["batch"], gl["tokens"])
+    k1, k2 = jax.random.split(jax.random.key(1))
+    qkv = jax.random.normal(k1, rows + (3 * heads * d,), jnp.bfloat16)
+    weight = jax.random.normal(k2, rows + (heads * d,), jnp.bfloat16)
+
+    def lax_attention(qkv):
+        x = qkv.reshape(rows + (3, heads, d))
+        return dot_product_attention(x[:, :, 0], x[:, :, 1],
+                                     x[:, :, 2]).reshape(weight.shape)
+
+    def with_grad(attend):
+        def run(a, w):
+            out, vjp = jax.vjp(attend, a)
+            return out, vjp(w)[0]
+        return jax.jit(run)
+
+    lowered = with_grad(functools.partial(
+        global_attention, heads=heads)).lower(qkv, weight)
+    in_program = lowered.as_text().count("tpu_custom_call") >= 2
+    check(in_program == on_tpu, f"global attention on {platform}: both "
+          f"Pallas kernels in the program is {in_program}")
+    got, glob_compile_s, glob_run_s = compile_and_time(lowered, qkv, weight)
+    glob = {"compile_s": round(glob_compile_s, 2), "run_s": glob_run_s}
+    for label, out, ref in zip(("out", "dqkv"), got,
+                               with_grad(lax_attention)(qkv, weight)):
+        out, ref = (np.asarray(x, np.float32) for x in (out, ref))
+        check(np.isfinite(out).all(), f"global attention {label}: "
+              "non-finite")
+        err = float(np.max(np.abs(out - ref) / (1.0 + np.abs(ref))))
+        check(err <= ATTN_TOL, f"global attention {label}: off by "
+              f"{err:.3g} (tolerance {ATTN_TOL})")
+        glob[f"{label}_max_err"] = err
+
     emit(phase="kernels", interpret_mode=interpret_mode(),
          nms={"impl": "auto", "shape": [c["batch"], c["n"]],
               "pallas_in_program": on_tpu, "kept": kept,
               "compile_s": round(nms_compile_s, 2), "run_s": nms_run_s,
               "matches_reference": True,
               "also_checked": sorted(set(impls) - {"auto"})},
-         window_attention={"shape": list(qkv.shape), "tol": ATTN_TOL,
-                           **attn},
+         window_attention={"shape": [bw, 49, 3 * w["heads"] * w["d"]],
+                           "tol": ATTN_TOL, **attn},
+         global_attention={"shape": list(qkv.shape), "tol": ATTN_TOL,
+                           **glob},
          jax_cache=cache.take(), peak_bytes_in_use=peak_bytes())
 
 

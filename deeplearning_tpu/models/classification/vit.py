@@ -8,8 +8,11 @@ factories (:290-358: B/16, B/32, L/16, L/32, H/14).
 
 TPU-first design choices (not in the reference):
 - bf16 compute / f32 params; logits returned f32.
-- attention is a pluggable callable so the Pallas flash-attention kernel
-  (ops/pallas) can replace the naive softmax path at scale.
+- on a TPU the attention core runs as two fused Pallas kernels, forward and
+  backward (ops/pallas/global_attention.py), wherever its ``select_path``
+  says the shape is covered; ``dot_product_attention`` below is the oracle
+  and the path for everything else. Attention is still a pluggable callable
+  (``attn_fn``): ring and Ulysses attention take that slot.
 - ``remat`` wraps each Block with jax.checkpoint (the torch
   gradient-checkpointing analog, swin_transformer.py:410-411) to trade
   FLOPs for HBM.
@@ -27,6 +30,8 @@ import jax.numpy as jnp
 
 from ...core import numerics
 from ...core.registry import MODELS
+from ...obs import flight
+from ...ops.pallas import global_attention as fused_attention
 
 
 def drop_path(x: jax.Array, rate: float, deterministic: bool,
@@ -95,8 +100,9 @@ class _PatchProj(nn.Module):
 
 def dot_product_attention(q, k, v, dropout_rate=0.0, deterministic=True,
                           rng=None):
-    """Naive softmax attention — the lax reference path the Pallas kernel is
-    tested against. q,k,v: (B, N, H, D)."""
+    """Naive softmax attention — the lax reference path the Pallas kernels
+    (ops/pallas/global_attention.py) are tested against, and the path of
+    every shape they do not cover. q,k,v: (B, N, H, D)."""
     scale = q.shape[-1] ** -0.5
     attn = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k)
     attn = jax.nn.softmax(attn.astype(jnp.float32), axis=-1).astype(q.dtype)
@@ -119,16 +125,28 @@ class Attention(nn.Module):
     def __call__(self, x, deterministic: bool = True):
         b, n, c = x.shape
         head_dim = c // self.num_heads
+        dropout = self.attn_drop > 0 and not deterministic
+        path = fused_attention.select_path(
+            n, head_dim, dropout=dropout, injected=self.attn_fn is not None,
+            initializing=self.is_initializing())
+        # one flight event per path and shape, whatever the batch and however
+        # often it is traced; ``shape`` is the first one seen
+        flight.tally("kernel", ("attention", path, n, self.num_heads,
+                                head_dim),
+                     member="/".join(self.path), name="attention", path=path,
+                     shape=[b, n, self.num_heads, head_dim])
         qkv = nn.Dense(3 * c, use_bias=self.qkv_bias, dtype=self.dtype,
                        name="qkv")(x)
-        qkv = qkv.reshape(b, n, 3, self.num_heads, head_dim)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        fn = self.attn_fn or dot_product_attention
-        rng = (self.make_rng("dropout")
-               if (self.attn_drop > 0 and not deterministic) else None)
-        out = fn(q, k, v, dropout_rate=self.attn_drop,
-                 deterministic=deterministic, rng=rng)
-        out = out.reshape(b, n, c)
+        if path == "fused":
+            out = fused_attention.global_attention(qkv, heads=self.num_heads)
+        else:
+            qkv = qkv.reshape(b, n, 3, self.num_heads, head_dim)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            fn = self.attn_fn or dot_product_attention
+            rng = self.make_rng("dropout") if dropout else None
+            out = fn(q, k, v, dropout_rate=self.attn_drop,
+                     deterministic=deterministic, rng=rng)
+            out = out.reshape(b, n, c)
         out = nn.Dense(c, dtype=self.dtype, name="proj")(out)
         out = nn.Dropout(self.proj_drop, deterministic=deterministic)(out)
         return out

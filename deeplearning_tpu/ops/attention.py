@@ -1,10 +1,17 @@
-"""Attention dispatch: naive lax path vs Pallas flash kernel.
+"""Attention adapters for the models' ``attn_fn`` slot.
 
 ``get_attn_fn("flash")`` plugs into models' ``attn_fn`` slot
 (models/classification/vit.py Attention). The naive path is the golden
-reference; the flash path is the TPU production path. Attention dropout is
-applied on the naive path only — flash attention ignores it (attn-dropout
-is 0 in all reference training configs; ViT uses drop_path instead).
+reference. None of these adapters is a production path: on a TPU the
+ViT attention core runs the fused kernels of
+``ops/pallas/global_attention.py`` (chosen by its ``select_path``; an
+injected ``attn_fn`` turns them off), and in the ViT-B/16 training cell
+the head-batched flash kernel and ``jax.nn.dot_product_attention`` both
+lose to the naive path (steps of 189.4 and 173.3 ms against 144.8;
+PERF.md, PR 29). They stay for the ring (``use_flash``) until ROADMAP D3
+deletes them. Attention dropout is applied on the naive path only; the
+adapters refuse it (attn-dropout is 0 in all reference training configs;
+ViT uses drop_path instead).
 """
 
 from __future__ import annotations

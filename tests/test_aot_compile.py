@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 
 from deeplearning_tpu.ops.pallas import flash_attention as flash
+from deeplearning_tpu.ops.pallas import global_attention as global_attn
 from deeplearning_tpu.ops.pallas import nms as pallas_nms
 from deeplearning_tpu.ops.pallas import window_attention as window
 
@@ -52,7 +53,7 @@ def chip():
 
 @pytest.fixture
 def compiled_mode(monkeypatch):
-    for module in (flash, pallas_nms, window):
+    for module in (flash, global_attn, pallas_nms, window):
         monkeypatch.setattr(module, "interpret_mode", lambda: False)
 
 
@@ -70,6 +71,20 @@ def _window_case(windows, heads, n_mask, dtype, grad=False):
             [((windows, 49, 3 * heads * 32), dtype),
              ((heads, 49, 49), jnp.float32)]
             + ([((n_mask, 49, 49), jnp.float32)] if n_mask else []))
+
+
+def _global_case(batch, tokens, heads, d, dtype, grad=False):
+    """Fused global attention at a ViT's shape, forward or forward + the
+    fused backward (``dqkv``)."""
+    def forward(qkv):
+        return global_attn.global_attention(qkv, heads=heads)
+
+    def backward(qkv):
+        return jax.grad(lambda a: jnp.sum(forward(a).astype(jnp.float32)))(
+            qkv)
+
+    return (backward if grad else forward,
+            [((batch, tokens, 3 * heads * d), dtype)])
 
 
 def _flash_grad(q, k, v):
@@ -98,6 +113,19 @@ CASES = {
         512, 12, 4, jnp.bfloat16, grad=True),
     "window_swin_t_s3_b128_grad": _window_case(
         128, 24, 0, jnp.bfloat16, grad=True),
+    # ViT-B/16 at batch 128, the benchmark's cell (197 tokens, 12 heads of
+    # 64), forward + the fused backward; the same served at batch 8 in
+    # float32; ViT-L/16's 16 heads; 50 tokens (patch 32) at a ragged batch;
+    # heads of 32 at the widest covered sequence
+    "global_vit_b16_b128_grad": _global_case(
+        128, 197, 12, 64, jnp.bfloat16, grad=True),
+    "global_vit_b16_b8_f32": _global_case(8, 197, 12, 64, jnp.float32),
+    "global_vit_l16_b64_grad": _global_case(
+        64, 197, 16, 64, jnp.bfloat16, grad=True),
+    "global_vit_b32_b30_grad": _global_case(
+        30, 50, 12, 64, jnp.bfloat16, grad=True),
+    "global_d32_n256_grad": _global_case(
+        16, 256, 16, 32, jnp.bfloat16, grad=True),
     "nms_pallas_n1024": (_NMS, [((1024, 4), jnp.float32),
                                 ((1024,), jnp.float32)]),
     "nms_pallas_n4096": (_NMS, [((4096, 4), jnp.float32),
