@@ -86,8 +86,7 @@ HOT_PATH_MODULES: Tuple[str, ...] = (
 # design: test code syncs on purpose, and seeded-violation fixtures for
 # the unit tests live in tmp dirs)
 DEFAULT_SCAN: Tuple[str, ...] = (
-    "deeplearning_tpu", "tools", "bench.py", "chip_smoke.py",
-    "__graft_entry__.py",
+    "deeplearning_tpu", "tools", "chip_smoke.py", "__graft_entry__.py",
 )
 
 DEFAULT_BASELINE = os.path.join(os.path.dirname(os.path.abspath(__file__)),

@@ -4,10 +4,10 @@ Round 4 switched ViT/Swin/ConvNeXt to exact-erf GELU for torch parity
 (reference uses ``torch.nn.GELU()`` = erf, e.g.
 classification/vision_transformer/vit_model.py:114) asserting the cost was
 ~0 because "the elementwise op fuses either way". Round 5 measured it on a
-TPU v5e (tools/mfu_results.jsonl): the erf lowering costs **3.8 MFU
-points** on the ViT-B/16 train step — 47.94% (erf) vs 51.71% (tanh) at
-batch 128 — because XLA lowers erf to a long polynomial while tanh uses the
-fast rational approximation.
+TPU v5e: the erf lowering cost **3.8 MFU points** on the ViT-B/16 train
+step at batch 128 (July, another runtime; not measured on this one),
+because XLA lowers erf to a long polynomial while tanh uses the fast
+rational approximation.
 
 Policy: training defaults to the tanh approximation (max abs deviation from
 erf-GELU is ~1e-3, irrelevant to SGD); weight-port / reference-parity paths

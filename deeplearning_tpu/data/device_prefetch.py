@@ -26,7 +26,8 @@ dispatcher only queues behind the steps the training thread already has
 in flight, and on a v5e that wait read as 75-137 ms of ``feed/h2d`` a
 batch where the put itself takes under 1 ms (PERF.md, PR 28).
 
-Telemetry (feeds Trainer ``data_time``/``throughput_stats``):
+Telemetry (feeds the Trainer's ``data_time`` and per-epoch ``feed/*``
+scalars):
 - ``last_data_wait`` / ``data_wait_total``: time the CONSUMER actually
   blocked on the queue — true feed starvation, not wall clock.
 - ``h2d_wait_total``: worker-thread time spent assembling/transferring
@@ -296,7 +297,8 @@ class DevicePrefetcher:
         return self._occ_sum / self._occ_n if self._occ_n else 0.0
 
     def stats(self) -> Dict[str, float]:
-        """Feed telemetry snapshot for throughput_stats / bench rows."""
+        """Feed telemetry of this epoch: the Trainer logs it as ``feed/*``
+        scalars at every epoch end, then resets it."""
         busy = self.source_wait_total + self.h2d_wait_total
         out = {
             "prefetch_depth": float(self.depth),

@@ -60,9 +60,9 @@ class PatchEmbed(nn.Module):
     """Image → patch tokens (vit_model.py:43).
 
     The reference's strided conv IS a block reshape + matmul; lowering it
-    explicitly that way measures +1.2 MFU points on the v5e ViT-B/16 train
-    step vs XLA's conv path (52.03% vs 50.87%, tools/mfu_results.jsonl
-    patch_matmul_b128). Params keep the conv's HWIO kernel shape
+    explicitly that way measured +1.2 MFU points on the v5e ViT-B/16 train
+    step vs XLA's conv path (July, another runtime; not measured on this
+    one). Params keep the conv's HWIO kernel shape
     (p, p, c, embed) and "proj" naming, so checkpoints and torch-weight
     ports are unaffected — the kernel is reshaped at trace time."""
     patch_size: int = 16

@@ -14,9 +14,8 @@ ways:
   ``engine.stats()``) into gauges/counters — zero added cost on the
   request path.
 
-Cost discipline (same budget as ``obs/spans.py``, enforced by the
-``tools/bench_util.metrics_overhead`` A/B and by DLT100 coverage of
-this module):
+Cost discipline (same rule as ``obs/spans.py``; DLT100 covers this
+module):
 - **Disabled** (the default): each helper is one module-pointer load
   plus an ``is None`` check — no lock, no allocation.
 - **Enabled**: a dict lookup and one O(1) add under the metric's own

@@ -1,17 +1,21 @@
 """Flash attention for TPU: fused online-softmax attention in Pallas.
 
-This is the framework's answer to the reference's attention hot paths —
-the naive materialized softmax in ViT (classification/vision_transformer/
-vit_model.py:100-111) and the CUDA window kernel motivation in Swin
-(SURVEY.md §2.10.1): never materialize the (N, N) attention matrix in HBM.
-Forward and backward are Pallas kernels with a custom VJP; the backward
-recomputes P = exp(S - LSE) blockwise from the saved logsumexp, FlashAttention-2
+Per-head kernels that never write the (N, N) scores to HBM: forward and
+backward are Pallas kernels with a custom VJP; the backward recomputes
+P = exp(S - LSE) blockwise from the saved logsumexp, FlashAttention-2
 style.
 
-Ring attention (parallel/ring_attention.py) is the sequence-parallel
-counterpart; ``flash_attention_with_lse`` exposes the per-row logsumexp
-so the ring's online-softmax merge can combine per-chunk kernel outputs
-exactly — blockwise HBM savings and ring scaling stack.
+What is left here is what sequence parallelism calls, and it stays until
+ROADMAP W8's long-sequence row decides it on the chip:
+``flash_attention_with_lse`` and ``flash_chunk_grads`` are the ring's
+``use_flash`` chunks (parallel/ring_attention.py: the per-row logsumexp
+lets the ring's online-softmax merge combine per-chunk kernel outputs
+exactly), ``flash_attention`` is Ulysses' ``use_flash`` inner attention.
+No model reaches this module on its own: short sequences (ViT's 197
+tokens) are ``global_attention.select_path``'s, windows are
+``window_attention``'s. The head-batched variant that was meant for short
+N lost to the lax path on the chip every time it was measured and was
+deleted at PR 30 (PERF.md §6).
 
 Layout: (B, H, N, D). N must be a multiple of the block size — wrappers
 pad and mask via ``kv_len`` (the number of valid key tokens).
@@ -161,253 +165,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk, dv = jax.lax.fori_loop(0, nq, body, (dk0, dv0))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
-
-
-def _fwd_kernel_hb(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                   sm_scale: float, block_k: int, kv_len: int,
-                   causal: bool, q_block: int):
-    """Head-batched forward: blocks carry HB heads so each program feeds
-    the MXU HB small matmuls in one batched dot_general — amortizes the
-    per-program overhead that dominates at short N / small head dim."""
-    qi = pl.program_id(1)
-    q = q_ref[...]                      # (HB, block_q, d)
-    n = k_ref.shape[1]
-    nk = n // block_k
-    hb, bq, d = q.shape
-
-    def body(ki, carry):
-        acc, m_prev, l_prev = carry
-        k = k_ref[:, pl.ds(ki * block_k, block_k), :]
-        v = v_ref[:, pl.ds(ki * block_k, block_k), :]
-        s = sm_scale * jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)   # (HB, bq, block_k)
-        col = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2)
-        mask = col < kv_len
-        if causal:
-            row = qi * q_block + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            mask = mask & (col <= row)
-        s = jnp.where(mask, s, NEG_INF)
-        m_cur = jnp.max(s, axis=2)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[..., None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=2)
-        acc = acc * alpha[..., None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        return acc, m_new, l_new
-
-    acc = jnp.zeros((hb, bq, d), jnp.float32)
-    m0 = jnp.full((hb, bq), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((hb, bq), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, nk, body, (acc, m0, l0))
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[...] = (acc / l_safe[..., None]).astype(o_ref.dtype)
-    lse = m + jnp.log(l_safe)
-    lse_ref[...] = jnp.broadcast_to(lse[..., None],
-                                    lse.shape + (8,))
-
-
-def _bwd_dq_kernel_hb(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, *, sm_scale: float, block_k: int,
-                      kv_len: int, causal: bool, q_block: int):
-    """Head-batched dQ: every dot_general carries the HB batch dim, so
-    one program amortizes HB heads (the short-N regime where per-program
-    overhead dominates the per-head kernels)."""
-    qi = pl.program_id(1)
-    q = q_ref[...]                       # (HB, bq, d)
-    do = do_ref[...]
-    lse = lse_ref[..., 0]                # (HB, bq)
-    delta = delta_ref[..., 0]
-    n = k_ref.shape[1]
-    nk = n // block_k
-
-    def body(ki, dq):
-        k = k_ref[:, pl.ds(ki * block_k, block_k), :]
-        v = v_ref[:, pl.ds(ki * block_k, block_k), :]
-        s = sm_scale * jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)   # (HB, bq, block_k)
-        col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        mask = col < kv_len
-        if causal:
-            row = qi * q_block + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            mask = mask & (col <= row)
-        p = jnp.where(mask, jnp.exp(s - lse[..., None]), 0.0)
-        dp = jax.lax.dot_general(do, v, (((2,), (2,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[..., None]) * sm_scale
-        return dq + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-
-    dq = jax.lax.fori_loop(0, nk, body, jnp.zeros(q.shape, jnp.float32))
-    dq_ref[...] = dq.astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel_hb(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, *, sm_scale: float, block_q: int,
-                       kv_len: int, causal: bool, k_block: int):
-    ki = pl.program_id(1)
-    k = k_ref[...]                       # (HB, bk, d)
-    v = v_ref[...]
-    n = q_ref.shape[1]
-    nq = n // block_q
-    col = ki * k_block + jax.lax.broadcasted_iota(
-        jnp.int32, (k.shape[0], block_q, k.shape[1]), 2)
-
-    def body(qi, carry):
-        dk, dv = carry
-        q = q_ref[:, pl.ds(qi * block_q, block_q), :]
-        do = do_ref[:, pl.ds(qi * block_q, block_q), :]
-        lse = lse_ref[:, pl.ds(qi * block_q, block_q), 0]
-        delta = delta_ref[:, pl.ds(qi * block_q, block_q), 0]
-        s = sm_scale * jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)   # (HB, bq, bk)
-        mask = col < kv_len
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            mask = mask & (col <= row)
-        p = jnp.where(mask, jnp.exp(s - lse[..., None]), 0.0)
-        dv = dv + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((2,), (2,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[..., None]) * sm_scale
-        dk = dk + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        return dk, dv
-
-    dk0 = jnp.zeros(k.shape, jnp.float32)
-    dv0 = jnp.zeros(v.shape, jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, nq, body, (dk0, dv0))
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_hb(q, k, v, sm_scale, kv_len, causal, block_q, block_k, hb):
-    out, _ = _flash_hb_fwd(q, k, v, sm_scale, kv_len, causal, block_q,
-                           block_k, hb)
-    return out
-
-
-def _flash_hb_fwd(q, k, v, sm_scale, kv_len, causal, block_q, block_k,
-                  hb):
-    b, h, n, d = q.shape
-    qf, kf, vf = map(_flatten_bh, (q, k, v))
-    grid = (b * h // hb, n // block_q)
-    kernel = functools.partial(_fwd_kernel_hb, sm_scale=sm_scale,
-                               block_k=block_k, kv_len=kv_len,
-                               causal=causal, q_block=block_q)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((hb, block_q, d), lambda g, qi: (g, qi, 0)),
-            pl.BlockSpec((hb, n, d), lambda g, qi: (g, 0, 0)),
-            pl.BlockSpec((hb, n, d), lambda g, qi: (g, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((hb, block_q, d), lambda g, qi: (g, qi, 0)),
-            pl.BlockSpec((hb, block_q, 8), lambda g, qi: (g, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, n, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, n, 8), jnp.float32),
-        ],
-        interpret=interpret_mode(),
-    )(qf, kf, vf)
-    return out.reshape(b, h, n, d), (q, k, v, out.reshape(b, h, n, d), lse)
-
-
-def _flash_hb_bwd(sm_scale, kv_len, causal, block_q, block_k, hb, res,
-                  dout):
-    q, k, v, out, lse = res
-    b, h, n, d = q.shape
-    qf, kf, vf = map(_flatten_bh, (q, k, v))
-    dof = _flatten_bh(dout)
-    of = _flatten_bh(out)
-    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    delta = jnp.broadcast_to(delta, (b * h, n, 8))
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_hb, sm_scale=sm_scale,
-                          block_k=block_k, kv_len=kv_len, causal=causal,
-                          q_block=block_q),
-        grid=(b * h // hb, n // block_q),
-        in_specs=[
-            pl.BlockSpec((hb, block_q, d), lambda g, qi: (g, qi, 0)),
-            pl.BlockSpec((hb, n, d), lambda g, qi: (g, 0, 0)),
-            pl.BlockSpec((hb, n, d), lambda g, qi: (g, 0, 0)),
-            pl.BlockSpec((hb, block_q, d), lambda g, qi: (g, qi, 0)),
-            pl.BlockSpec((hb, block_q, 8), lambda g, qi: (g, qi, 0)),
-            pl.BlockSpec((hb, block_q, 8), lambda g, qi: (g, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((hb, block_q, d), lambda g, qi: (g, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, n, d), q.dtype),
-        interpret=interpret_mode(),
-    )(qf, kf, vf, dof, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_hb, sm_scale=sm_scale,
-                          block_q=block_q, kv_len=kv_len, causal=causal,
-                          k_block=block_k),
-        grid=(b * h // hb, n // block_k),
-        in_specs=[
-            pl.BlockSpec((hb, n, d), lambda g, ki: (g, 0, 0)),
-            pl.BlockSpec((hb, block_k, d), lambda g, ki: (g, ki, 0)),
-            pl.BlockSpec((hb, block_k, d), lambda g, ki: (g, ki, 0)),
-            pl.BlockSpec((hb, n, d), lambda g, ki: (g, 0, 0)),
-            pl.BlockSpec((hb, n, 8), lambda g, ki: (g, 0, 0)),
-            pl.BlockSpec((hb, n, 8), lambda g, ki: (g, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((hb, block_k, d), lambda g, ki: (g, ki, 0)),
-            pl.BlockSpec((hb, block_k, d), lambda g, ki: (g, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, n, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, n, d), v.dtype),
-        ],
-        interpret=interpret_mode(),
-    )(qf, kf, vf, dof, lse, delta)
-
-    unflat = lambda x: x.reshape(b, h, n, d)
-    return unflat(dq), unflat(dk), unflat(dv)
-
-
-_flash_hb.defvjp(_flash_hb_fwd, _flash_hb_bwd)
-
-
-def flash_attention_hb(q, k, v, *, sm_scale=None, causal=False,
-                       block_q: int = DEFAULT_BLOCK_Q,
-                       block_k: int = DEFAULT_BLOCK_K,
-                       head_block: int = 4):
-    """Head-batched flash attention (B, H, N, D), trainable: forward AND
-    backward kernels batch ``head_block`` heads per program, amortizing
-    program overhead in the short-N regime (ViT N=197, MAE N=50) where
-    the per-head kernels lose to naive XLA attention."""
-    b, h, n, d = q.shape
-    if sm_scale is None:
-        sm_scale = d ** -0.5
-    while h % head_block:
-        head_block //= 2
-    head_block = max(head_block, 1)
-    block_q, block_k, _, (q, k, v) = _blocks_and_pad(n, block_q, block_k,
-                                                     q, k, v)
-    out = _flash_hb(q, k, v, sm_scale, n, causal, block_q, block_k,
-                    head_block)
-    return out[:, :, :n, :]
 
 
 def _flatten_bh(x):

@@ -29,7 +29,11 @@ scores and softmax in float32, ``p`` cast to the input dtype for ``PV``,
 float32 accumulation.
 
 ``select_path`` is the one place that chooses between this and
-``dot_product_attention`` (the oracle).
+``dot_product_attention`` (the oracle). These two are all the attention a
+ViT block has: the per-head flash kernels of ``flash_attention.py`` are
+reached only through an injected ``attn_fn`` (the ring's and Ulysses'
+``use_flash``), which turns this module off, and stay for long sequences
+until ROADMAP W8 measures one.
 
 Mosaic kernels cannot be partitioned automatically: lowered into a program
 that GSPMD spreads over several devices, a bare ``pallas_call`` is refused

@@ -2,8 +2,7 @@
 
 Merges the three reference archetypes: the simple epoch loop
 (classification/mnist/train.py:141), the yacs/DDP/AMP harness features
-(swin main.py:84-300: accumulation, auto-resume, save-freq, throughput
-mode), and YOLOX's hook skeleton (yolox/core/trainer.py:69-88:
+(swin main.py:84-300: accumulation, auto-resume, save-freq), and YOLOX's hook skeleton (yolox/core/trainer.py:69-88:
 before_train/before_epoch/before_iter/after_iter/after_epoch/after_train)
 with yolov5's Callbacks event registry (utils/callbacks.py:8).
 
@@ -859,87 +858,3 @@ class Trainer:
                                     weight_update=self.weight_update)
         except Exception:  # noqa: BLE001 - never block a save on it
             return None
-
-    # -------------------------------------------------- throughput mode
-    def throughput(self, n_iters: int = 30, lag: int = 3) -> float:
-        """images/sec over n averaged iters (swin main.py:281-300).
-
-        ONE pipelined pass over real loader batches. Per-step tail stats
-        come from a lagged metrics ring instead of a per-iter
-        ``float(m["loss"])`` sync: after dispatching step i the loop
-        fetches step i-``lag``'s metrics — a buffer that is the only
-        UNRETIRED work older than the ``lag`` steps still in flight, so
-        the fetch completes the moment that step does without draining
-        the dispatch queue. Timestamp deltas between those lagged
-        completions ARE the pipelined per-step times (p50/p90), the same
-        quantity the old serializing pass approximated while flushing
-        the pipe every iteration.
-
-        Donation-safe by construction: every dispatched batch is a fresh
-        one from the loader (never reused), so ``donate_batch=True``
-        steps measure identically. When the loader is a
-        ``DevicePrefetcher``, its queue-occupancy / H2D-wait counters are
-        folded into ``throughput_stats``."""
-        import collections as _collections
-        if n_iters < 2:
-            raise ValueError("throughput needs n_iters >= 2")
-        lag = max(1, min(int(lag), n_iters - 1))
-        loader = self.train_loader
-        reset = getattr(loader, "reset_stats", None)
-        if reset is not None:
-            reset()
-
-        def cycle():
-            while True:
-                got = False
-                for b in iter(loader):
-                    got = True
-                    yield b
-                if not got:
-                    raise ValueError("loader yielded zero batches")
-        it = cycle()
-        batch = next(it)
-        bsz = jax.tree.leaves(batch)[0].shape[0]
-        # warmup: compile + land the executable, then drain (clean start)
-        self.state, m = self.train_step(self.state, batch, self.rng)
-        float(m["loss"])                      # the one draining sync
-        ring: "_collections.deque" = _collections.deque()
-        lag_marks, data_times = [], []
-        t0 = time.perf_counter()
-        for _ in range(n_iters):
-            t_d = time.perf_counter()
-            batch = next(it)
-            wait = getattr(loader, "last_data_wait", None)
-            data_times.append(wait if wait is not None
-                              else time.perf_counter() - t_d)
-            self.state, m = self.train_step(self.state, batch, self.rng)
-            ring.append(m)
-            if len(ring) > lag:
-                float(ring.popleft()["loss"])  # lagged, non-draining
-                lag_marks.append(time.perf_counter())
-        while ring:                            # end-of-run drain
-            float(ring.popleft()["loss"])
-            lag_marks.append(time.perf_counter())
-        total = time.perf_counter() - t0
-        ips = bsz * n_iters / total
-        step_times = np.diff(lag_marks) if len(lag_marks) > 1 else \
-            np.asarray([total / n_iters])  # dltpu: allow(DLT100) host floats
-        p50, p90 = np.percentile(step_times, [50, 90])
-        data_frac = sum(data_times) / total if total else 0.0
-        self.throughput_stats = {
-            "images_per_sec": ips,
-            "step_ms_mean": total / n_iters * 1e3,
-            "step_ms_p50": p50 * 1e3,
-            "step_ms_p90": p90 * 1e3,
-            "data_wait_frac": data_frac,
-            "batch": bsz,
-        }
-        feed_stats = getattr(loader, "stats", None)
-        if feed_stats is not None:
-            self.throughput_stats.update(feed_stats())
-        self.logger.info(
-            f"throughput: {ips:.1f} images/s "
-            f"({total / n_iters * 1e3:.1f} ms/iter pipelined, "
-            f"p50 {p50 * 1e3:.1f} ms, p90 {p90 * 1e3:.1f} ms, "
-            f"data-wait {data_frac:.1%}, batch {bsz}, lag {lag})")
-        return ips

@@ -1,16 +1,17 @@
-"""Profiling: step timing, XLA-FLOPs MFU meter.
+"""Profiling helpers: the peak table, the retrace guard, XLA cost analysis.
 
-The reference's ad-hoc timing stack (SURVEY.md §5: cuda-synchronized
-time_sync, thop-based layer profilers, swin throughput mode) becomes:
-- ``StepTimer``: wall-clock per-step timing; the caller syncs with
-  ``jax.block_until_ready`` before ``stop()``.
-- ``mfu``: measured step time vs compiled-graph FLOPs vs chip peak — the
-  BASELINE.md headline metric.
+- ``PEAK_BF16_FLOPS`` / ``device_peak_flops``: the program's one table of
+  per-chip peaks, keyed by ``device_kind``; an unlisted device is an error.
+- ``RetraceGuard``: warns when a jitted step sees a new argument signature.
+- ``compiled_flops`` / ``model_info``: what XLA's ``cost_analysis()`` counts
+  for a compiled function (export and the model summaries read it).
+
+No timing lives here: a speed is measured by ``benchmarks/run.py`` through
+``Trainer.train`` on the chip (PERF.md).
 """
 
 from __future__ import annotations
 
-import time
 import warnings
 from typing import Callable, Dict, Optional
 
@@ -36,30 +37,6 @@ def device_peak_flops(device: Optional[jax.Device] = None) -> float:
             f"no bf16 peak known for device_kind {kind!r} (platform "
             f"{device.platform!r}); known: {sorted(PEAK_BF16_FLOPS)}")
     return PEAK_BF16_FLOPS[kind]
-
-
-class StepTimer:
-    """Accumulates step wall times; caller syncs before ``stop()``."""
-
-    def __init__(self):
-        self.times = []
-        self._t0 = None
-
-    def start(self):
-        self._t0 = time.perf_counter()
-
-    def stop(self):
-        # stop() without a matching start() (callback fired before the
-        # loop primed the timer) records nothing instead of raising a
-        # TypeError on the None arithmetic
-        if self._t0 is None:
-            return
-        self.times.append(time.perf_counter() - self._t0)
-        self._t0 = None
-
-    @property
-    def mean(self) -> float:
-        return sum(self.times) / max(len(self.times), 1)
 
 
 class RetraceGuard:
@@ -139,24 +116,6 @@ def compiled_flops(fn: Callable, *args) -> float:
     compiled = tracked_compile(jax.jit(fn).lower(*args),
                                getattr(fn, "__name__", "flops_probe"))
     return float(cost_analysis_dict(compiled).get("flops", 0.0))
-
-
-def measure_mfu(step_fn: Callable, args: tuple, n_steps: int = 10
-                ) -> Dict[str, float]:
-    """Run ``step_fn(*args)`` n times on the default device and report
-    step time + MFU against that device's peak. Raises before running
-    anything on a device the peak table does not know."""
-    peak = device_peak_flops()
-    flops = compiled_flops(step_fn, *args)
-    jax.block_until_ready(step_fn(*args))
-    t0 = time.perf_counter()
-    for _ in range(n_steps):
-        out = step_fn(*args)
-    jax.block_until_ready(out)
-    dt = (time.perf_counter() - t0) / n_steps
-    return {"step_time_s": dt, "flops_per_step": flops,
-            "mfu": flops / dt / peak if flops else 0.0,
-            "peak_flops": peak}
 
 
 def model_info(model, *example_args, train: bool = False,

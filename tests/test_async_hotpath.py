@@ -260,19 +260,6 @@ class TestZeroSyncEval:
             assert results[k] == ref[k], k
 
 
-class TestThroughputStats:
-    def test_percentiles_and_data_wait(self):
-        trainer = make_trainer(epochs=1)
-        ips = trainer.throughput(n_iters=3)
-        assert ips > 0
-        stats = trainer.throughput_stats
-        for key in ("step_ms_mean", "step_ms_p50", "step_ms_p90",
-                    "data_wait_frac", "images_per_sec", "batch"):
-            assert key in stats, key
-        assert stats["step_ms_p90"] >= stats["step_ms_p50"] > 0
-        assert 0.0 <= stats["data_wait_frac"] <= 1.0
-
-
 class TestLoaderDataWait:
     def test_parallel_loader_reports_wait(self):
         from deeplearning_tpu.data.loader import MapSource

@@ -165,11 +165,6 @@ class TestTrainer:
         assert int(t2.state.step) == step_after * 2
         t2.ckpt.close()
 
-    def test_throughput_mode(self):
-        trainer = self._make(None, epochs=1)
-        ips = trainer.throughput(n_iters=3)
-        assert ips > 0
-
 
 class TestLrFinder:
     def test_suggests_reasonable_lr(self):

@@ -359,6 +359,8 @@ class TestPrecompile:
         assert trainer.precompile() is None  # no spec, but feed started
         time.sleep(0.1)
         assert src.produced > 0              # worker ran during "compile"
+        pf.reseed(0)     # stops the started pipeline: its worker would
+        #                  outlive the test, parked on the full queue
 
 
 class SlowSyntheticLoader:
@@ -407,20 +409,50 @@ def _blocking_step(state, batch, rng):
 
 class TestPipelinedThroughput:
     """The ISSUE acceptance criterion: DevicePrefetcher(depth=2) over a
-    slow synthetic source beats the unwrapped loader on images/sec."""
+    slow synthetic source beats the unwrapped loader on images/sec,
+    through ``Trainer.train()``: the one loop."""
 
-    @staticmethod
-    def _ips(prefetch):
+    STEPS = 15
+
+    @classmethod
+    def _run(cls, prefetch):
+        """One epoch of ``STEPS`` + 1 steps; the first (it compiles, and
+        the prefetcher's queue is cold) is left out of the clock. Returns
+        images/sec over the rest, the share of that time the training
+        thread waited for data (the loader's own ``last_data_wait`` where
+        it has one, the wall clock otherwise: the Trainer's ``data_time``
+        rule), and the loader's ``stats()`` at the last step (the Trainer
+        resets them at the epoch's end)."""
+        loader = SlowSyntheticLoader(n=cls.STEPS + 1)
         trainer = Trainer(state=None, train_step=_blocking_step,
-                          train_loader=SlowSyntheticLoader(),
+                          train_loader=loader, epochs=1,
                           retrace_warn=False, prefetch=prefetch,
                           log_every=50)
-        ips = trainer.throughput(n_iters=15)
-        return ips, trainer.throughput_stats
+        marks, waits, stats = [], [], {}
+
+        def before_iter(t, batch):
+            if marks:
+                wait = getattr(t.train_loader, "last_data_wait", None)
+                waits.append(wait if wait is not None
+                             else time.perf_counter() - marks[-1])
+
+        def after_iter(t, metrics):
+            marks.append(time.perf_counter())
+            feed_stats = getattr(t.train_loader, "stats", None)
+            if feed_stats is not None:
+                stats.update(feed_stats())
+
+        trainer.callbacks.register("before_iter", before_iter)
+        trainer.callbacks.register("after_iter", after_iter)
+        trainer.train()
+        assert len(marks) == cls.STEPS + 1
+        total = marks[-1] - marks[0]
+        ips = loader.batch * cls.STEPS / total
+        return ips, sum(waits) / total, stats
 
     def test_wrapped_beats_unwrapped(self):
-        serial_ips, serial_stats = self._ips(prefetch=0)
-        piped_ips, piped_stats = self._ips(prefetch=2)
+        serial_ips, serial_wait, serial_stats = self._run(prefetch=0)
+        piped_ips, piped_wait, piped_stats = self._run(prefetch=2)
         # feed (8 ms) overlaps compute (~8 ms): ~1.4-1.9x in practice;
         # assert a conservative margin so CI load can't flake it
         assert piped_ips > serial_ips * 1.15, \
@@ -428,9 +460,10 @@ class TestPipelinedThroughput:
         # wrapped stats carry the feed telemetry, serial ones don't
         assert "prefetch_occupancy" in piped_stats
         assert piped_stats["prefetch_depth"] == 2.0
+        assert piped_stats["batches_fed"] == self.STEPS + 1
         assert "prefetch_occupancy" not in serial_stats
         # overlap shows up as less consumer starvation per wall second
-        assert piped_stats["data_wait_frac"] < serial_stats["data_wait_frac"]
+        assert piped_wait < serial_wait
 
     def test_auto_wrap_requires_mesh(self):
         meshless = Trainer(state=None, train_step=_blocking_step,

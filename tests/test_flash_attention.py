@@ -119,65 +119,6 @@ class TestLayoutWrapper:
                                    atol=1e-6)
 
 
-class TestHeadBatchedForward:
-    def test_matches_reference(self):
-        from deeplearning_tpu.ops.pallas.flash_attention import (
-            flash_attention_hb)
-        q, k, v = rand_qkv(b=2, h=4, n=197, d=32)
-        out = flash_attention_hb(q, k, v, head_block=4)
-        ref = reference_attention(q, k, v)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=3e-5, rtol=3e-5)
-
-    def test_head_block_not_dividing_heads(self):
-        from deeplearning_tpu.ops.pallas.flash_attention import (
-            flash_attention_hb)
-        q, k, v = rand_qkv(b=1, h=3, n=64, d=32)   # 3 heads, hb falls to 1
-        out = flash_attention_hb(q, k, v, head_block=4)
-        ref = reference_attention(q, k, v)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=3e-5, rtol=3e-5)
-
-
-class TestHeadBatchedBackward:
-    def test_grads_match_reference(self):
-        from deeplearning_tpu.ops.pallas.flash_attention import (
-            flash_attention_hb)
-        q, k, v = rand_qkv(b=2, h=4, n=197, d=32)
-
-        def loss_hb(q, k, v):
-            return jnp.sum(
-                flash_attention_hb(q, k, v, head_block=4) ** 2)
-
-        def loss_ref(q, k, v):
-            return jnp.sum(reference_attention(q, k, v) ** 2)
-
-        g_hb = jax.grad(loss_hb, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for gf, gr in zip(g_hb, g_ref):
-            np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
-                                       atol=5e-4, rtol=5e-4)
-
-    def test_causal_grads_match(self):
-        from deeplearning_tpu.ops.pallas.flash_attention import (
-            flash_attention_hb)
-        q, k, v = rand_qkv(b=1, h=4, n=128, d=32)
-
-        def loss_hb(q, k, v):
-            return jnp.sum(flash_attention_hb(
-                q, k, v, head_block=2, causal=True) ** 2)
-
-        def loss_ref(q, k, v):
-            return jnp.sum(
-                reference_attention(q, k, v, causal=True) ** 2)
-
-        g_hb = jax.grad(loss_hb, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for gf, gr in zip(g_hb, g_ref):
-            np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
-                                       atol=5e-4, rtol=5e-4)
-
-
 class TestChunkGrads:
     def test_single_chunk_equals_full_gradient(self):
         # flash_chunk_grads with the GLOBAL lse/delta over one chunk that

@@ -362,17 +362,6 @@ class TestToolChecks:
         assert proc.returncode == 0, proc.stderr
         assert "OK" in proc.stdout
 
-    def test_metrics_overhead_shape(self):
-        import jax.numpy as jnp
-
-        import bench_util
-        res = bench_util.metrics_overhead(
-            lambda x: x + 1, (jnp.ones((8,), jnp.float32),), n=3, reps=1)
-        assert set(res) == {"metrics_off_ms", "metrics_on_ms",
-                            "overhead_pct", "within_budget", "budget_pct"}
-        assert res["metrics_on_ms"] > 0
-        assert not metrics.enabled()     # A/B restored the disabled state
-
 
 # ------------------------------------------------- multi-replica CPU e2e
 @pytest.mark.e2e

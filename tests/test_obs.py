@@ -1,7 +1,7 @@
 """PR 5 observability: span tracer / compile telemetry / flight
 recorder units, the Trainer trace + crash-dump acceptance runs, the
 serving health surface, and the satellite fixes (create_logger dir
-cache, StepTimer.stop, RetraceGuard hook + signature semantics,
+cache, RetraceGuard hook + signature semantics,
 obs_report --check)."""
 
 import json
@@ -32,7 +32,7 @@ from deeplearning_tpu.train.classification import make_loss_fn, make_metric_fn
 from deeplearning_tpu.train.optim import build_optimizer
 from deeplearning_tpu.train.schedules import build_schedule
 from deeplearning_tpu.train.trainer import Trainer
-from deeplearning_tpu.utils.profiling import RetraceGuard, StepTimer
+from deeplearning_tpu.utils.profiling import RetraceGuard
 
 
 @pytest.fixture(autouse=True)
@@ -645,18 +645,6 @@ class TestRetraceGuard:
                              "n_signatures": 4}
 
 
-class TestProfilingSatellites:
-    def test_steptimer_stop_before_start_is_noop(self):
-        t = StepTimer()
-        t.stop()                               # used to TypeError on None
-        assert t.times == []
-        t.start()
-        t.stop()
-        assert len(t.times) == 1
-        t.stop()                               # unmatched stop: ignored
-        assert len(t.times) == 1
-
-
 class TestLoggerDirCache:
     def test_new_output_dir_attaches_new_file_handler(self, tmp_path):
         name = "dltpu-test-dircache"
@@ -690,17 +678,3 @@ class TestObsReportCheck:
                               capture_output=True, text=True, timeout=120)
         assert proc.returncode == 0, proc.stderr
         assert "ok" in proc.stdout
-
-
-class TestObsOverheadHelper:
-    def test_ab_helper_reports_and_restores_tracer_state(self):
-        """Structural check of the obs-overhead A/B row (the <2% budget
-        itself is a chip measurement: ``perf_sweep.py --set obs``)."""
-        from bench_util import obs_overhead
-        fn = jax.jit(lambda x: (x @ x).sum())
-        x = jnp.ones((64, 64), jnp.float32)
-        res = obs_overhead(fn, (x,), n=5, reps=1)
-        assert set(res) == {"spans_off_ms", "spans_on_ms",
-                            "overhead_pct", "within_budget", "budget_pct"}
-        assert res["spans_off_ms"] > 0 and res["spans_on_ms"] > 0
-        assert not spans.enabled()             # state restored

@@ -53,7 +53,7 @@ def _exact_torch_numerics():
     """Parity IS exact-torch mode: erf GELU etc. (core/numerics.py).
 
     Training defaults to the fast tanh GELU (erf measured at −3.8 MFU
-    points on the v5e ViT-B/16 step, tools/mfu_results.jsonl), so every
+    points on the v5e ViT-B/16 step: July, another runtime), so every
     parity test traces under the exact flag instead.
     """
     from deeplearning_tpu.core import numerics

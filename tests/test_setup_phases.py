@@ -41,9 +41,16 @@ def build(*extra):
         config_cli(train_cli.Config(), TINY + list(extra)))
 
 
+@pytest.fixture
 def feed_threads():
-    return [t for t in threading.enumerate()
-            if t.name == "device-prefetch" and t.is_alive()]
+    """The live ``device-prefetch`` workers started since the test began:
+    its own trainers'. A worker that a test of another file left parked on
+    its full queue is a daemon thread that lives as long as the process,
+    and which files share a process is the test runner's choice."""
+    before = set(threading.enumerate())
+    return lambda: [t for t in threading.enumerate()
+                    if t.name == "device-prefetch" and t.is_alive()
+                    and t not in before]
 
 
 def xs(tracer, name):
@@ -195,7 +202,7 @@ class TestPublicNamesForTheDriver:
         assert text == trainer._aot_step.as_text() and "HloModule" in text
         trainer.train_loader.reseed(0)
 
-    def test_close_feed_stops_the_worker(self):
+    def test_close_feed_stops_the_worker(self, feed_threads):
         trainer = build()
         trainer.close_feed()          # nothing live yet: a no-op
         trainer.train_loader.infinite = True
@@ -212,7 +219,8 @@ class TestPublicNamesForTheDriver:
         with pytest.raises(StopIteration):
             next(trainer._batches)
 
-    def test_request_stop_returns_the_state_at_a_step_boundary(self):
+    def test_request_stop_returns_the_state_at_a_step_boundary(self,
+                                                               feed_threads):
         trainer = build("train.epochs=50")
         trainer.train_loader.infinite = True     # one endless epoch
         fired = {"after_train": 0, "after_epoch": 0, "evals": 0, "steps": 0}

@@ -21,10 +21,9 @@ model choice, per-model op rows):
   python tools/loadgen.py --mode closed --concurrency 32 --n 256 \\
       --mix "mnist_fcn=0.7,mnist_cnn=0.3" --size 28 --buckets 1,8,32
 
-Every run can append a ``--set serve`` row (op schema:
-``bench_util.append_op_result``) to tools/mfu_results.jsonl so the
-request-path latency trajectory is recorded next to the train-step MFU
-rows; ``--mix`` runs append one row per tenant.
+With ``--results FILE`` every run appends one ``serve_<mode>`` op row
+({op, n, ms, device, utc, ...}) to that jsonl; ``--mix`` runs append one
+row per tenant. Without the flag no file is written.
 
 Fleet HTTP mode (``--mode open --fleet-urls`` / ``--fleet-dir``):
 arrivals POST ``/predict`` to a replica fleet through a
@@ -48,7 +47,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import jax
 import numpy as np
@@ -456,16 +454,23 @@ def run_open_loop_http(router, images, rate_hz: float,
 
 
 def append_serve_row(results_path: str, rec: dict, **extra) -> None:
-    """One serve row in the shared op-row schema (``"op" in rec`` splits
-    op rows from step rows for every mfu_results.jsonl consumer)."""
-    from bench_util import append_op_result
-    tag = rec.get("concurrency", rec.get("rate_hz", 1))
-    append_op_result(
-        results_path, f"serve_{rec['mode']}", n=int(tag),
-        ms=rec.get("p50_ms", 0.0), req_per_s=rec.get("req_per_s", 0.0),
-        p99_ms=rec.get("p99_ms", 0.0), completed=rec.get("completed", 0),
-        rejected=rec.get("rejected", 0),
-        batch_occupancy=rec.get("batch_occupancy", 0.0), **extra)
+    """Append one ``serve_<mode>`` op row ({op, n, ms}, device, UTC time
+    and the run's rates) for a run's record to a jsonl."""
+    row = {
+        "op": f"serve_{rec['mode']}",
+        "n": int(rec.get("concurrency", rec.get("rate_hz", 1))),
+        "ms": round(float(rec.get("p50_ms", 0.0)), 3),
+        "device": jax.devices()[0].device_kind,
+        "utc": time.strftime("%Y-%m-%d %H:%M:%S", time.gmtime()),
+        "req_per_s": rec.get("req_per_s", 0.0),
+        "p99_ms": rec.get("p99_ms", 0.0),
+        "completed": rec.get("completed", 0),
+        "rejected": rec.get("rejected", 0),
+        "batch_occupancy": rec.get("batch_occupancy", 0.0),
+        **extra,
+    }
+    with open(results_path, "a") as f:
+        f.write(json.dumps(row) + "\n")
 
 
 def main(argv=None) -> int:
@@ -494,7 +499,7 @@ def main(argv=None) -> int:
                     help="per-request deadline")
     ap.add_argument("--results", default=None,
                     help="append serve rows to this jsonl "
-                         "(default: tools/mfu_results.jsonl; 'none' off)")
+                         "(unset or 'none': no file is written)")
     ap.add_argument("--mix", default=None,
                     help='mixed zoo traffic, e.g. "a=0.7,b=0.3": each '
                          "request samples its model by weight "
@@ -519,6 +524,9 @@ def main(argv=None) -> int:
         ap.error("--mix needs --mode closed or open")
     if (args.fleet_urls or args.fleet_dir) and args.mode != "open":
         ap.error("--fleet-urls/--fleet-dir need --mode open")
+    results_path = args.results
+    if results_path and results_path.lower() == "none":
+        results_path = None
 
     if args.fleet_urls or args.fleet_dir:
         from deeplearning_tpu.fleet import FleetRouter
@@ -540,19 +548,14 @@ def main(argv=None) -> int:
             router, make_images(64, args.size), args.rate,
             args.duration, timeout_s=args.timeout_s or 10.0)
         print(json.dumps(rec), flush=True)
-        if (args.results or "").lower() != "none":
-            append_serve_row(args.results or os.path.join(
-                os.path.dirname(os.path.abspath(__file__)),
-                "mfu_results.jsonl"), rec, model=args.model)
+        if results_path:
+            append_serve_row(results_path, rec, model=args.model)
         return 0
 
     from deeplearning_tpu.serve import (InferenceEngine, MicroBatcher,
                                         ModelZoo)
 
     buckets = tuple(int(b) for b in args.buckets.split(","))
-    results_path = args.results or os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "mfu_results.jsonl")
-    write_rows = (args.results or "").lower() != "none"
 
     mix = zoo = None
     images_by_model = {}
@@ -602,12 +605,12 @@ def main(argv=None) -> int:
 
     def report(rec, **extra):
         print(json.dumps(rec), flush=True)
-        if not write_rows:
+        if not results_path:
             return
         models = rec.get("models")
         if models:
             # one op row per tenant, so the per-model latency
-            # trajectories land in mfu_results.jsonl individually
+            # trajectories land in the results file individually
             for alias, sub in sorted(models.items()):
                 append_serve_row(results_path, sub, model=alias,
                                  mix_weight=sub["mix_weight"], **extra)
