@@ -12,8 +12,10 @@ TPU-first: v1 window attention runs the fused Pallas kernels
 backward stay in VMEM) wherever they compile; v2 cosine attention, a CPU
 backend and the one eager pass of ``model.init`` run the lax path.
 ``window_attention.select_path`` makes that choice from what the layer can
-see; there is no flag. Roll and partition are lax ops: XLA turns them into
-layout copies, which the fused path does not remove. NHWC throughout.
+see; there is no flag. Roll, partition and merge are lax ops: XLA turns them
+into layout copies of their own, and keeps the windows token-major between
+them, which is the order the kernels read the qkv rows in and write their
+output in: nothing is copied at the kernels' boundary. NHWC throughout.
 """
 
 from __future__ import annotations
@@ -50,12 +52,13 @@ class WindowAttention(nn.Module):
         path = fused_attention.select_path(self.v2, self.is_initializing())
         masked = mask is not None
         # one flight event per path and per-window shape, whatever the batch
-        # and however often it is traced; ``shape`` is the first one seen
+        # and however often it is traced; ``shape`` is the first one seen.
+        # ``interface``: the order of the rows the kernels read and write
         flight.tally("kernel", ("window_attention", path, n, c,
                                 self.num_heads, masked),
                      member="/".join(self.path), name="window_attention",
-                     path=path, shape=[bw, n, self.num_heads, d],
-                     masked=masked)
+                     path=path, interface=fused_attention.interface(path),
+                     shape=[bw, n, self.num_heads, d], masked=masked)
         if self.v2 and self.qkv_bias:
             # v2 uses q/v biases only: a k bias is NOT softmax-invariant
             # under cosine attention (it shifts keys before normalization).

@@ -17,15 +17,24 @@ leading one reaches HBM; what does is the combined bias + mask, at most
 ``max(nW, windows a program)`` windows of it, and the bias gradient's
 partial sums.
 
-Layout: the kernels read ``qkv`` as the ``(BW, N, 3*C)`` rows the qkv matmul
-wrote and write ``(BW, N, C)`` rows; heads are lane slices inside the kernel.
-A block holds ``slot = 64`` rows of a window: the rows past ``N`` lie outside
-the array, arrive as whatever the buffer held and are zeroed in VMEM (so are
-the windows past ``BW`` in a ragged last block); as keys they carry -1e9 in
-the combined bias and vanish in the softmax, as queries their output rows are
-dropped by the write. Numbers as the lax path has them: ``q*scale`` in the
-input dtype, scores, bias, mask and softmax in float32, ``p`` cast to the
-input dtype for ``PV``, float32 accumulation.
+Layout: XLA keeps Swin's activations token-major on the chip (the window
+partition's copy and the qkv matmul both write ``(N, BW, lanes)`` physically),
+so that is what the kernels read and write: ``qkv`` and ``do`` as
+``(N, BW, 3*C)`` / ``(N, BW, C)``, ``o`` and ``dqkv`` the same, blocks
+``(N, wb, lanes)`` of ``wb`` windows. ``window_attention`` takes and returns
+the ``(BW, N, lanes)`` rows its callers have; the transposes inside it are
+logical, XLA makes them bitcasts, and no layout copy stands at either side of
+either kernel (PR 31; a row-major ``pallas_call`` operand had one on every
+operand and result, 2.1 GB a Swin-T step at batch 128). The
+``(token, window) -> (window, slot, lanes)`` arrangement the pair mathematics
+wants is made in VMEM on the way in and undone on the way out; heads are lane
+slices inside the kernel. A window holds ``slot = 64`` rows there: the rows
+past ``N`` are zeros the kernel makes (the windows past ``BW`` in a ragged
+last block are zeroed); as keys they carry -1e9 in the combined bias and
+vanish in the softmax, as queries their output rows are never written.
+Numbers as the lax path has them: ``q*scale`` in the input dtype, scores,
+bias, mask and softmax in float32, ``p`` cast to the input dtype for ``PV``,
+float32 accumulation.
 
 ``select_path`` is the one place that chooses between this and
 ``ops/window_utils.windowed_attention_reference`` (the oracle).
@@ -34,6 +43,7 @@ input dtype for ``PV``, float32 accumulation.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -44,10 +54,14 @@ from jax.experimental.pallas import tpu as pltpu
 from .common import interpret_mode
 
 # (pairs of heads) x windows one program unrolls: its code size, its compile
-# time and its live scores. Swin-T at batch 128 on a v5e (PR 26): 16 windows a
-# program is as fast as any size at 3 and 6 heads, 8 at 12 heads; under 8 the
-# programs are too short to fill the VLIW slots
-_UNITS_PER_PROGRAM = 64
+# time and, with C, the VMEM its blocks and live rows take. The windows are
+# the second-minor axis of every block, so whole tiles of 8 sublanes or all of
+# them: 16 up to 12 heads, 8 beyond (Swin-T at batch 128 on a v5e, PR 31: at
+# 3 heads 16 is a fifth faster than 8 and as fast as 32, at 12 heads a tenth
+# faster than 8; at 24 heads it saves 0.02 ms a block and compiles three
+# times as long)
+_UNITS_PER_PROGRAM = 96
+_SUBLANES = 8
 _MASKED = -1e9
 _VMEM_LIMIT = 64 * 2 ** 20
 
@@ -63,6 +77,14 @@ def select_path(v2: bool, initializing: bool = False) -> str:
     return "lax" if v2 or initializing or interpret_mode() else "fused"
 
 
+def interface(path: str) -> Optional[str]:
+    """The order of the rows a ``path`` of ``select_path`` hands its kernels:
+    ``"token_major"``, ``(N, BW, lanes)``, for the fused one (the order XLA
+    keeps them in on the chip, so no copy stands at the kernels' boundary);
+    None for the lax path, which runs no kernel."""
+    return "token_major" if path == "fused" else None
+
+
 def _slot(n: int) -> int:
     """Rows (and key lanes) a window's ``n`` tokens take in VMEM: 64, so that
     two heads' scores fill a 128-lane tile; larger windows a multiple of 16."""
@@ -70,14 +92,17 @@ def _slot(n: int) -> int:
 
 
 def windows_per_program(bw: int, nw: int, heads: int) -> int:
-    """Windows a program takes: a power of two from 4 to 16 by the number of
-    heads, then either a divisor of ``nw`` (a program stays inside one image's
-    mask rows) or a multiple of it (whole images a program)."""
-    target = min(16, max(4, _UNITS_PER_PROGRAM // -(-heads // 2)))
-    target = 1 << (target.bit_length() - 1)
-    if nw >= target:
-        return max(w for w in range(1, target + 1) if nw % w == 0)
-    return nw * max(1, min(target // nw, bw // nw))
+    """Windows a program takes, the blocks' second axis: 16 or, with many
+    heads, 8 (whole sublane tiles), and either a divisor of ``nw`` (a program
+    stays inside one image's mask rows) or a multiple of it (whole images a
+    program); 8 whole images where ``nw`` pairs with neither; all ``bw``
+    windows where that is no more than one program's."""
+    pairs = -(-heads // 2)
+    wanted = 2 * _SUBLANES if 2 * _SUBLANES * pairs <= _UNITS_PER_PROGRAM \
+        else _SUBLANES
+    fits = [w for w in (wanted, _SUBLANES) if nw % w == 0 or w % nw == 0]
+    wb = fits[0] if fits else math.lcm(nw, _SUBLANES)
+    return wb if wb < bw else bw
 
 
 def _zero_outside(x, n: int, windows_left):
@@ -175,25 +200,57 @@ def _pair_backward(q, k, v, do, bias, d):
     return dq, _dot(ds, q, _NN), dv, dbias
 
 
-def _fwd_kernel(qkv_ref, bias_ref, o_ref, *, heads, n, bw, ragged):
-    wb, _, c3 = qkv_ref.shape
+# The swap of a block's two leading axes runs on float32 rows, whatever the
+# input dtype: on a v5e (PR 31) Mosaic's swap of packed bf16 sublanes gave
+# wrong ``do`` rows at Swin-T's third stage (384 lanes; 11 % off in the block's
+# input gradient, every other stage exact), the 32-bit one is exact at all
+# four against the row-major kernels and costs 0.13 ms forward, 0.31 ms
+# backward of a 13.6 ms first-stage block.
+
+def _windows_major(ref, slot: int, windows_left):
+    """A token-major ``(n, wb, lanes)`` block as the ``(wb, slot, lanes)``
+    rows the pair mathematics reads: zero rows appended past ``n`` (whole
+    tiles of the leading axis, no data moves), then the two leading axes
+    swapped in VMEM; the windows past ``windows_left`` zeroed."""
+    x = ref[...]
+    n, wb, lanes = x.shape
+    rows = jnp.concatenate([x.astype(jnp.float32),
+                            jnp.zeros((slot - n, wb, lanes), jnp.float32)])
+    rows = jnp.swapaxes(rows, 0, 1).astype(x.dtype)
+    if windows_left is None:
+        return rows
+    return _zero_outside(rows, n, windows_left)
+
+
+def _store_token_major(ref, rows_ref):
+    """``(wb, slot, lanes)`` rows into a token-major ``(n, wb, lanes)`` block,
+    the rows past ``n`` dropped."""
+    rows = jnp.swapaxes(rows_ref[...].astype(jnp.float32), 0, 1)
+    ref[...] = rows[:ref.shape[0]].astype(ref.dtype)
+
+
+def _fwd_kernel(qkv_ref, bias_ref, o_ref, o_rows, *, heads, bw, ragged):
+    _, wb, c3 = qkv_ref.shape
     c = c3 // 3
     d = c // heads
-    qkv = _zero_outside(qkv_ref[...], n, _windows_left(bw, wb, ragged))
+    qkv = _windows_major(qkv_ref, o_rows.shape[1],
+                         _windows_left(bw, wb, ragged))
     for j, (lo, w) in enumerate(_pair_lanes(heads, d)):
         q, k, v = (qkv[:, :, at + lo:at + lo + w] for at in (0, c, 2 * c))
         o = _pair_forward(q, k, v, bias_ref[:, j], d=d)
-        o_ref[:, :, lo:lo + w] = o.astype(o_ref.dtype)
+        o_rows[:, :, lo:lo + w] = o.astype(o_rows.dtype)
+    _store_token_major(o_ref, o_rows)
 
 
-def _bwd_kernel(qkv_ref, bias_ref, do_ref, dqkv_ref, dbias_ref, *, heads, n,
-                bw, ragged):
-    wb, _, c3 = qkv_ref.shape
+def _bwd_kernel(qkv_ref, bias_ref, do_ref, dqkv_ref, dbias_ref, dqkv_rows, *,
+                heads, bw, ragged):
+    _, wb, c3 = qkv_ref.shape
     c = c3 // 3
     d = c // heads
+    slot = dqkv_rows.shape[1]
     left = _windows_left(bw, wb, ragged)
-    qkv = _zero_outside(qkv_ref[...], n, left)
-    do = _zero_outside(do_ref[...], n, left)
+    qkv = _windows_major(qkv_ref, slot, left)
+    do = _windows_major(do_ref, slot, left)
 
     @pl.when(pl.program_id(1) == 0)
     def _():
@@ -205,7 +262,9 @@ def _bwd_kernel(qkv_ref, bias_ref, do_ref, dqkv_ref, dbias_ref, *, heads, n,
                                       bias_ref[:, j], d=d)
         dbias_ref[0, j] += dbias
         for at, grad in zip((0, c, 2 * c), dqkv):
-            dqkv_ref[:, :, at + lo:at + lo + w] = grad.astype(dqkv_ref.dtype)
+            dqkv_rows[:, :, at + lo:at + lo + w] = grad.astype(
+                dqkv_rows.dtype)
+    _store_token_major(dqkv_ref, dqkv_rows)
 
 
 def _pack_bias(comb, slot: int):
@@ -245,11 +304,11 @@ def _combined_bias(bias, mask, slot: int, windows: int):
 
 
 def _plan(qkv, mask, heads: int):
-    """Block sizes from the shapes: windows a program, the grid (mask-row
-    block, image block: the second runs fastest, so a mask block is fetched
-    once), the combined bias's window count, and whether the last block is
-    ragged."""
-    bw, n, _ = qkv.shape
+    """Block sizes from the shapes of the token-major ``(N, BW, 3*C)`` rows:
+    windows a program, the grid (mask-row block, image block: the second runs
+    fastest, so a mask block is fetched once), the combined bias's window
+    count, and whether the last block is ragged."""
+    _, bw, _ = qkv.shape
     nw = 1 if mask is None else mask.shape[0]
     wb = windows_per_program(bw, nw, heads)
     tile = max(nw, wb)                      # windows of one pass over j
@@ -257,14 +316,14 @@ def _plan(qkv, mask, heads: int):
     return wb, grid, (1 if mask is None else tile), bool(bw % wb)
 
 
-def _specs(wb, c, grid, comb):
+def _specs(wb, n, c, grid, comb):
     """Blocks of the qkv rows, of the packed bias (one shared block without
-    a mask) and of the output rows."""
+    a mask) and of the output rows: ``wb`` windows of the second axis."""
     nj = grid[0]
     windows, pairs, slot, lanes = comb.shape
     shared = windows == 1
     rows = lambda width: pl.BlockSpec(           # noqa: E731
-        (wb, slot, width), lambda j, b: (b * nj + j, 0, 0))
+        (n, wb, width), lambda j, b: (0, b * nj + j, 0))
     bias = pl.BlockSpec(
         (1 if shared else wb, pairs, slot, lanes),
         (lambda j, b: (0, 0, 0, 0)) if shared
@@ -277,16 +336,16 @@ def _specs(wb, c, grid, comb):
 @functools.partial(jax.jit, static_argnames=("heads", "plan"))
 def _forward(qkv, comb, heads, plan):
     wb, grid, _, ragged = plan
-    bw, n, c3 = qkv.shape
+    n, bw, c3 = qkv.shape
     c = c3 // 3
-    qkv_spec, bias_spec, out_spec = _specs(wb, c, grid, comb)
+    qkv_spec, bias_spec, out_spec = _specs(wb, n, c, grid, comb)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads, n=n, bw=bw,
-                          ragged=ragged),
+        functools.partial(_fwd_kernel, heads=heads, bw=bw, ragged=ragged),
         grid=grid,
         in_specs=[qkv_spec, bias_spec],
         out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((bw, n, c), qkv.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, bw, c), qkv.dtype),
+        scratch_shapes=[pltpu.VMEM((wb, comb.shape[2], c), qkv.dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_VMEM_LIMIT),
@@ -298,12 +357,10 @@ def _forward(qkv, comb, heads, plan):
 @functools.partial(jax.jit, static_argnames=("heads", "plan"))
 def _backward(qkv, comb, g, heads, plan):
     wb, grid, _, ragged = plan
-    bw, n, c3 = qkv.shape
-    c = c3 // 3
-    qkv_spec, bias_spec, out_spec = _specs(wb, c, grid, comb)
+    n, bw, c3 = qkv.shape
+    qkv_spec, bias_spec, out_spec = _specs(wb, n, c3 // 3, grid, comb)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads, n=n, bw=bw,
-                          ragged=ragged),
+        functools.partial(_bwd_kernel, heads=heads, bw=bw, ragged=ragged),
         grid=grid,
         in_specs=[qkv_spec, bias_spec, out_spec],
         out_specs=[qkv_spec,
@@ -312,6 +369,7 @@ def _backward(qkv, comb, g, heads, plan):
         out_shape=[jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
                    jax.ShapeDtypeStruct((grid[0],) + comb.shape[1:],
                                         jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((wb, comb.shape[2], c3), qkv.dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
@@ -322,18 +380,19 @@ def _backward(qkv, comb, g, heads, plan):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _attend(qkv, bias, mask, heads):
+    """Token-major in, token-major out: ``(N, BW, 3*C) -> (N, BW, C)``."""
     return _attend_fwd(qkv, bias, mask, heads)[0]
 
 
 def _attend_fwd(qkv, bias, mask, heads):
     plan = _plan(qkv, mask, heads)
-    comb = _combined_bias(bias, mask, _slot(qkv.shape[1]), plan[2])
+    comb = _combined_bias(bias, mask, _slot(qkv.shape[0]), plan[2])
     return _forward(qkv, comb, heads, plan), (qkv, comb, bias, mask)
 
 
 def _attend_bwd(heads, residuals, g):
     qkv, comb, bias, mask = residuals
-    n = qkv.shape[1]
+    n = qkv.shape[0]
     dqkv, dbias = _backward(qkv, comb, g, heads, _plan(qkv, mask, heads))
     dbias = jnp.sum(_unpack_bias(dbias, heads, n), axis=0).astype(bias.dtype)
     return dqkv, dbias, None if mask is None else jnp.zeros_like(mask)
@@ -355,5 +414,10 @@ def window_attention(qkv: jax.Array, bias: jax.Array,
     mask: (nW, N, N) additive shift mask or None; window ``i`` takes row
           ``i % nW``.
     Returns (BW, N, C).
+
+    The kernels work on the token-major ``(N, BW, lanes)`` arrays; the two
+    transposes here (and their transposes going back) change no bytes where
+    XLA is free to lay the rows out token-major, as it does on the chip.
     """
-    return _attend(qkv, bias, mask, heads)
+    out = _attend(jnp.swapaxes(qkv, 0, 1), bias, mask, heads)
+    return jnp.swapaxes(out, 0, 1)

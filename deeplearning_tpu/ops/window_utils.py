@@ -11,9 +11,11 @@ reference's CUDA kernel — and the path v2 attention and a CPU backend run
 
 XLA note: roll, partition and merge are reshapes and transposes that the TPU
 compiler turns into layout copies, not into the neighbouring matmul's
-epilogue: some 16 ms of a 169 ms Swin-T step at batch 128 on a v5e (PR 24's
-trace), with or without the fused kernels. What the kernels remove is the
-per-window score matrix's trips through HBM.
+epilogue. On the chip it keeps the partitioned windows token-major,
+``(N, BW, C)`` physically, and the fused kernels read and write that order
+(PR 31), so the copies that are left are the partition's, the merge's and the
+roll's own, forward and backward; what the kernels remove is the per-window
+score matrix's trips through HBM and every copy at their own boundary.
 """
 
 from __future__ import annotations

@@ -15,11 +15,13 @@ written for a described chip cannot be read back without one).
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from deeplearning_tpu.models.classification import swin
 from deeplearning_tpu.ops.pallas import flash_attention as flash
 from deeplearning_tpu.ops.pallas import global_attention as global_attn
 from deeplearning_tpu.ops.pallas import nms as pallas_nms
@@ -113,6 +115,13 @@ CASES = {
         512, 12, 4, jnp.bfloat16, grad=True),
     "window_swin_t_s3_b128_grad": _window_case(
         128, 24, 0, jnp.bfloat16, grad=True),
+    # Swin-L's last stage, the widest rows a program holds (48 heads,
+    # C = 1,536), and the 56-px configs' 16 windows of head width 32 in
+    # float32 (8 sublanes a tile)
+    "window_swin_l_s3_b128_grad": _window_case(
+        128, 48, 0, jnp.bfloat16, grad=True),
+    "window_swin_mini_s0_f32_grad_masked": _window_case(
+        512, 2, 16, jnp.float32, grad=True),
     # ViT-B/16 at batch 128, the benchmark's cell (197 tokens, 12 heads of
     # 64), forward + the fused backward; the same served at batch 8 in
     # float32; ViT-L/16's 16 heads; 50 tokens (patch 32) at a ragged batch;
@@ -151,3 +160,41 @@ def test_kernel_compiles_for_v5e(name, chip, compiled_mode):
         compiled = jax.jit(fn).lower(*specs).compile()
     assert "tpu_custom_call" in compiled.as_text(), (
         f"{name}: the compiled program holds no Mosaic kernel")
+
+
+# (token grid, C, heads) of Swin-T's four stages; a stage's second block is
+# the shifted one (the last stage's grid is one window: no shift)
+SWIN_T_STAGES = [(56, 96, 3), (28, 192, 6), (14, 384, 12), (7, 768, 24)]
+_KERNEL_BOUNDARY = re.compile(
+    r'op_name="[^"]*(?:window_attention_fwd/pallas_call|jit\(_backward\)'
+    r'|attn/qkv/dot_general)"')
+
+
+@pytest.mark.parametrize("stage", range(4))
+def test_no_layout_copy_at_the_window_kernels(stage, chip, compiled_mode):
+    """A shifted ``SwinBlock``'s gradient at the benchmark's batch, compiled
+    for the described v5e: the kernels take the token-major rows XLA keeps,
+    so no ``copy`` stands between the qkv matmul and the forward kernel,
+    after the forward kernel, or after the backward kernel's ``dqkv`` (a
+    row-major operand had one at each, 2.1 GB a Swin-T step: PR 31)."""
+    res, c, heads = SWIN_T_STAGES[stage]
+    block = swin.SwinBlock(c, (res, res), heads, 7, 3, dtype=jnp.bfloat16,
+                           name=f"stage{stage}_block1")
+    x = jax.ShapeDtypeStruct((128, res * res, c), jnp.bfloat16, sharding=chip)
+    # ``init`` takes the lax path whatever the backend; shapes alone
+    shapes = jax.eval_shape(lambda: block.init(
+        jax.random.key(0), jnp.zeros((1, res * res, c), jnp.bfloat16)))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=chip), shapes)
+
+    def loss(p, x):
+        return jnp.mean(block.apply(p, x).astype(jnp.float32) ** 2)
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2, "no fused kernels in the block"
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= \S+ copy\(", line)
+              and _KERNEL_BOUNDARY.search(line)]
+    assert not copies, copies

@@ -59,19 +59,30 @@ def _reference(qkv, bias, mask, heads):
         qkv.reshape(bw, n, 3, heads, c3 // 3 // heads), bias, mask)
 
 
-# (bw, heads, d, res, window, masked): the grid each makes is in the id
+# (bw, heads, d, res, window, masked): the grid each makes is in the id. The
+# kernels' blocks are (N, wb windows, lanes) of the token-major rows, wb a
+# multiple of 8 or all of BW
 KERNEL_CASES = {
     "shifted_d32_one_program": (8, 3, 32, 14, 7, True),
     "unshifted_d32_shared_bias": (8, 3, 32, 14, 7, False),
     "shifted_d16_images_per_program": (12, 2, 16, 14, 7, True),
     "unshifted_d16": (6, 2, 16, 14, 7, False),
-    # nW 4, 16 windows a program, 20 windows: the last block is ragged
+    # nW 4 < 16 windows a program (four images), 20 windows: the last block
+    # is ragged and BW is no multiple of 8
     "shifted_bw_not_multiple_of_block": (20, 3, 32, 10, 5, True),
+    # the same with 24 windows: a multiple of 8, not of 16
+    "shifted_bw_multiple_of_8_not_16": (24, 2, 16, 14, 7, True),
+    # no mask, 40 windows of 16 a program: ragged, five heads of 32 (the
+    # odd one stands alone)
+    "unshifted_ragged_odd_heads_d32": (40, 5, 32, 7, 7, False),
     # nW 64 > windows a program (16): four mask-row blocks an image
     "shifted_nw_larger_than_block": (128, 3, 16, 56, 7, True),
-    # nW 9 and 7 heads (an odd one stands alone): 16 windows wanted, so one
-    # image a program; 27 windows, 9 a program
+    # nW 9 and 7 heads (an odd one stands alone): no multiple of 8 up to 16
+    # pairs with 9, and 27 windows are fewer than 8 images: one program
     "shifted_nw_not_a_power_of_two": (27, 7, 16, 21, 7, True),
+    # nW 9, 90 windows: 8 whole images (72 windows) a program, the second
+    # block ragged
+    "shifted_nw_odd_eight_images_per_program": (90, 3, 16, 21, 7, True),
 }
 
 
@@ -114,12 +125,15 @@ class TestPallasWindowAttention:
 
     @pytest.mark.parametrize("bw,nw,heads,want", [
         (8192, 64, 3, 16), (8192, 1, 3, 16), (2048, 16, 6, 16),
-        (512, 4, 12, 8), (128, 1, 24, 4), (18, 9, 8, 9), (2, 1, 2, 2),
-        (12, 4, 2, 12), (7, 7, 48, 1)])
+        (512, 4, 12, 16), (128, 1, 24, 8), (18, 9, 8, 18), (2, 1, 2, 2),
+        (12, 4, 2, 12), (7, 7, 48, 7), (90, 9, 3, 72), (20, 4, 3, 16),
+        (512, 4, 24, 8), (128, 4, 16, 8)])
     def test_windows_per_program_from_shapes(self, bw, nw, heads, want):
         wb = pwa.windows_per_program(bw, nw, heads)
         assert wb == want
         assert nw % wb == 0 or wb % nw == 0
+        # the blocks' second-minor axis: whole sublane tiles, or all of it
+        assert wb % 8 == 0 or wb == bw
 
 
 def _micro():
@@ -251,7 +265,8 @@ class TestSwinModel:
         # 28x28 and 14x14 token grids, one shifted block each
         assert len(events) == 4
         assert all(e["name"] == "window_attention" and e["path"] == "lax"
-                   and e["calls"] == 1 for e in events)
+                   and e["interface"] is None and e["calls"] == 1
+                   for e in events)
         assert sorted(m for e in events for m in e["members"]) == [
             f"stage{s}_block{b}/attn" for s in (0, 1) for b in (0, 1)]
         assert {(tuple(e["shape"]), e["masked"]) for e in events} == {
@@ -264,6 +279,8 @@ class TestSwinModel:
         assert len(events) == 8 and recorder.recorded == 8
         fused = [e for e in events if e["path"] == "fused"]
         assert sum(len(e["members"]) for e in fused) == 4
+        # the rows the kernels read and write: the order XLA keeps on the chip
+        assert all(e["interface"] == "token_major" for e in fused)
         jax.eval_shape(lambda p: model.apply(p, x, train=False), shapes)
         assert recorder.recorded == 8
         assert all(e["calls"] == 2 for e in recorder.events("kernel")
