@@ -1,1 +1,2 @@
-from . import classification, detection, metric, pose, segmentation, ssl, stereo  # noqa: F401
+from . import (classification, detection, language, metric, pose,
+               segmentation, ssl, stereo)  # noqa: F401

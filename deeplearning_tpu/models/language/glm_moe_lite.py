@@ -1,0 +1,248 @@
+"""``glm4_moe_lite`` (GLM-4.7-Flash): a causal decoder with latent attention
+(MLA), sigmoid-routed sparse experts with a shared expert, and a
+multi-token-prediction module. Training path only: keys and values are
+computed in full, there is no latent cache.
+
+With ``x`` a token's hidden state and ``RMSNorm`` at eps 1e-5:
+
+- block: ``h = x + MLA(RMSNorm(x))``, ``out = h + FFN(RMSNorm(h))``; ``FFN`` is
+  a SwiGLU MLP in the first ``first_dense`` layers and the expert layer
+  (``parallel/moe.py::HeldExpertsMlp``) elsewhere; no biases anywhere.
+- MLA: ``c_q = RMSNorm(x W_qa)``; per head ``[q_nope, q_rope] = c_q W_qb``;
+  ``[c_kv, k_rope] = x W_kva``, ``c_kv = RMSNorm(c_kv)``; per head ``[k_nope,
+  v] = c_kv W_kvb``; ``k_rope`` is one vector shared by the heads; rotary
+  embedding over all rope dimensions (pairs ``(i, i + r/2)``) on ``q_rope``
+  and ``k_rope``; scores ``(q_nope . k_nope + q_rope . k_rope) / sqrt(nope +
+  rope)``, causal, softmax in float32; output ``concat_heads(P v) W_o``.
+- MTP (after the last block, before the final norm): ``h' = W_eh
+  [RMSNorm(Emb(t_{i+1})) ; RMSNorm(h_i)]``, one expert-layer block on ``h'``
+  under the same mask, its own final RMSNorm, the model's embedding and
+  head; it predicts ``t_{i+2}``.
+
+A chip holds its share of a stated deployment: ``experts_held`` of
+``n_routed_experts`` a layer from ``first_expert`` on, ``vocab_size`` rows of
+the vocabulary. The attention core picks its own path
+(``ops/pallas/flash_attention.py::select_path``): the fused causal kernels on
+a TPU at whole 128-row blocks and 128-lane head widths, the lax mathematics
+elsewhere. Each layer tallies a ``kernel`` flight event (``mla_attention``)
+with the path it took.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ...core.registry import MODELS
+from ...obs import flight
+from ...ops.pallas import flash_attention as fused
+from ...parallel.moe import HeldExpertsMlp, SwiGLU
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    """``config.json``'s keys, with the chip's share beside them."""
+    vocab_size: int = 154880
+    hidden_size: int = 2048
+    num_hidden_layers: int = 47
+    first_k_dense_replace: int = 1
+    intermediate_size: int = 10240
+    moe_intermediate_size: int = 1536
+    num_attention_heads: int = 20
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    n_routed_experts: int = 64
+    experts_held: int = 64
+    first_expert: int = 0
+    num_experts_per_tok: int = 4
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.8
+    rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-5
+    num_nextn_predict_layers: int = 1
+
+
+def _dense(features: int, dtype, name: str) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, dtype=dtype, name=name,
+                    kernel_init=nn.initializers.normal(0.02))
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(self.dtype)
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding over all of the last axis of (..., N, r), positions
+    0..N-1, dimension i paired with i + r/2; float32 inside."""
+    n, r = x.shape[-2], x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    ang = jnp.arange(n, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., : r // 2], x32[..., r // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class MLA(nn.Module):
+    """Multi-head latent attention, keys and values computed in full."""
+    cfg: DecoderConfig
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.cfg
+        b, n, _ = x.shape
+        h, nope, rope, dv = (c.num_attention_heads, c.qk_nope_head_dim,
+                             c.qk_rope_head_dim, c.v_head_dim)
+        norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)
+        c_q = norm("q_a_norm")(_dense(c.q_lora_rank, self.dtype, "q_a")(x))
+        q = _dense(h * (nope + rope), self.dtype, "q_b")(c_q)
+        q = q.reshape(b, n, h, nope + rope).transpose(0, 2, 1, 3)
+        kv = _dense(c.kv_lora_rank + rope, self.dtype, "kv_a")(x)
+        c_kv = norm("kv_a_norm")(kv[..., : c.kv_lora_rank])
+        k_rope = rotary(kv[..., c.kv_lora_rank:], c.rope_theta)   # (b, n, r)
+        kv = _dense(h * (nope + dv), self.dtype, "kv_b")(c_kv)
+        kv = kv.reshape(b, n, h, nope + dv).transpose(0, 2, 1, 3)
+        q = jnp.concatenate(
+            [q[..., :nope], rotary(q[..., nope:], c.rope_theta)], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_rope[:, None], (b, h, n, rope))], axis=-1)
+        v = kv[..., nope:]
+        path = fused.select_path(
+            n, nope + rope, initializing=self.is_initializing()) \
+            if nope + rope == dv else "lax"
+        flight.tally("kernel", ("mla_attention", path, n, h, dv),
+                     member="/".join(self.path), name="mla_attention",
+                     path=path, shape=[b, h, n, dv])
+        with jax.named_scope("mla_core"):
+            out = fused.causal_attention(q, k, v, (nope + rope) ** -0.5, path)
+        out = out.transpose(0, 2, 1, 3).reshape(b, n, h * dv)
+        return _dense(c.hidden_size, self.dtype, "o")(out)
+
+
+class DecoderBlock(nn.Module):
+    cfg: DecoderConfig
+    dense_ffn: bool
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.cfg
+        norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)
+        h = x + MLA(c, self.dtype, name="attn")(norm("attn_norm")(x))
+        if self.dense_ffn:
+            ffn = SwiGLU(c.intermediate_size, self.dtype, name="mlp")
+        else:
+            ffn = HeldExpertsMlp(
+                num_experts=c.n_routed_experts, held=c.experts_held,
+                first=c.first_expert, top_k=c.num_experts_per_tok,
+                hidden=c.moe_intermediate_size,
+                shared_experts=c.n_shared_experts,
+                routed_scale=c.routed_scaling_factor, dtype=self.dtype,
+                name="moe")
+        return h + ffn(norm("ffn_norm")(h))
+
+
+class CausalLM(nn.Module):
+    """tokens (B, S) int -> float32 logits (B, S, V) of the next token.
+
+    With ``next_tokens`` (B, S), token i + 1 beside token i, the MTP module
+    runs too and a pair comes back (second: logits of token i + 2).
+    ``return_hidden`` hands back the normed hidden states before the head,
+    for a loss that never holds the logits whole (``train/language.py``).
+    Every block is rematerialised in the backward pass."""
+    cfg: DecoderConfig
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False,
+                 next_tokens: Optional[jax.Array] = None,
+                 return_hidden: bool = False):
+        c = self.cfg
+        block_cls = nn.remat(DecoderBlock)
+        norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)
+        embed = nn.Embed(c.vocab_size, c.hidden_size, dtype=self.dtype,
+                         embedding_init=nn.initializers.normal(0.02),
+                         name="embed")
+        head = _dense(c.vocab_size, self.dtype, "head")
+        x = embed(tokens)
+        for i in range(c.num_hidden_layers):
+            x = block_cls(c, i < c.first_k_dense_replace, self.dtype,
+                          name=f"layers_{i}")(x)
+        hidden = [norm("norm")(x)]
+        if c.num_nextn_predict_layers and next_tokens is None \
+                and self.is_initializing():
+            next_tokens = tokens
+        if c.num_nextn_predict_layers and next_tokens is not None:
+            hidden.append(MTP(c, self.dtype, block_cls, name="mtp")(
+                x, embed(next_tokens)))
+        if not return_hidden or self.is_initializing():
+            logits = [head(h).astype(jnp.float32) for h in hidden]
+            if not return_hidden:
+                return logits[0] if len(logits) == 1 else tuple(logits)
+        return tuple(hidden)
+
+
+class MTP(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3 report, section 2.2)."""
+    cfg: DecoderConfig
+    dtype: Any
+    block_cls: Any
+
+    @nn.compact
+    def __call__(self, hidden, next_embedded):
+        c = self.cfg
+        norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)
+        joined = jnp.concatenate([norm("enorm")(next_embedded),
+                                  norm("hnorm")(hidden)], axis=-1)
+        x = _dense(c.hidden_size, self.dtype, "eh_proj")(joined)
+        x = self.block_cls(c, False, self.dtype, name="block")(x)
+        return norm("norm")(x)
+
+
+def _factory(name: str, **published):
+    @MODELS.register(name)
+    def build(num_classes: Optional[int] = None, dtype=jnp.bfloat16,
+              **overrides):
+        """``num_classes`` is the vocabulary this chip holds."""
+        cfg = DecoderConfig(**{**published, **overrides})
+        if num_classes:
+            cfg = dataclasses.replace(cfg, vocab_size=num_classes)
+        return CausalLM(cfg, dtype)
+    # what the entry is: tools/train.py picks the loader and the loss by it
+    build.task = "language"
+    build.__name__ = name
+    return build
+
+
+# GLM-4.7-Flash (30B-A3B; ``DecoderConfig``'s defaults are its published
+# config, which no single chip holds) as one chip's share of 8-way expert
+# parallelism: experts 0-7 of 64 and rows
+# 0-19,359 of the vocabulary, the dense layer and four expert layers (the
+# other 42 lie on further chips as pipeline stages)
+glm47_flash_ep8 = _factory("glm47_flash_ep8", vocab_size=19360,
+                           num_hidden_layers=5, experts_held=8)
+# a CPU-sized decoder of the same shape of block, for tests and smoke runs
+glm_moe_lite_micro = _factory(
+    "glm_moe_lite_micro", vocab_size=512, hidden_size=64, num_hidden_layers=3,
+    intermediate_size=160, moe_intermediate_size=48, num_attention_heads=4,
+    q_lora_rank=32, kv_lora_rank=24, qk_nope_head_dim=24, qk_rope_head_dim=8,
+    v_head_dim=32, n_routed_experts=16, experts_held=4)

@@ -5,15 +5,18 @@ backward are Pallas kernels with a custom VJP; the backward recomputes
 P = exp(S - LSE) blockwise from the saved logsumexp, FlashAttention-2
 style.
 
-What is left here is what sequence parallelism calls, and it stays until
-ROADMAP W8's long-sequence row decides it on the chip:
-``flash_attention_with_lse`` and ``flash_chunk_grads`` are the ring's
-``use_flash`` chunks (parallel/ring_attention.py: the per-row logsumexp
-lets the ring's online-softmax merge combine per-chunk kernel outputs
-exactly), ``flash_attention`` is Ulysses' ``use_flash`` inner attention.
-No model reaches this module on its own: short sequences (ViT's 197
-tokens) are ``global_attention.select_path``'s, windows are
-``window_attention``'s. The head-batched variant that was meant for short
+Two callers. A causal decoder's attention core (``causal_attention``, path
+by ``select_path``: GLM-4.7-Flash's latent attention at 4,096 tokens of head
+width 256, blocks of 512, the key blocks past a query block's last row
+skipped; the benchmark's ``glm47_flash_ep8`` cell, PR 32). And what
+sequence parallelism calls, which stays until ROADMAP W8's long-sequence
+row decides it on the chip: ``flash_attention_with_lse`` and
+``flash_chunk_grads`` are the ring's ``use_flash`` chunks
+(parallel/ring_attention.py: the per-row logsumexp lets the ring's
+online-softmax merge combine per-chunk kernel outputs exactly),
+``flash_attention`` is Ulysses' ``use_flash`` inner attention. Short
+sequences (ViT's 197 tokens) are ``global_attention.select_path``'s,
+windows are ``window_attention``'s. The head-batched variant that was meant for short
 N lost to the lax path on the chip every time it was measured and was
 deleted at PR 30 (PERF.md §6).
 
@@ -37,6 +40,23 @@ from .common import interpret_mode
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
+# Every program holds one head's whole K and V (the backward pass: Q, dO,
+# logsumexp and delta) in VMEM, double-buffered: at 4,096 tokens of width 256
+# that is 16.5 MB, over the compiler's 16 MB default on a v5e (128 MB there).
+_VMEM_LIMIT = 96 * 2 ** 20
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _causal_stop(qi, q_block: int, block_k: int, nk: int):
+    """Key blocks a causal query block has to visit: those that start at or
+    before its last row. The blocks after them are masked whole, so the loop
+    ends there (half the work of a square at long sequences)."""
+    return jnp.minimum(nk, ((qi + 1) * q_block + block_k - 1) // block_k)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
@@ -78,7 +98,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     acc = jnp.zeros((bq, d), jnp.float32)
     m0 = jnp.full((bq,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, nk, body, (acc, m0, l0))
+    acc, m, l = jax.lax.fori_loop(0, _causal_stop(qi, q_block, block_k, nk)
+                                  if causal else nk, body, (acc, m0, l0))
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
     lse = m + jnp.log(l_safe)
@@ -118,7 +139,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
                                       preferred_element_type=jnp.float32)
         return dq
 
-    dq = jax.lax.fori_loop(0, nk, body,
+    dq = jax.lax.fori_loop(0, _causal_stop(qi, q_block, block_k, nk)
+                           if causal else nk, body,
                            jnp.zeros(q.shape, jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
@@ -162,7 +184,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     dk0 = jnp.zeros(k.shape, jnp.float32)
     dv0 = jnp.zeros(v.shape, jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, nq, body, (dk0, dv0))
+    # causal: query blocks that end before this key block starts see none
+    # of it
+    dk, dv = jax.lax.fori_loop((ki * k_block) // block_q if causal else 0,
+                               nq, body, (dk0, dv0))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -202,6 +227,7 @@ def _flash_fwd(q, k, v, sm_scale, kv_len, causal, block_q, block_k):
             jax.ShapeDtypeStruct((b * h, n, 8), jnp.float32),
         ],
         interpret=interpret_mode(),
+        compiler_params=_compiler_params(),
     )(qf, kf, vf)
     out = out.reshape(b, h, n, d)
     return out, (q, k, v, out, lse)
@@ -251,6 +277,7 @@ def _bwd_calls(qf, kf, vf, dof, lse, delta, *, sm_scale, kv_len, causal,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, n, d), out_dtype or qf.dtype),
         interpret=interpret_mode(),
+        compiler_params=_compiler_params(),
     )(qf, kf, vf, dof, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -275,6 +302,7 @@ def _bwd_calls(qf, kf, vf, dof, lse, delta, *, sm_scale, kv_len, causal,
             jax.ShapeDtypeStruct((bh, n, d), out_dtype or vf.dtype),
         ],
         interpret=interpret_mode(),
+        compiler_params=_compiler_params(),
     )(qf, kf, vf, dof, lse, delta)
 
     return dq, dk, dv
@@ -367,9 +395,9 @@ def flash_chunk_grads(q: jax.Array, k: jax.Array, v: jax.Array,
     return unflat(dqf), unflat(dkf), unflat(dvf)
 
 
-def _round_block(n: int) -> int:
-    """Largest power-of-two block <= max(n, 128) capped at 128, >=8."""
-    b = 128
+def _round_block(n: int, cap: int = 128) -> int:
+    """Largest block <= n among ``cap`` halved again and again, >= 8."""
+    b = cap
     while b > 8 and b > n:
         b //= 2
     return max(b, 8)
@@ -379,8 +407,8 @@ def _blocks_and_pad(n, block_q, block_k, *arrays):
     """Clamp block sizes to the sequence and zero-pad every (B, H, N, D)
     array along N to the blocks' lcm. Returns (block_q, block_k, n_pad,
     padded_arrays) — the one place the padding policy lives."""
-    block_q = min(block_q, _round_block(n))
-    block_k = min(block_k, _round_block(n))
+    block_q = _round_block(n, block_q)
+    block_k = _round_block(n, block_k)
     n_pad = -n % math.lcm(block_q, block_k)
     if n_pad:
         pad = [(0, 0), (0, 0), (0, n_pad), (0, 0)]
@@ -394,3 +422,45 @@ def flash_attention_bnhd(q: jax.Array, k: jax.Array, v: jax.Array,
     out = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                           v.transpose(0, 2, 1, 3), **kw)
     return out.transpose(0, 2, 1, 3)
+
+
+# --------------------------------------------------------------------------
+# The causal attention core of a decoder: one path a shape, chosen by what
+# the code can see.
+
+CAUSAL_BLOCK_Q = 512
+CAUSAL_BLOCK_K = 512
+
+
+def select_path(tokens: int, head_width: int, *,
+                initializing: bool = False) -> str:
+    """``"fused"`` where the kernels compile (not the CPU backend, where they
+    would run interpreted) and the shape fills their tiles (whole 128-row
+    blocks, a head width of whole 128-lane tiles) with enough rows that the
+    ``T x T`` scores are worth keeping off HBM; ``"lax"`` everywhere else, and
+    while ``model.init`` runs the layer once, eagerly."""
+    covered = tokens % 128 == 0 and tokens >= 512 and head_width % 128 == 0
+    return ("fused" if covered and not (initializing or interpret_mode())
+            else "lax")
+
+
+def causal_attention_lax(q: jax.Array, k: jax.Array, v: jax.Array,
+                         sm_scale: float) -> jax.Array:
+    """The lax mathematics, softmax in float32: the CPU path and the
+    oracle. q, k, v: (B, H, N, D)."""
+    n = q.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     sm_scale: float, path: str) -> jax.Array:
+    """softmax(q k^T * sm_scale, causal) v over (B, H, N, D) with equal q, k
+    and v widths, by ``path`` (``select_path``)."""
+    if path == "fused":
+        return flash_attention(q, k, v, sm_scale=sm_scale, causal=True,
+                               block_q=CAUSAL_BLOCK_Q, block_k=CAUSAL_BLOCK_K)
+    return causal_attention_lax(q, k, v, sm_scale)

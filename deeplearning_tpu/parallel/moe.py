@@ -12,6 +12,7 @@ top-k routing with dropped-token passthrough, fully static shapes.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Optional, Tuple
 
@@ -19,6 +20,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..obs import flight
 from .mesh import EXPERT_AXIS
 from .sharding import Rules
 from jax.sharding import PartitionSpec as P
@@ -168,3 +170,178 @@ class MoEMlp(nn.Module):
                 * w[:, None].astype(expert_out.dtype)
         out = nn.Dropout(self.drop, deterministic=deterministic)(out)
         return out.reshape(b, n, d), self.aux_weight * aux
+
+
+# --------------------------------------------------------------------------
+# One chip's share of a sigmoid-routed expert layer (DeepSeek-V3 / GLM-4.x
+# ``noaux_tc`` routing): dropless, told which experts it holds.
+
+def sigmoid_route(scores: jax.Array, bias: jax.Array, top_k: int,
+                  scale: float) -> Tuple[jax.Array, jax.Array]:
+    """``scores`` (T, E) are the sigmoids. The ``top_k`` experts with the
+    largest ``scores + bias`` are chosen; their weights come from the
+    scores alone, normalised over the chosen and scaled. (T, k) ids and
+    float32 weights."""
+    _, idx = jax.lax.top_k(scores + bias, top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = scale * chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-20)
+    return idx, weights
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation: the cotangent is ``g[inverse]``, a
+    gather again, where autodiff would scatter-add row by row."""
+    return x[perm]
+
+
+_permute_rows.defvjp(lambda x, perm, inverse: (x[perm], (perm, inverse)),
+                     lambda res, g: (g[res[1]], None, None))
+
+
+@jax.custom_vjp
+def _dispatch_rows(tokens, order, inverse):
+    """Row ``order[i] // k`` of ``tokens`` for every assignment i, k
+    assignments a token: (T, D) -> (T k, D)."""
+    return tokens[order // (order.shape[0] // tokens.shape[0])]
+
+
+def _dispatch_fwd(tokens, order, inverse):
+    return _dispatch_rows(tokens, order, inverse), (inverse, tokens.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    inverse, t = res
+    return g[inverse].reshape(t, -1, g.shape[-1]).sum(1), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+# megablox tiles (rows, contraction, columns) of the grouped product
+_GMM_TILING = (512, 1024, 1024)
+
+
+def grouped_route(rows: int, initializing: bool = False) -> str:
+    """Which grouped product a layer takes, from what the code can see:
+    ``megablox`` (the Pallas kernel that ships with JAX) on a TPU where the
+    rows fill whole tiles; ``ragged_dot`` on the CPU, for other row counts,
+    while ``model.init`` runs the layer once, eagerly, and as the oracle."""
+    fits = rows % _GMM_TILING[0] == 0 and not initializing
+    return "megablox" if fits and jax.default_backend() != "cpu" \
+        else "ragged_dot"
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   route: str) -> jax.Array:
+    """``lhs[rows of group g] @ rhs[g]`` for rows sorted by group: (M, K) x
+    (G, K, N) -> (M, N), device work in proportion to ``sum(group_sizes)``.
+    Rows past that sum hold nothing that may be used (``megablox`` leaves
+    them unwritten): the caller masks them."""
+    with jax.named_scope("expert_matmul"):
+        if route == "megablox":
+            from jax.experimental.pallas.ops.tpu.megablox import ops as mblx
+            return mblx.gmm(lhs, rhs, group_sizes, lhs.dtype, _GMM_TILING)
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+class HeldExpertsMlp(nn.Module):
+    """This chip's share of a sparse expert layer with sigmoid routing and a
+    shared expert. It routes every token over all ``num_experts`` (the
+    published count), computes experts ``first .. first + held - 1`` for the
+    rows whose chosen expert it holds, and adds the shared expert; what the
+    absent experts would have added is left out (the chips that hold them
+    add it in a deployment, through an exchange that one chip has not).
+
+    Dropless: no capacity. The row buffer is sized for the worst case (every
+    choice of every token held here, ``top_k`` x tokens rows), rows are sorted
+    by expert, and the grouped products work on the rows present alone.
+    SwiGLU experts of width ``hidden``, no biases. ``correction_bias`` is the
+    ``noaux_tc`` buffer: it enters the choice, not the weights, and no
+    gradient reaches it."""
+    num_experts: int = 64
+    held: int = 8
+    first: int = 0
+    top_k: int = 4
+    hidden: int = 1536
+    shared_experts: int = 1
+    routed_scale: float = 1.8
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        b, n, d = x.shape
+        t, k, e, held = b * n, self.top_k, self.num_experts, self.held
+        if not 0 <= self.first <= e - held:
+            raise ValueError(f"experts {self.first}..{self.first + held - 1} "
+                             f"are not among {e}")
+        tokens = x.reshape(t, d)
+        init = nn.initializers.normal(0.02)
+        w_r = self.param("router_kernel", init, (d, e), jnp.float32)
+        bias = self.param("correction_bias", nn.initializers.zeros, (e,),
+                          jnp.float32)
+        with jax.named_scope("moe_dispatch"):
+            # the choice is made in float32 as the published code makes it
+            # (a float32 product on the MXU needs ``highest`` to be one)
+            scores = jax.nn.sigmoid(jnp.dot(
+                tokens.astype(jnp.float32), w_r,
+                precision=jax.lax.Precision.HIGHEST))
+            idx, weights = sigmoid_route(scores, jax.lax.stop_gradient(bias),
+                                         k, self.routed_scale)
+            local = idx.reshape(-1) - self.first
+            here = (local >= 0) & (local < held)
+            key = jnp.where(here, local, held)        # absent rows sort last
+            order = jnp.argsort(key, stable=True)
+            inverse = jnp.zeros_like(order).at[order].set(
+                jnp.arange(t * k, dtype=order.dtype))
+            sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                            dtype=jnp.int32)
+            present = jnp.arange(t * k)[:, None] < jnp.sum(sizes)
+            rows = jnp.where(present, _dispatch_rows(
+                tokens.astype(self.dtype), order, inverse), 0)
+        self.sow("moe_metrics", "rows_held", jnp.sum(sizes))
+        self.sow("moe_metrics", "rows_absent", t * k - jnp.sum(sizes))
+        self.sow("moe_metrics", "load_max_over_mean",
+                 jnp.max(sizes) / jnp.maximum(jnp.mean(
+                     sizes.astype(jnp.float32)), 1.0))
+        self.sow("intermediates", "choice", idx)
+
+        f = self.hidden
+        gate = self.param("experts_gate", init, (held, d, f), jnp.float32)
+        up = self.param("experts_up", init, (held, d, f), jnp.float32)
+        down = self.param("experts_down", init, (held, f, d), jnp.float32)
+        route = grouped_route(t * k, self.is_initializing())
+        flight.tally("kernel", ("expert_matmul", route, t * k, d, f, held),
+                     member="/".join(self.path), name="expert_matmul",
+                     path=route, shape=[t * k, d, f, held])
+        both = grouped_matmul(
+            rows, jnp.concatenate([gate, up], -1).astype(self.dtype), sizes,
+            route)
+        act = nn.silu(both[:, :f]) * both[:, f:]
+        out_rows = grouped_matmul(act, down.astype(self.dtype), sizes, route)
+        with jax.named_scope("moe_combine"):
+            out_rows = jnp.where(present, out_rows, 0)
+            per_choice = _permute_rows(out_rows, inverse, order).reshape(
+                t, k, d)
+            w = jnp.where(here.reshape(t, k), weights, 0.0)
+            routed = jnp.einsum("tkd,tk->td", per_choice.astype(jnp.float32),
+                                w).astype(self.dtype)
+        y = routed.reshape(b, n, d)
+        if self.shared_experts:
+            y = y + SwiGLU(self.hidden * self.shared_experts, self.dtype,
+                           name="shared")(x)
+        return y
+
+
+class SwiGLU(nn.Module):
+    """``(silu(x W_g) * (x W_u)) W_d``, no biases."""
+    hidden: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=self.dtype,
+            kernel_init=nn.initializers.normal(0.02))
+        h = nn.silu(dense(self.hidden, name="gate")(x)) \
+            * dense(self.hidden, name="up")(x)
+        return dense(x.shape[-1], name="down")(h)

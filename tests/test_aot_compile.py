@@ -94,6 +94,21 @@ def _flash_grad(q, k, v):
         flash.flash_attention(*qkv).astype(jnp.float32)), (0, 1, 2))(q, k, v)
 
 
+def _causal_grad(q, k, v):
+    """The decoder's attention core (MLA at its published head width), fused,
+    forward + backward."""
+    return jax.grad(lambda *qkv: jnp.sum(flash.causal_attention(
+        *qkv, 256 ** -0.5, "fused").astype(jnp.float32)), (0, 1, 2))(q, k, v)
+
+
+def _grouped_grad(rows, experts, sizes):
+    """The routed experts' grouped product by the Pallas route, forward +
+    both gradients."""
+    from deeplearning_tpu.parallel import moe
+    return jax.grad(lambda x, w: jnp.sum(moe.grouped_matmul(
+        x, w, sizes, "megablox").astype(jnp.float32)), (0, 1))(rows, experts)
+
+
 _NMS = functools.partial(pallas_nms.nms_pallas, iou_threshold=0.5,
                          max_out=100)
 _VIT_QKV = [((8, 12, 197, 64), jnp.bfloat16)] * 3
@@ -145,6 +160,15 @@ CASES = {
     # 1,024 tokens: whole 128-wide blocks, no padded keys
     "flash_fwd_n1024": (flash.flash_attention, _LONG_QKV),
     "flash_grad_n1024": (_flash_grad, _LONG_QKV),
+    # GLM-4.7-Flash's attention core: 4,096 tokens, causal, 20 heads of 256
+    # (one sequence of the cell's four), blocks of 512
+    "flash_causal_n4096_d256_grad": (
+        _causal_grad, [((1, 20, 4096, 256), jnp.bfloat16)] * 3),
+    # its routed experts: 8 held, 2,048 -> gate and up of 1,536, an eighth of
+    # the cell's worst-case row buffer
+    "grouped_megablox_8x2048x3072_grad": (
+        _grouped_grad, [((8192, 2048), jnp.bfloat16),
+                        ((8, 2048, 3072), jnp.bfloat16), ((8,), jnp.int32)]),
 }
 
 

@@ -47,6 +47,7 @@ class DataCfg:
     num_workers: int = 8             # folder-mode decode threads
     augment: str = "imagenet"        # imagenet | light | none
     prefetch: int = 2                # device-feed queue depth (0 = off)
+    seq_len: int = 128               # synthetic token rows (language models)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,11 +116,48 @@ def load_data(cfg: DataCfg, num_classes: int
     return images, labels
 
 
+def model_task(name: str) -> str:
+    """What the registry entry says it is: ``language`` (token rows, the
+    loss of ``train/language.py``) or, unsaid, ``classification``."""
+    from deeplearning_tpu.core.registry import MODELS
+    return getattr(MODELS.get(name), "task", "classification")
+
+
+def _token_loaders(cfg: Config, mesh):
+    """Token rows ``(rows, S + 1)`` int32 from the npz's ``tokens`` (or drawn
+    uniformly over the vocabulary held), through the same ``ArraySource``
+    route as the image sets: a batch is one gather, int32 on the wire."""
+    from deeplearning_tpu.data import ArraySource, DataLoader
+
+    if cfg.data.folder:
+        raise ValueError("a language model reads token rows from data.npz")
+    if cfg.data.npz:
+        tokens = np.load(cfg.data.npz)["tokens"]
+    else:
+        tokens = np.random.default_rng(0).integers(
+            0, cfg.model.num_classes, (cfg.data.n_train, cfg.data.seq_len + 1))
+    tokens = np.ascontiguousarray(tokens, np.int32)
+    gb = cfg.data.global_batch
+    n_val = 0
+    if cfg.data.val_rate > 0 and len(tokens) >= 2 * gb:
+        n_val = min(max(int(len(tokens) * cfg.data.val_rate), gb),
+                    len(tokens) - gb)
+    loader = DataLoader(ArraySource(tokens=tokens[n_val:]), global_batch=gb,
+                        mesh=mesh, seed=cfg.train.seed)
+    eval_loader = DataLoader(ArraySource(tokens=tokens[:n_val or len(tokens)]),
+                             global_batch=gb, mesh=mesh, shuffle=False)
+    # the parameters do not depend on the sequence: a short row initialises
+    return loader, eval_loader, (1, min(tokens.shape[1] - 1, 16)), \
+        len(tokens) - n_val
+
+
 def _build_loaders(cfg: Config, mesh):
     """Train and eval loaders from the folder, the npz or the synthetic
     set: ``(loader, eval_loader, sample_shape, n_train)``."""
     from deeplearning_tpu.data import ArraySource, DataLoader, ScaleUint8
 
+    if model_task(cfg.model.name) == "language":
+        return _token_loaders(cfg, mesh)
     if cfg.data.folder:
         from deeplearning_tpu.data.build import (LoaderConfig,
                                                  build_classification_loaders)
@@ -207,13 +245,17 @@ def build_trainer(cfg: Config, devices=None):
     from deeplearning_tpu.parallel import MeshConfig, build_mesh
     from deeplearning_tpu.train import (TrainState, make_eval_step,
                                         make_train_step, shard_state)
-    from deeplearning_tpu.train.classification import (make_loss_fn,
-                                                       make_metric_fn)
+    from deeplearning_tpu.train import classification, language
     from deeplearning_tpu.train.optim import build_optimizer
     from deeplearning_tpu.train.schedules import build_schedule
     from deeplearning_tpu.train.trainer import Trainer
 
     pp_stages = cfg.train.pipeline_stages
+    lm = model_task(cfg.model.name) == "language"
+    if lm and (pp_stages > 1 or cfg.train.mixup or cfg.train.label_smoothing
+               or cfg.train.mesh_seq_axis > 1):
+        raise ValueError("a language model trains without pipeline_stages, "
+                         "mixup, label_smoothing and mesh_seq_axis")
     if pp_stages > 1 and (cfg.train.mesh_model_axis > 1
                           or cfg.train.mesh_seq_axis > 1):
         raise ValueError("train.pipeline_stages reuses the 'model' mesh "
@@ -278,9 +320,14 @@ def build_trainer(cfg: Config, devices=None):
         model = MODELS.build(cfg.model.name,
                              num_classes=cfg.model.num_classes,
                              dtype=dtype, **model_kw)
-        sample = jnp.zeros(sample_shape)
-        variables = model.init(jax.random.key(cfg.train.seed), sample,
-                               train=False)
+        sample = jnp.zeros(sample_shape, jnp.int32 if lm else jnp.float32)
+        # a decoder's 700 M parameters in one compiled program; run
+        # eagerly, layer by layer, the draw took 4 min of a cold set-up on
+        # the chip (PR 32). The image models keep the eager pass their
+        # cells were measured with.
+        init = jax.jit(model.init, static_argnames="train") if lm \
+            else model.init
+        variables = init(jax.random.key(cfg.train.seed), sample, train=False)
         params = variables["params"]
         k_per_stage = 0
         if pp_stages > 1:
@@ -333,7 +380,9 @@ def build_trainer(cfg: Config, devices=None):
                 label_smoothing=cfg.train.label_smoothing)
         else:
             base_step = make_train_step(
-                make_loss_fn(cfg.train.label_smoothing, has_bn), mesh=mesh,
+                language.make_loss_fn() if lm else
+                classification.make_loss_fn(cfg.train.label_smoothing,
+                                            has_bn), mesh=mesh,
                 accum_steps=cfg.train.accum_steps,
                 donate_batch=cfg.train.donate_batch,
                 weight_update=cfg.train.weight_update,
@@ -360,7 +409,9 @@ def build_trainer(cfg: Config, devices=None):
             train_step=train_step,
             train_loader=loader,
             eval_step=(pp_eval_step if pp_stages > 1
-                       else make_eval_step(make_metric_fn())),
+                       else make_eval_step(
+                           language.make_metric_fn() if lm
+                           else classification.make_metric_fn())),
             eval_loader=eval_loader,
             epochs=cfg.train.epochs,
             seed=cfg.train.seed,
