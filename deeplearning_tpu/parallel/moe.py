@@ -183,46 +183,22 @@ def sigmoid_route(scores: jax.Array, bias: jax.Array, top_k: int,
     scores alone, normalised over the chosen and scaled. (T, k) ids and
     float32 weights."""
     _, idx = jax.lax.top_k(scores + bias, top_k)
-    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    # the chosen experts' scores, picked by comparison: the same values as
+    # ``take_along_axis``, whose gather of scalars and scatter-add back cost
+    # 0.74 + 1.22 ms a layer on a v5e against 0.22 + 0.22 (PR 33)
+    chosen = jnp.sum(jnp.where(
+        idx[..., None] == jnp.arange(scores.shape[-1]), scores[:, None], 0.0),
+        axis=-1)
     weights = scale * chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-20)
     return idx, weights
 
-
-@jax.custom_vjp
-def _permute_rows(x, perm, inverse):
-    """``x[perm]`` for a permutation: the cotangent is ``g[inverse]``, a
-    gather again, where autodiff would scatter-add row by row."""
-    return x[perm]
-
-
-_permute_rows.defvjp(lambda x, perm, inverse: (x[perm], (perm, inverse)),
-                     lambda res, g: (g[res[1]], None, None))
-
-
-@jax.custom_vjp
-def _dispatch_rows(tokens, order, inverse):
-    """Row ``order[i] // k`` of ``tokens`` for every assignment i, k
-    assignments a token: (T, D) -> (T k, D)."""
-    return tokens[order // (order.shape[0] // tokens.shape[0])]
-
-
-def _dispatch_fwd(tokens, order, inverse):
-    return _dispatch_rows(tokens, order, inverse), (inverse, tokens.shape[0])
-
-
-def _dispatch_bwd(res, g):
-    inverse, t = res
-    return g[inverse].reshape(t, -1, g.shape[-1]).sum(1), None, None
-
-
-_dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 # megablox tiles (rows, contraction, columns) of the grouped product
 _GMM_TILING = (512, 1024, 1024)
 
 
 def grouped_route(rows: int, initializing: bool = False) -> str:
-    """Which grouped product a layer takes, from what the code can see:
+    """Which grouped product a row buffer takes, from what the code can see:
     ``megablox`` (the Pallas kernel that ships with JAX) on a TPU where the
     rows fill whole tiles; ``ragged_dot`` on the CPU, for other row counts,
     while ``model.init`` runs the layer once, eagerly, and as the oracle."""
@@ -234,7 +210,8 @@ def grouped_route(rows: int, initializing: bool = False) -> str:
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                    route: str) -> jax.Array:
     """``lhs[rows of group g] @ rhs[g]`` for rows sorted by group: (M, K) x
-    (G, K, N) -> (M, N), device work in proportion to ``sum(group_sizes)``.
+    (G, K, N) -> (M, N), device work in proportion to ``sum(group_sizes)``,
+    which may be anything up to M (the buffer's rows, not the rows present).
     Rows past that sum hold nothing that may be used (``megablox`` leaves
     them unwritten): the caller masks them."""
     with jax.named_scope("expert_matmul"):
@@ -242,6 +219,179 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
             from jax.experimental.pallas.ops.tpu.megablox import ops as mblx
             return mblx.gmm(lhs, rhs, group_sizes, lhs.dtype, _GMM_TILING)
         return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+def buffer_capacity(choices: int, held: int, num_experts: int) -> int:
+    """Rows of the compact buffer of a layer that holds ``held`` of
+    ``num_experts`` experts and routes ``choices`` (tokens x top_k) rows over
+    all of them: twice the rows it can expect, rounded up to the grouped
+    product's row tile, and never more than ``choices``. The size of a pass,
+    not a capacity: a batch that sends more goes through it more than once."""
+    tile = _GMM_TILING[0]
+    twice = -(-2 * choices * held // num_experts)
+    return min(choices, -(-twice // tile) * tile)
+
+
+def _swiglu(both: jax.Array) -> jax.Array:
+    f = both.shape[-1] // 2
+    with jax.named_scope("expert_act"):
+        return nn.silu(both[:, :f]) * both[:, f:]
+
+
+def _sum_choices(src, slot, here, w):
+    """Token side of the row buffer: ``sum_j w[t, j] * src[slot[t, j]]`` over
+    the choices that are ``here``, in float32: (C, D) -> (T, D). A token has
+    ``top_k`` choices wherever they lie, so this reads ``top_k`` x T rows;
+    the absent ones all read one row and are masked."""
+    out = 0.0
+    for j in range(slot.shape[1]):
+        row = jnp.where(here[:, j, None], src[slot[:, j]], 0)
+        out = out + row.astype(jnp.float32) * w[:, j, None]
+    return out
+
+
+def _pass_index(cap, k, lo, order, inverse, sizes):
+    """The pass over sorted rows ``lo .. lo + cap - 1``: how many of each
+    expert's rows lie in it, which of its rows are present, the choice each
+    is (``top_k`` x token + j) and, for every choice, its row in the buffer
+    (clamped) and whether it lies in this pass."""
+    n, ends = jnp.sum(sizes), jnp.cumsum(sizes)
+    in_pass = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - sizes, lo, lo + cap)
+    choice = jax.lax.dynamic_slice(
+        jnp.pad(order, (0, -order.shape[0] % cap)), (lo,), (cap,))
+    at = inverse.reshape(-1, k) - lo
+    return (in_pass, (lo + jnp.arange(cap))[:, None] < n, choice,
+            jnp.clip(at, 0, cap - 1), (at >= 0) & (at < jnp.minimum(cap, n - lo)))
+
+
+# jitted so that a step's expert layers, and both branches of each, trace and
+# lower a pass once (un-jitted, lowering the cell's step took 44 % longer)
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _pass_fwd(route, cap, lo, tokens, w, gate_up, down, order, inverse, sizes):
+    """What sorted rows ``lo .. lo + cap - 1`` add to the routed experts'
+    output, (T, D) in float32, and what ``_pass_bwd`` reads beside. Every
+    array on the rows side has ``cap`` rows."""
+    with jax.named_scope("moe_dispatch"):
+        in_pass, present, choice, slot, here = _pass_index(
+            cap, w.shape[1], lo, order, inverse, sizes)
+        rows = jnp.where(present, tokens[choice // w.shape[1]], 0)
+    both = grouped_matmul(rows, gate_up, in_pass, route)
+    act = _swiglu(both)
+    out_rows = grouped_matmul(act, down, in_pass, route)
+    with jax.named_scope("moe_combine"):
+        out_rows = jnp.where(present, out_rows, 0)
+        routed = _sum_choices(out_rows, slot, here, w)
+    return routed, (rows, both, act, out_rows)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _pass_bwd(route, cap, lo, args, saved, g):
+    """Cotangents of ``_pass_fwd``'s tokens, weights and expert kernels.
+    Rows-side directions are ``cap``-row gathers from (T, D) arrays, the
+    weights' cotangent a dot product a row sent back as a scalar; the
+    (T, k, D) cotangent of the weighted sum never stands."""
+    tokens, w, gate_up, down, order, inverse, sizes = args
+    rows, both, act, out_rows = saved
+    with jax.named_scope("moe_combine"):
+        in_pass, present, choice, slot, here = _pass_index(
+            cap, w.shape[1], lo, order, inverse, sizes)
+        g_rows = g[choice // w.shape[1]].astype(jnp.float32)
+        d_out = jnp.where(present, (g_rows * w.reshape(-1)[choice][:, None]
+                                    ).astype(out_rows.dtype), 0)
+        d_w = jnp.where(here, jnp.sum(out_rows.astype(jnp.float32) * g_rows,
+                                      -1)[slot], 0)
+    product = lambda a, b: grouped_matmul(a, b, in_pass, route)
+    # the scopes again, plain: inside ``jax.vjp`` they read
+    # ``transpose(jvp(expert_matmul))``, which no reader of op paths matches
+    with jax.named_scope("expert_matmul"):
+        d_act, d_down = jax.vjp(product, act, down)[1](d_out)
+    with jax.named_scope("expert_act"):
+        d_both, = jax.vjp(_swiglu, both)[1](d_act)
+    with jax.named_scope("expert_matmul"):
+        d_rows, d_gate_up = jax.vjp(product, rows, gate_up)[1](d_both)
+    with jax.named_scope("moe_dispatch"):
+        d_rows = jnp.where(present, d_rows, 0)
+        d_tokens = _sum_choices(d_rows, slot, here, here.astype(jnp.float32))
+    return d_tokens, d_w, d_gate_up, d_down
+
+
+def _passes(cap, sizes):
+    """Passes the rows present take through a buffer of ``cap`` rows."""
+    return -(-jnp.sum(sizes) // cap)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed(route, cap, tokens, w, gate_up, down, order, inverse, sizes):
+    """The routed experts' part: (T, D) tokens, (T, k) float32 weights that
+    are 0 where the choice is absent, rows sorted by expert through ``order``
+    and ``inverse`` -> (T, D). The rows present go through a buffer of ``cap``
+    rows: in one pass where they fit it (what its backward reads is kept),
+    in as many as it takes where they do not (summed in float32; the backward
+    makes each pass's forward again). Chosen on the device from the row
+    count of the batch at hand; every row is computed either way."""
+    return _routed_fwd(route, cap, tokens, w, gate_up, down, order, inverse,
+                       sizes)[0]
+
+
+def _one_pass_or_more(cap, order, sizes, one_pass, passes, *operands):
+    """``one_pass`` where the buffer holds every choice, else the device's
+    pick of it or ``passes`` from the rows present."""
+    if cap == order.shape[0]:
+        return one_pass(*operands)
+    return jax.lax.cond(_passes(cap, sizes) <= 1, one_pass, passes, *operands)
+
+
+def _routed_fwd(route, cap, *args):
+    tokens, _, _, _, order, _, sizes = args
+
+    def one_pass(*args):
+        routed, saved = _pass_fwd(route, cap, jnp.int32(0), *args)
+        return routed.astype(tokens.dtype), saved
+
+    def passes(*args):
+        routed = jax.lax.fori_loop(
+            0, _passes(cap, sizes),
+            lambda i, sum_: sum_ + _pass_fwd(route, cap, i * cap, *args)[0],
+            jnp.zeros(tokens.shape, jnp.float32))
+        return routed.astype(tokens.dtype), jax.tree.map(
+            lambda a: jnp.zeros(a.shape, a.dtype),
+            jax.eval_shape(one_pass, *args)[1])
+
+    routed, saved = _one_pass_or_more(cap, order, sizes, one_pass, passes,
+                                      *args)
+    return routed, (args, saved)
+
+
+def _routed_bwd(route, cap, res, g):
+    args, saved = res
+    *primal, order, _, sizes = args
+    like_primal = lambda grads: tuple(
+        d.astype(a.dtype) for d, a in zip(grads, primal))
+
+    def one_pass(args, saved, g):
+        return like_primal(_pass_bwd(route, cap, jnp.int32(0), args, saved, g))
+
+    def passes(args, saved, g):
+        def add(i, sums):
+            saved = _pass_fwd(route, cap, i * cap, *args)[1]
+            grads = _pass_bwd(route, cap, i * cap, args, saved, g)
+            return tuple(a + d.astype(a.dtype) for a, d in zip(sums, grads))
+        # the tokens' cotangent is summed over the passes in float32 like
+        # the output; the kernels' in their own dtype, as steps of gradient
+        # accumulation are (float32 sums of them put 0.7 GB on the step's
+        # temporaries, compiled for a v5e at 16,384 tokens: PR 33)
+        tokens, w, gate_up, down = primal
+        return like_primal(jax.lax.fori_loop(
+            0, _passes(cap, sizes), add,
+            (jnp.zeros(tokens.shape, jnp.float32), jnp.zeros_like(w),
+             jnp.zeros_like(gate_up), jnp.zeros_like(down))))
+
+    grads = _one_pass_or_more(cap, order, sizes, one_pass, passes, args,
+                              saved, g)
+    return (*grads, None, None, None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 class HeldExpertsMlp(nn.Module):
@@ -252,9 +402,17 @@ class HeldExpertsMlp(nn.Module):
     absent experts would have added is left out (the chips that hold them
     add it in a deployment, through an exchange that one chip has not).
 
-    Dropless: no capacity. The row buffer is sized for the worst case (every
-    choice of every token held here, ``top_k`` x tokens rows), rows are sorted
-    by expert, and the grouped products work on the rows present alone.
+    Dropless: no capacity, every chosen held expert is computed under any
+    routing. Rows are sorted by expert and the grouped products work on the
+    rows present alone. The row buffer, and every gather, mask and activation
+    on it, has ``buffer_capacity`` rows: twice this chip's expected share of
+    the ``top_k`` x tokens choices. A batch whose rows present fit it goes
+    through in one pass; one that sends more (up to every choice of every
+    token) goes through the same buffer as often as it takes, which the
+    layer decides on the device from the count it has just computed
+    (``buffer_rows`` in ``moe_metrics``: the buffer's rows times the passes).
+    A layer that holds every expert has a buffer of every choice and no such
+    decision.
     SwiGLU experts of width ``hidden``, no biases. ``correction_bias`` is the
     ``noaux_tc`` buffer: it enters the choice, not the weights, and no
     gradient reaches it."""
@@ -279,6 +437,7 @@ class HeldExpertsMlp(nn.Module):
         w_r = self.param("router_kernel", init, (d, e), jnp.float32)
         bias = self.param("correction_bias", nn.initializers.zeros, (e,),
                           jnp.float32)
+        cap = buffer_capacity(t * k, held, e)
         with jax.named_scope("moe_dispatch"):
             # the choice is made in float32 as the published code makes it
             # (a float32 product on the MXU needs ``highest`` to be one)
@@ -291,15 +450,15 @@ class HeldExpertsMlp(nn.Module):
             here = (local >= 0) & (local < held)
             key = jnp.where(here, local, held)        # absent rows sort last
             order = jnp.argsort(key, stable=True)
-            inverse = jnp.zeros_like(order).at[order].set(
-                jnp.arange(t * k, dtype=order.dtype))
+            inverse = jnp.argsort(order)      # a sort again beats a scatter
             sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
                             dtype=jnp.int32)
-            present = jnp.arange(t * k)[:, None] < jnp.sum(sizes)
-            rows = jnp.where(present, _dispatch_rows(
-                tokens.astype(self.dtype), order, inverse), 0)
-        self.sow("moe_metrics", "rows_held", jnp.sum(sizes))
-        self.sow("moe_metrics", "rows_absent", t * k - jnp.sum(sizes))
+            w = jnp.where(here.reshape(t, k), weights, 0.0)
+        rows_held = jnp.sum(sizes)
+        self.sow("moe_metrics", "rows_held", rows_held)
+        self.sow("moe_metrics", "rows_absent", t * k - rows_held)
+        self.sow("moe_metrics", "buffer_rows",
+                 cap * jnp.maximum(_passes(cap, sizes), 1))
         self.sow("moe_metrics", "load_max_over_mean",
                  jnp.max(sizes) / jnp.maximum(jnp.mean(
                      sizes.astype(jnp.float32)), 1.0))
@@ -309,22 +468,14 @@ class HeldExpertsMlp(nn.Module):
         gate = self.param("experts_gate", init, (held, d, f), jnp.float32)
         up = self.param("experts_up", init, (held, d, f), jnp.float32)
         down = self.param("experts_down", init, (held, f, d), jnp.float32)
-        route = grouped_route(t * k, self.is_initializing())
-        flight.tally("kernel", ("expert_matmul", route, t * k, d, f, held),
+        route = grouped_route(cap, self.is_initializing())
+        flight.tally("kernel", ("expert_matmul", route, cap, d, f, held),
                      member="/".join(self.path), name="expert_matmul",
-                     path=route, shape=[t * k, d, f, held])
-        both = grouped_matmul(
-            rows, jnp.concatenate([gate, up], -1).astype(self.dtype), sizes,
-            route)
-        act = nn.silu(both[:, :f]) * both[:, f:]
-        out_rows = grouped_matmul(act, down.astype(self.dtype), sizes, route)
-        with jax.named_scope("moe_combine"):
-            out_rows = jnp.where(present, out_rows, 0)
-            per_choice = _permute_rows(out_rows, inverse, order).reshape(
-                t, k, d)
-            w = jnp.where(here.reshape(t, k), weights, 0.0)
-            routed = jnp.einsum("tkd,tk->td", per_choice.astype(jnp.float32),
-                                w).astype(self.dtype)
+                     path=route, shape=[cap, d, f, held])
+        routed = _routed(
+            route, cap, tokens.astype(self.dtype), w,
+            jnp.concatenate([gate, up], -1).astype(self.dtype),
+            down.astype(self.dtype), order, inverse, sizes)
         y = routed.reshape(b, n, d)
         if self.shared_experts:
             y = y + SwiGLU(self.hidden * self.shared_experts, self.dtype,

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from .state import TrainState
 
-_COUNTERS = ("rows_held", "rows_absent", "load_max_over_mean")
+_COUNTERS = ("rows_held", "rows_absent", "buffer_rows", "load_max_over_mean")
 
 
 def blocked_cross_entropy(hidden: jax.Array, kernel: jax.Array,

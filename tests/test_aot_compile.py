@@ -26,6 +26,7 @@ from deeplearning_tpu.ops.pallas import flash_attention as flash
 from deeplearning_tpu.ops.pallas import global_attention as global_attn
 from deeplearning_tpu.ops.pallas import nms as pallas_nms
 from deeplearning_tpu.ops.pallas import window_attention as window
+from deeplearning_tpu.parallel import moe
 
 TOPOLOGY = "v5e:2x2"
 
@@ -104,7 +105,6 @@ def _causal_grad(q, k, v):
 def _grouped_grad(rows, experts, sizes):
     """The routed experts' grouped product by the Pallas route, forward +
     both gradients."""
-    from deeplearning_tpu.parallel import moe
     return jax.grad(lambda x, w: jnp.sum(moe.grouped_matmul(
         x, w, sizes, "megablox").astype(jnp.float32)), (0, 1))(rows, experts)
 
@@ -164,8 +164,8 @@ CASES = {
     # (one sequence of the cell's four), blocks of 512
     "flash_causal_n4096_d256_grad": (
         _causal_grad, [((1, 20, 4096, 256), jnp.bfloat16)] * 3),
-    # its routed experts: 8 held, 2,048 -> gate and up of 1,536, an eighth of
-    # the cell's worst-case row buffer
+    # its routed experts: 8 held, 2,048 -> gate and up of 1,536, half the
+    # cell's row buffer
     "grouped_megablox_8x2048x3072_grad": (
         _grouped_grad, [((8192, 2048), jnp.bfloat16),
                         ((8, 2048, 3072), jnp.bfloat16), ((8,), jnp.int32)]),
@@ -184,6 +184,36 @@ def test_kernel_compiles_for_v5e(name, chip, compiled_mode):
         compiled = jax.jit(fn).lower(*specs).compile()
     assert "tpu_custom_call" in compiled.as_text(), (
         f"{name}: the compiled program holds no Mosaic kernel")
+
+
+def test_compact_expert_layer_compiles_for_v5e(chip, monkeypatch):
+    """GLM-4.7-Flash's expert layer as the benchmark's cell runs it (16,384
+    tokens of width 2,048, 8 of 64 experts held, 8 x 2,048 x 3,072 and 8 x
+    1,536 x 2,048 expert kernels, a row buffer of 16,384 rows), forward +
+    backward: the grouped products are Mosaic kernels, 2 forward and 4
+    backward where the rows fit the buffer in one pass (the forward's dead
+    copies inside ``jax.vjp`` are gone), and 2, then 2 again + 4 in the loops
+    over several passes."""
+    # this process's backend is the CPU: the route a TPU would take
+    monkeypatch.setattr(
+        moe, "grouped_route", lambda rows, initializing=False:
+        "ragged_dot" if initializing else "megablox")
+    layer = moe.HeldExpertsMlp(shared_experts=0)
+    x = jax.ShapeDtypeStruct((4, 4096, 2048), jnp.bfloat16, sharding=chip)
+    assert moe.buffer_capacity(4 * 4096 * layer.top_k, 8, 64) == 16384
+    shapes = jax.eval_shape(lambda: layer.init(
+        jax.random.key(0), jnp.zeros((1, 128, 2048), jnp.bfloat16)))["params"]
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=chip), shapes)
+
+    def loss(p, x):
+        return jnp.mean(layer.apply({"params": p}, x).astype(jnp.float32) ** 2)
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, x).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 6 + 8
 
 
 # (token grid, C, heads) of Swin-T's four stages; a stage's second block is

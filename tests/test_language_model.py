@@ -204,6 +204,93 @@ def test_no_row_is_dropped_when_every_token_picks_the_same_experts():
     assert np.all(np.isfinite(np.asarray(g)))
 
 
+def _steered(held_tokens, single_tokens, tokens=512):
+    """A 16-expert layer's parameters and input in which ``held_tokens``
+    tokens send all four choices to held experts 0-3, ``single_tokens`` one
+    choice (to expert 1) and the others none: 4 x held_tokens + single_tokens
+    rows present."""
+    p = _layer_params(jax.random.key(21), 4)
+    steer = np.zeros((64, 16), np.float32)
+    steer[0, 0:4] = steer[1, 4:8] = steer[2, [1, 8, 9, 10]] = 1.0
+    p["router_kernel"] = 0.05 * p["router_kernel"] + steer
+    p["correction_bias"] = 0.1 * p["correction_bias"]
+    kind = np.ones(tokens, np.int32)
+    kind[:held_tokens] = 0
+    kind[held_tokens:held_tokens + single_tokens] = 2
+    kind = np.random.default_rng(3).permutation(kind)
+    x = 0.1 * jax.random.normal(jax.random.key(22), (2, tokens // 2, 64))
+    x = x + 2.0 * jax.nn.one_hot(kind, 64).reshape(x.shape)
+    return p, x
+
+
+def _mix(x):
+    return jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+
+
+def _layer_value_and_grad(p, x, held=4):
+    layer = moe.HeldExpertsMlp(num_experts=16, held=held, top_k=4, hidden=48,
+                               dtype=jnp.float32)
+    mix = _mix(x)
+
+    def loss(p, x):
+        y, sown = layer.apply({"params": p}, x, mutable=["moe_metrics"])
+        return jnp.sum(y * mix), (y, sown["moe_metrics"])
+    return jax.value_and_grad(loss, (0, 1), has_aux=True), p, x
+
+
+# 512 tokens x 4 choices over 16 experts of which 4 are held: a row buffer
+# of 1,024 rows for 2,048 choices
+@pytest.mark.parametrize("held_tokens,single_tokens,buffer_rows", [
+    (0, 0, 1024), (100, 3, 1024), (256, 0, 1024), (256, 1, 2048),
+    (512, 0, 2048)], ids=["none", "some", "exactly_c", "c_plus_1", "all"])
+def test_row_buffer_equals_the_reference_and_the_full_buffer(
+        monkeypatch, held_tokens, single_tokens, buffer_rows):
+    """Value and every gradient, whatever share of the rows is present,
+    against the plain reference and against a buffer of every choice; one
+    row past the buffer and the layer makes a second pass: no row is
+    dropped."""
+    assert moe.buffer_capacity(2048, 4, 16) == 1024
+    fn, p, x = _layer_value_and_grad(*_steered(held_tokens, single_tokens))
+    (_, (y, counters)), grads = fn(p, x)
+    assert int(counters["rows_held"][0]) == 4 * held_tokens + single_tokens
+    assert int(counters["buffer_rows"][0]) == buffer_rows
+    mix = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+    def reference(p, x):
+        y = ref.expert_layer(x, p, SHAPES, "f32")[0]
+        return jnp.sum(y * mix), y
+    theirs = jax.value_and_grad(reference, (0, 1), has_aux=True)(p, x)
+    _close(y, theirs[0][1], 2e-5)
+    monkeypatch.setattr(moe, "buffer_capacity", lambda choices, *_: choices)
+    (_, (y_full, counters)), grads_full = fn(p, x)
+    assert int(counters["buffer_rows"][0]) == 2048
+    _close(y, y_full, 1e-6)
+    for mine, full, ref_grad in zip(*map(jax.tree.leaves,
+                                         (grads, grads_full, theirs[1]))):
+        _close(mine, full, 1e-6)
+        _close(mine, ref_grad, 2e-5)
+    assert float(jnp.abs(grads[0]["experts_down"]).max()) > 0 \
+        or not held_tokens + single_tokens
+
+
+def test_no_full_size_rows_and_no_scatter():
+    """A share of 1/8, value and gradient: nothing as large as tokens x top_k
+    rows at the model's width stands in either direction, one pass or
+    several (the token side sums top_k gathers of one row a token), and
+    nothing scatters."""
+    from deeplearning_tpu.analysis import jaxpr as audit
+    fn, p, x = _layer_value_and_grad(*_steered(40, 5), held=2)
+    p = {k: v[:2] if k.startswith("experts_") else v for k, v in p.items()}
+    traced = jax.make_jaxpr(fn)(p, x)
+    choices, d = 512 * 4, 64
+    assert moe.buffer_capacity(choices, 2, 16) == 512
+    conds = [e for e in audit.iter_eqns(traced) if e.primitive.name == "cond"]
+    assert len(conds) == 2                       # forward, backward
+    assert max(a.size for a in audit.iter_avals(traced) if a.shape) \
+        < choices * d
+    assert not [e for e in audit.iter_eqns(traced)
+                if "scatter" in e.primitive.name]
+
+
 def test_blocked_loss_equals_the_whole_one():
     h = jax.random.normal(jax.random.key(9), (64, 32), jnp.float32)
     w = jax.random.normal(jax.random.key(10), (32, 50), jnp.float32)
@@ -255,6 +342,12 @@ def test_grouped_route_by_backend_and_rows(monkeypatch):
     assert moe.grouped_route(65536) == "megablox"
     assert moe.grouped_route(64) == "ragged_dot"
     assert moe.grouped_route(65536, initializing=True) == "ragged_dot"
+    # the compact buffer is whole row tiles, so it takes the kernel too
+    assert moe.buffer_capacity(65536, 8, 64) == 16384
+    assert moe.grouped_route(moe.buffer_capacity(4 * 1000, 8, 64)) == "megablox"
+    assert moe.buffer_capacity(4 * 1000, 8, 64) == 1024
+    assert moe.buffer_capacity(4 * 100, 8, 64) == 400      # never past the full one
+    assert moe.buffer_capacity(65536, 64, 64) == 65536
 
 
 def test_two_steps_through_build_trainer_on_a_token_npz(tmp_path):
@@ -284,6 +377,9 @@ def test_two_steps_through_build_trainer_on_a_token_npz(tmp_path):
         assert seen[0][f"moe/rows_held/{layer}"] \
             + seen[0][f"moe/rows_absent/{layer}"] == 8 * 32 * 4
         assert seen[0][f"moe/load_max_over_mean/{layer}"] >= 1.0
+        # 4 of 16 experts held: half the choices' rows are the buffer, and a
+        # batch that sends more goes through it twice
+        assert seen[0][f"moe/buffer_rows/{layer}"] in (8 * 32 * 2, 8 * 32 * 4)
     kernels = {(e["name"], e["path"]) for e in recorder.events("kernel")
                if "name" in e}
     assert {("mla_attention", "lax"), ("expert_matmul", "ragged_dot")} <= kernels
