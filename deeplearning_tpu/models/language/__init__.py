@@ -1,1 +1,1 @@
-from . import glm_moe_lite  # noqa: F401
+from . import glm_moe_lite, mellum  # noqa: F401

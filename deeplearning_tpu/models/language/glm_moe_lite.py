@@ -25,22 +25,25 @@ the vocabulary. The attention core picks its own path
 (``ops/pallas/flash_attention.py::select_path``): the fused causal kernels on
 a TPU at whole 128-row blocks and 128-lane head widths, the lax mathematics
 elsewhere. Each layer tallies a ``kernel`` flight event (``mla_attention``)
-with the path it took.
+with the path it took. The skeleton round the blocks (embedding, remat, final
+norm, head, the registry factory) is ``decoder.py``'s, shared with
+``mellum.py``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ...core.registry import MODELS
 from ...obs import flight
 from ...ops.pallas import flash_attention as fused
 from ...parallel.moe import HeldExpertsMlp, SwiGLU
+from . import decoder
+from .decoder import RMSNorm, _dense, _factory
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,36 +71,18 @@ class DecoderConfig:
     rms_norm_eps: float = 1e-5
     num_nextn_predict_layers: int = 1
 
+    def block(self, i: int, dtype, name: str):
+        return _RematBlock(self, i < self.first_k_dense_replace, dtype,
+                           name=name)
 
-def _dense(features: int, dtype, name: str) -> nn.Dense:
-    return nn.Dense(features, use_bias=False, dtype=dtype, name=name,
-                    kernel_init=nn.initializers.normal(0.02))
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        x32 = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(self.dtype)
+    def mtp(self, dtype, name: str):
+        return MTP(self, dtype, name=name) \
+            if self.num_nextn_predict_layers else None
 
 
 def rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding over all of the last axis of (..., N, r), positions
-    0..N-1, dimension i paired with i + r/2; float32 inside."""
-    n, r = x.shape[-2], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
-    ang = jnp.arange(n, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x32 = x.astype(jnp.float32)
-    a, b = x32[..., : r // 2], x32[..., r // 2:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
+    """``decoder.rotary`` at the unscaled frequencies of ``theta``."""
+    return decoder.rotary(x, decoder.rope_inv_freq(theta, x.shape[-1]))
 
 
 class MLA(nn.Module):
@@ -161,51 +146,13 @@ class DecoderBlock(nn.Module):
         return h + ffn(norm("ffn_norm")(h))
 
 
-class CausalLM(nn.Module):
-    """tokens (B, S) int -> float32 logits (B, S, V) of the next token.
-
-    With ``next_tokens`` (B, S), token i + 1 beside token i, the MTP module
-    runs too and a pair comes back (second: logits of token i + 2).
-    ``return_hidden`` hands back the normed hidden states before the head,
-    for a loss that never holds the logits whole (``train/language.py``).
-    Every block is rematerialised in the backward pass."""
-    cfg: DecoderConfig
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, tokens, train: bool = False,
-                 next_tokens: Optional[jax.Array] = None,
-                 return_hidden: bool = False):
-        c = self.cfg
-        block_cls = nn.remat(DecoderBlock)
-        norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)
-        embed = nn.Embed(c.vocab_size, c.hidden_size, dtype=self.dtype,
-                         embedding_init=nn.initializers.normal(0.02),
-                         name="embed")
-        head = _dense(c.vocab_size, self.dtype, "head")
-        x = embed(tokens)
-        for i in range(c.num_hidden_layers):
-            x = block_cls(c, i < c.first_k_dense_replace, self.dtype,
-                          name=f"layers_{i}")(x)
-        hidden = [norm("norm")(x)]
-        if c.num_nextn_predict_layers and next_tokens is None \
-                and self.is_initializing():
-            next_tokens = tokens
-        if c.num_nextn_predict_layers and next_tokens is not None:
-            hidden.append(MTP(c, self.dtype, block_cls, name="mtp")(
-                x, embed(next_tokens)))
-        if not return_hidden or self.is_initializing():
-            logits = [head(h).astype(jnp.float32) for h in hidden]
-            if not return_hidden:
-                return logits[0] if len(logits) == 1 else tuple(logits)
-        return tuple(hidden)
+_RematBlock = nn.remat(DecoderBlock)
 
 
 class MTP(nn.Module):
     """One multi-token-prediction module (DeepSeek-V3 report, section 2.2)."""
     cfg: DecoderConfig
     dtype: Any
-    block_cls: Any
 
     @nn.compact
     def __call__(self, hidden, next_embedded):
@@ -214,23 +161,9 @@ class MTP(nn.Module):
         joined = jnp.concatenate([norm("enorm")(next_embedded),
                                   norm("hnorm")(hidden)], axis=-1)
         x = _dense(c.hidden_size, self.dtype, "eh_proj")(joined)
-        x = self.block_cls(c, False, self.dtype, name="block")(x)
+        # an expert-layer block, as the layers after the dense ones are
+        x = c.block(c.num_hidden_layers, self.dtype, name="block")(x)
         return norm("norm")(x)
-
-
-def _factory(name: str, **published):
-    @MODELS.register(name)
-    def build(num_classes: Optional[int] = None, dtype=jnp.bfloat16,
-              **overrides):
-        """``num_classes`` is the vocabulary this chip holds."""
-        cfg = DecoderConfig(**{**published, **overrides})
-        if num_classes:
-            cfg = dataclasses.replace(cfg, vocab_size=num_classes)
-        return CausalLM(cfg, dtype)
-    # what the entry is: tools/train.py picks the loader and the loss by it
-    build.task = "language"
-    build.__name__ = name
-    return build
 
 
 # GLM-4.7-Flash (30B-A3B; ``DecoderConfig``'s defaults are its published
@@ -238,11 +171,12 @@ def _factory(name: str, **published):
 # parallelism: experts 0-7 of 64 and rows
 # 0-19,359 of the vocabulary, the dense layer and four expert layers (the
 # other 42 lie on further chips as pipeline stages)
-glm47_flash_ep8 = _factory("glm47_flash_ep8", vocab_size=19360,
+glm47_flash_ep8 = _factory("glm47_flash_ep8", DecoderConfig, vocab_size=19360,
                            num_hidden_layers=5, experts_held=8)
 # a CPU-sized decoder of the same shape of block, for tests and smoke runs
 glm_moe_lite_micro = _factory(
-    "glm_moe_lite_micro", vocab_size=512, hidden_size=64, num_hidden_layers=3,
-    intermediate_size=160, moe_intermediate_size=48, num_attention_heads=4,
-    q_lora_rank=32, kv_lora_rank=24, qk_nope_head_dim=24, qk_rope_head_dim=8,
-    v_head_dim=32, n_routed_experts=16, experts_held=4)
+    "glm_moe_lite_micro", DecoderConfig, vocab_size=512, hidden_size=64,
+    num_hidden_layers=3, intermediate_size=160, moe_intermediate_size=48,
+    num_attention_heads=4, q_lora_rank=32, kv_lora_rank=24,
+    qk_nope_head_dim=24, qk_rope_head_dim=8, v_head_dim=32,
+    n_routed_experts=16, experts_held=4)

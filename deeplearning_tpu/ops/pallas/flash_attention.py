@@ -6,9 +6,12 @@ P = exp(S - LSE) blockwise from the saved logsumexp, FlashAttention-2
 style.
 
 Two callers. A causal decoder's attention core (``causal_attention``, path
-by ``select_path``: GLM-4.7-Flash's latent attention at 4,096 tokens of head
-width 256, blocks of 512, the key blocks past a query block's last row
-skipped; the benchmark's ``glm47_flash_ep8`` cell, PR 32). And what
+by ``select_path``, blocks of 512, the key blocks past a query block's last
+row skipped: GLM-4.7-Flash's latent attention at 4,096 tokens of head width
+256, the benchmark's ``glm47_flash_ep8`` cell, PR 32; and Mellum2's grouped
+heads at 8,192 tokens of width 128, 8 query heads reading one key/value
+head, in sliding layers the key blocks before a query block's window
+skipped too, the ``mellum2_ep4`` cell, PR 34). And what
 sequence parallelism calls, which stays until ROADMAP W8's long-sequence
 row decides it on the chip: ``flash_attention_with_lse`` and
 ``flash_chunk_grads`` are the ring's ``use_flash`` chunks
@@ -20,8 +23,13 @@ windows are ``window_attention``'s. The head-batched variant that was meant for 
 N lost to the lax path on the chip every time it was measured and was
 deleted at PR 30 (PERF.md §6).
 
-Layout: (B, H, N, D). N must be a multiple of the block size — wrappers
-pad and mask via ``kv_len`` (the number of valid key tokens).
+Layout: (B, H, N, D); K and V may have fewer heads, (B, H / g, N, D): query
+head ``j`` then reads key/value head ``j // g`` through the block index, no
+repeated K or V stands in HBM, and ``dK``, ``dV`` come out of the kernel a
+query head each and are summed over a group after it (PERF.md §6, PR 34, for
+the in-kernel sum that lost). N must be a multiple of the block size —
+wrappers pad and mask via ``kv_len`` (the number of valid key tokens).
+``window`` (causal only) lets query ``i`` see keys ``i - window + 1 .. i``.
 """
 
 from __future__ import annotations
@@ -59,15 +67,39 @@ def _causal_stop(qi, q_block: int, block_k: int, nk: int):
     return jnp.minimum(nk, ((qi + 1) * q_block + block_k - 1) // block_k)
 
 
+def _key_loop(qi, q_block: int, block_k: int, nk: int, causal: bool, window):
+    """(first, one past the last) key block a query block visits: causal,
+    up to ``_causal_stop``; with a window, from the block that holds key
+    ``first row - window + 1``."""
+    if not causal:
+        return 0, nk
+    first = 0 if window is None else jnp.maximum(
+        0, (qi * q_block - window + 1) // block_k)
+    return first, _causal_stop(qi, q_block, block_k, nk)
+
+
+def _visible(row, col, kv_len: int, causal: bool, window):
+    """Query ``row`` sees key ``col``: a key that is there, causal: at or
+    before the query, with a window: no further back than ``window - 1``."""
+    mask = col < kv_len
+    if causal:
+        mask = mask & (col <= row)
+        if window is not None:
+            mask = mask & (col > row - window)
+    return mask
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                 sm_scale: float, block_k: int, kv_len: int, causal: bool,
-                q_block: int):
+                q_block: int, window):
     # q_ref: (1, block_q, d); k_ref/v_ref: (1, n, d); o_ref like q_ref;
     # lse_ref: (1, block_q, 8) — 8-lane padded, lane 0 meaningful.
     qi = pl.program_id(1)
     q = q_ref[0]  # native dtype (bf16 in production) -> MXU full rate
     n = k_ref.shape[1]
     nk = n // block_k
+    row = qi * q_block + jax.lax.broadcasted_iota(
+        jnp.int32, (q.shape[0], block_k), 0)
 
     def body(ki, carry):
         acc, m_prev, l_prev = carry
@@ -78,12 +110,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
             preferred_element_type=jnp.float32)
         col = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (q.shape[0], block_k), 1)
-        mask = col < kv_len
-        if causal:
-            row = qi * q_block + jax.lax.broadcasted_iota(
-                jnp.int32, (q.shape[0], block_k), 0)
-            mask = mask & (col <= row)
-        s = jnp.where(mask, s, NEG_INF)
+        # a row whose window starts after this block sees none of it and
+        # counts its keys as one each; the next block's alpha, exp(NEG_INF -
+        # m), wipes that out (the diagonal block always holds a visible key)
+        s = jnp.where(_visible(row, col, kv_len, causal, window), s, NEG_INF)
         m_cur = jnp.max(s, axis=1)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new[:, None])
@@ -98,8 +128,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     acc = jnp.zeros((bq, d), jnp.float32)
     m0 = jnp.full((bq,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, _causal_stop(qi, q_block, block_k, nk)
-                                  if causal else nk, body, (acc, m0, l0))
+    acc, m, l = jax.lax.fori_loop(
+        *_key_loop(qi, q_block, block_k, nk, causal, window), body,
+        (acc, m0, l0))
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
     lse = m + jnp.log(l_safe)
@@ -108,7 +139,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
                    sm_scale: float, block_k: int, kv_len: int, causal: bool,
-                   q_block: int):
+                   q_block: int, window):
     qi = pl.program_id(1)
     q = q_ref[0]
     do = do_ref[0]
@@ -116,6 +147,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     delta = delta_ref[0, :, 0]
     n = k_ref.shape[1]
     nk = n // block_k
+    row = qi * q_block + jax.lax.broadcasted_iota(
+        jnp.int32, (q.shape[0], block_k), 0)
 
     def body(ki, dq):
         k = k_ref[0, pl.ds(ki * block_k, block_k), :]
@@ -125,12 +158,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
             preferred_element_type=jnp.float32)
         col = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (q.shape[0], block_k), 1)
-        mask = col < kv_len
-        if causal:
-            row = qi * q_block + jax.lax.broadcasted_iota(
-                jnp.int32, (q.shape[0], block_k), 0)
-            mask = mask & (col <= row)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(_visible(row, col, kv_len, causal, window),
+                      jnp.exp(s - lse[:, None]), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None]) * sm_scale
@@ -139,15 +168,15 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
                                       preferred_element_type=jnp.float32)
         return dq
 
-    dq = jax.lax.fori_loop(0, _causal_stop(qi, q_block, block_k, nk)
-                           if causal else nk, body,
-                           jnp.zeros(q.shape, jnp.float32))
+    dq = jax.lax.fori_loop(
+        *_key_loop(qi, q_block, block_k, nk, causal, window), body,
+        jnp.zeros(q.shape, jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *, sm_scale: float, block_q: int,
-                    kv_len: int, causal: bool, k_block: int):
+                    kv_len: int, causal: bool, k_block: int, window):
     ki = pl.program_id(1)
     k = k_ref[0]
     v = v_ref[0]
@@ -165,12 +194,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = sm_scale * jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        mask = col < kv_len
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, k.shape[0]), 0)
-            mask = mask & (col <= row)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+        row = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, k.shape[0]), 0)
+        p = jnp.where(_visible(row, col, kv_len, causal, window),
+                      jnp.exp(s - lse[:, None]), 0.0)
         dv = dv + jax.lax.dot_general(p.astype(do.dtype), do,
                                       (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
@@ -185,9 +212,15 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk0 = jnp.zeros(k.shape, jnp.float32)
     dv0 = jnp.zeros(v.shape, jnp.float32)
     # causal: query blocks that end before this key block starts see none
-    # of it
-    dk, dv = jax.lax.fori_loop((ki * k_block) // block_q if causal else 0,
-                               nq, body, (dk0, dv0))
+    # of it; with a window, neither do those that start more than
+    # ``window - 1`` rows past its last key
+    first, stop = 0, nq
+    if causal:
+        first = (ki * k_block) // block_q
+        if window is not None:
+            stop = jnp.minimum(
+                nq, ((ki + 1) * k_block + window - 2) // block_q + 1)
+    dk, dv = jax.lax.fori_loop(first, stop, body, (dk0, dv0))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -197,26 +230,29 @@ def _flatten_bh(x):
     return x.reshape(b * h, n, d)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, sm_scale, kv_len, causal, block_q, block_k):
-    out, _ = _flash_fwd(q, k, v, sm_scale, kv_len, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, sm_scale, kv_len, causal, block_q, block_k, window):
+    out, _ = _flash_fwd(q, k, v, sm_scale, kv_len, causal, block_q, block_k,
+                        window)
     return out
 
 
-def _flash_fwd(q, k, v, sm_scale, kv_len, causal, block_q, block_k):
+def _flash_fwd(q, k, v, sm_scale, kv_len, causal, block_q, block_k,
+               window=None):
     b, h, n, d = q.shape
+    group = h // k.shape[1]          # query heads a key/value head
     qf, kf, vf = map(_flatten_bh, (q, k, v))
     grid = (b * h, n // block_q)
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
                                block_k=block_k, kv_len=kv_len, causal=causal,
-                               q_block=block_q)
+                               q_block=block_q, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, n, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, n, d), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, n, d), lambda bh, qi: (bh // group, 0, 0)),
+            pl.BlockSpec((1, n, d), lambda bh, qi: (bh // group, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
@@ -233,9 +269,10 @@ def _flash_fwd(q, k, v, sm_scale, kv_len, causal, block_q, block_k):
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(sm_scale, kv_len, causal, block_q, block_k, res, dout):
+def _flash_bwd(sm_scale, kv_len, causal, block_q, block_k, window, res, dout):
     q, k, v, out, lse = res
     b, h, n, d = q.shape
+    kv_heads = k.shape[1]
     qf, kf, vf = map(_flatten_bh, (q, k, v))
     dof = _flatten_bh(dout)
     of = _flatten_bh(out)
@@ -246,30 +283,41 @@ def _flash_bwd(sm_scale, kv_len, causal, block_q, block_k, res, dout):
     dqf, dkf, dvf = _bwd_calls(qf, kf, vf, dof, lse, delta,
                                sm_scale=sm_scale, kv_len=kv_len,
                                causal=causal, block_q=block_q,
-                               block_k=block_k)
-    unflat = lambda x: x.reshape(b, h, n, d)
-    return unflat(dqf), unflat(dkf), unflat(dvf)
+                               block_k=block_k, window=window)
+
+    def over_group(x, like):
+        # a key/value head's gradient is the sum over the query heads that
+        # read it, taken in float32
+        if kv_heads == h:
+            return x.reshape(b, h, n, d)
+        return jnp.sum(x.reshape(b, kv_heads, h // kv_heads, n, d),
+                       axis=2, dtype=jnp.float32).astype(like.dtype)
+    return dqf.reshape(b, h, n, d), over_group(dkf, k), over_group(dvf, v)
 
 
 def _bwd_calls(qf, kf, vf, dof, lse, delta, *, sm_scale, kv_len, causal,
-               block_q, block_k, out_dtype=None):
+               block_q, block_k, out_dtype=None, window=None):
     """The two backward pallas_calls over flattened (BH, N, D) operands
     with caller-supplied lse/delta (BH, N, 8). Shared by the plain VJP
     and by ring attention's chunk backward (which passes the GLOBAL
     logsumexp/delta so per-chunk gradients sum to the exact full-sequence
     gradient). ``out_dtype`` overrides the gradients' dtype (the ring
     accumulates per-chunk grads in f32, so bf16 round trips per ring
-    step would otherwise lose precision)."""
+    step would otherwise lose precision). ``kf``, ``vf`` may hold fewer
+    heads, (BH / g, N, D): ``dk``, ``dv`` still come back a query head each,
+    (BH, N, D), for the caller to sum over a group."""
     bh, n, d = qf.shape
+    group = bh // kf.shape[0]
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, block_k=block_k,
-                          kv_len=kv_len, causal=causal, q_block=block_q),
+                          kv_len=kv_len, causal=causal, q_block=block_q,
+                          window=window),
         grid=(bh, n // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, n, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, n, d), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, n, d), lambda bh, qi: (bh // group, 0, 0)),
+            pl.BlockSpec((1, n, d), lambda bh, qi: (bh // group, 0, 0)),
             pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, block_q, 8), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, block_q, 8), lambda bh, qi: (bh, qi, 0)),
@@ -283,12 +331,12 @@ def _bwd_calls(qf, kf, vf, dof, lse, delta, *, sm_scale, kv_len, causal,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
                           block_q=block_q, kv_len=kv_len, causal=causal,
-                          k_block=block_k),
+                          k_block=block_k, window=window),
         grid=(bh, n // block_k),
         in_specs=[
             pl.BlockSpec((1, n, d), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh // group, ki, 0)),
+            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh // group, ki, 0)),
             pl.BlockSpec((1, n, d), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, n, 8), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, n, 8), lambda bh, ki: (bh, 0, 0)),
@@ -314,18 +362,25 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     sm_scale: Optional[float] = None,
                     causal: bool = False,
+                    window: Optional[int] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K) -> jax.Array:
-    """Fused attention. q,k,v: (B, H, N, D) with any N — padded internally
-    to a block multiple; padded KEY positions are masked out and padded
-    QUERY rows are dropped on return. D should be 64/128 for best MXU use.
+    """Fused attention. q: (B, H, N, D), k, v: (B, H or H / g, N, D) with any
+    N — padded internally to a block multiple; padded KEY positions are
+    masked out and padded QUERY rows are dropped on return. D should be
+    64/128 for best MXU use. ``window`` needs ``causal``.
     """
     b, h, n, d = q.shape
     if sm_scale is None:
         sm_scale = d ** -0.5
+    if window is not None and not causal:
+        raise ValueError("a window is a causal layer's")
+    if h % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(f"{h} query heads over {k.shape[1]} key and "
+                         f"{v.shape[1]} value heads")
     block_q, block_k, _, (q, k, v) = _blocks_and_pad(n, block_q, block_k,
                                                      q, k, v)
-    out = _flash(q, k, v, sm_scale, n, causal, block_q, block_k)
+    out = _flash(q, k, v, sm_scale, n, causal, block_q, block_k, window)
     return out[:, :, :n, :]
 
 
@@ -416,14 +471,6 @@ def _blocks_and_pad(n, block_q, block_k, *arrays):
     return block_q, block_k, n_pad, arrays
 
 
-def flash_attention_bnhd(q: jax.Array, k: jax.Array, v: jax.Array,
-                         **kw) -> jax.Array:
-    """(B, N, H, D) layout convenience wrapper (the models' layout)."""
-    out = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                          v.transpose(0, 2, 1, 3), **kw)
-    return out.transpose(0, 2, 1, 3)
-
-
 # --------------------------------------------------------------------------
 # The causal attention core of a decoder: one path a shape, chosen by what
 # the code can see.
@@ -445,22 +492,30 @@ def select_path(tokens: int, head_width: int, *,
 
 
 def causal_attention_lax(q: jax.Array, k: jax.Array, v: jax.Array,
-                         sm_scale: float) -> jax.Array:
+                         sm_scale: float,
+                         window: Optional[int] = None) -> jax.Array:
     """The lax mathematics, softmax in float32: the CPU path and the
-    oracle. q, k, v: (B, H, N, D)."""
-    n = q.shape[2]
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+    oracle. q: (B, H, N, D); k, v: (B, H / g, N, D), query head ``j`` reading
+    key/value head ``j // g``; with ``window``, query ``i`` sees keys
+    ``i - window + 1 .. i``."""
+    b, h, n, d = q.shape
+    kv = k.shape[1]
+    s = jnp.einsum("bkgqd,bkcd->bkgqc", q.reshape(b, kv, h // kv, n, d), k,
                    preferred_element_type=jnp.float32) * sm_scale
-    s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, NEG_INF)
+    row, col = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
+    s = jnp.where(_visible(row, col, n, True, window), s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    return jnp.einsum("bkgqc,bkcd->bkgqd", p, v).reshape(b, h, n, d)
 
 
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                     sm_scale: float, path: str) -> jax.Array:
-    """softmax(q k^T * sm_scale, causal) v over (B, H, N, D) with equal q, k
-    and v widths, by ``path`` (``select_path``)."""
+                     sm_scale: float, path: str,
+                     window: Optional[int] = None) -> jax.Array:
+    """softmax(q k^T * sm_scale, causal, within ``window``) v over q (B, H,
+    N, D) and k, v (B, H / g, N, D) of one width, by ``path``
+    (``select_path``)."""
     if path == "fused":
         return flash_attention(q, k, v, sm_scale=sm_scale, causal=True,
-                               block_q=CAUSAL_BLOCK_Q, block_k=CAUSAL_BLOCK_K)
-    return causal_attention_lax(q, k, v, sm_scale)
+                               window=window, block_q=CAUSAL_BLOCK_Q,
+                               block_k=CAUSAL_BLOCK_K)
+    return causal_attention_lax(q, k, v, sm_scale, window)

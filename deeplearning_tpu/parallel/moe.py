@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -173,8 +173,18 @@ class MoEMlp(nn.Module):
 
 
 # --------------------------------------------------------------------------
-# One chip's share of a sigmoid-routed expert layer (DeepSeek-V3 / GLM-4.x
-# ``noaux_tc`` routing): dropless, told which experts it holds.
+# One chip's share of a sparse expert layer: dropless, told which experts it
+# holds and by which rule its router chooses (DeepSeek-V3 / GLM-4.x
+# ``noaux_tc`` sigmoid routing; softmax top-k, Mellum2's).
+
+def _pick(idx: jax.Array, scores: jax.Array) -> jax.Array:
+    """``scores[t, idx[t, j]]``, picked by comparison: the same values as
+    ``take_along_axis``, whose gather of scalars and scatter-add back cost
+    0.74 + 1.22 ms a layer on a v5e against 0.22 + 0.22 (PR 33)."""
+    return jnp.sum(jnp.where(
+        idx[..., None] == jnp.arange(scores.shape[-1]), scores[:, None], 0.0),
+        axis=-1)
+
 
 def sigmoid_route(scores: jax.Array, bias: jax.Array, top_k: int,
                   scale: float) -> Tuple[jax.Array, jax.Array]:
@@ -183,18 +193,50 @@ def sigmoid_route(scores: jax.Array, bias: jax.Array, top_k: int,
     scores alone, normalised over the chosen and scaled. (T, k) ids and
     float32 weights."""
     _, idx = jax.lax.top_k(scores + bias, top_k)
-    # the chosen experts' scores, picked by comparison: the same values as
-    # ``take_along_axis``, whose gather of scalars and scatter-add back cost
-    # 0.74 + 1.22 ms a layer on a v5e against 0.22 + 0.22 (PR 33)
-    chosen = jnp.sum(jnp.where(
-        idx[..., None] == jnp.arange(scores.shape[-1]), scores[:, None], 0.0),
-        axis=-1)
+    chosen = _pick(idx, scores)
     weights = scale * chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-20)
     return idx, weights
 
 
-# megablox tiles (rows, contraction, columns) of the grouped product
-_GMM_TILING = (512, 1024, 1024)
+def softmax_route(probs: jax.Array, top_k: int
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """``probs`` (T, E) are the softmax over all experts. The ``top_k``
+    largest are chosen; their weights are their probabilities divided by
+    their sum. No bias, no scale. (T, k) ids and float32 weights; the
+    gradient reaches the router through the softmax."""
+    _, idx = jax.lax.top_k(probs, top_k)
+    chosen = _pick(idx, probs)
+    return idx, chosen / jnp.sum(chosen, -1, keepdims=True)
+
+
+# what a rule asks of the layer: the activation that makes its scores of the
+# router's logits, and whether it takes a correction bias and a scale
+sigmoid_route.scores, sigmoid_route.biased = jax.nn.sigmoid, True
+softmax_route.scores, softmax_route.biased = jax.nn.softmax, False
+
+
+# megablox's row tile of the grouped product: the buffer is whole such tiles
+_GMM_ROWS = 512
+
+
+def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """megablox tiles (rows, contraction, columns) of an (m, k) x (k, n)
+    grouped product, from its shape (megablox asks this of every product it
+    makes: the forward one, and the two of its backward pass with their own
+    k and n). On a v5e at both decoders' shapes, forward + both gradients
+    (PERF.md §6, PR 34): 2,304 -> 1,792 at (512, 768, 896) 4.11 ms against
+    5.75 at the fixed (512, 1024, 1024) with its masked remainders, 896 ->
+    2,304 2.04 against 2.98, 1,536 -> 2,048 0.94 against 1.19, and 2,048 ->
+    3,072 keeps (512, 1024, 1024)."""
+    return _GMM_ROWS, _lane_tile(k), _lane_tile(n)
+
+
+def _lane_tile(x: int, most: int = 1024) -> int:
+    """The largest divisor of ``x`` that is whole 128-lane tiles and at most
+    ``most``; ``most`` itself (megablox masks the remainder) where there is
+    none."""
+    fits = [t for t in range(128, most + 1, 128) if x % t == 0]
+    return fits[-1] if fits else most
 
 
 def grouped_route(rows: int, initializing: bool = False) -> str:
@@ -202,7 +244,7 @@ def grouped_route(rows: int, initializing: bool = False) -> str:
     ``megablox`` (the Pallas kernel that ships with JAX) on a TPU where the
     rows fill whole tiles; ``ragged_dot`` on the CPU, for other row counts,
     while ``model.init`` runs the layer once, eagerly, and as the oracle."""
-    fits = rows % _GMM_TILING[0] == 0 and not initializing
+    fits = rows % _GMM_ROWS == 0 and not initializing
     return "megablox" if fits and jax.default_backend() != "cpu" \
         else "ragged_dot"
 
@@ -217,7 +259,7 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     with jax.named_scope("expert_matmul"):
         if route == "megablox":
             from jax.experimental.pallas.ops.tpu.megablox import ops as mblx
-            return mblx.gmm(lhs, rhs, group_sizes, lhs.dtype, _GMM_TILING)
+            return mblx.gmm(lhs, rhs, group_sizes, lhs.dtype, gmm_tiling)
         return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
 
@@ -227,7 +269,7 @@ def buffer_capacity(choices: int, held: int, num_experts: int) -> int:
     all of them: twice the rows it can expect, rounded up to the grouped
     product's row tile, and never more than ``choices``. The size of a pass,
     not a capacity: a batch that sends more goes through it more than once."""
-    tile = _GMM_TILING[0]
+    tile = _GMM_ROWS
     twice = -(-2 * choices * held // num_experts)
     return min(choices, -(-twice // tile) * tile)
 
@@ -395,12 +437,13 @@ _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 class HeldExpertsMlp(nn.Module):
-    """This chip's share of a sparse expert layer with sigmoid routing and a
-    shared expert. It routes every token over all ``num_experts`` (the
-    published count), computes experts ``first .. first + held - 1`` for the
-    rows whose chosen expert it holds, and adds the shared expert; what the
-    absent experts would have added is left out (the chips that hold them
-    add it in a deployment, through an exchange that one chip has not).
+    """This chip's share of a sparse expert layer. It routes every token over
+    all ``num_experts`` (the published count) by the rule ``route``
+    (``sigmoid_route``, ``softmax_route``), computes experts ``first .. first
+    + held - 1`` for the rows whose chosen expert it holds, and adds the
+    shared experts where the layer has any; what the absent experts would
+    have added is left out (the chips that hold them add it in a deployment,
+    through an exchange that one chip has not).
 
     Dropless: no capacity, every chosen held expert is computed under any
     routing. Rows are sorted by expert and the grouped products work on the
@@ -414,8 +457,9 @@ class HeldExpertsMlp(nn.Module):
     A layer that holds every expert has a buffer of every choice and no such
     decision.
     SwiGLU experts of width ``hidden``, no biases. ``correction_bias`` is the
-    ``noaux_tc`` buffer: it enters the choice, not the weights, and no
-    gradient reaches it."""
+    ``noaux_tc`` buffer of the sigmoid rule (a layer routed by softmax has no
+    such parameter): it enters the choice, not the weights, and no gradient
+    reaches it."""
     num_experts: int = 64
     held: int = 8
     first: int = 0
@@ -423,6 +467,7 @@ class HeldExpertsMlp(nn.Module):
     hidden: int = 1536
     shared_experts: int = 1
     routed_scale: float = 1.8
+    route: Callable = sigmoid_route
     dtype: Any = jnp.bfloat16
 
     @nn.compact
@@ -435,17 +480,19 @@ class HeldExpertsMlp(nn.Module):
         tokens = x.reshape(t, d)
         init = nn.initializers.normal(0.02)
         w_r = self.param("router_kernel", init, (d, e), jnp.float32)
-        bias = self.param("correction_bias", nn.initializers.zeros, (e,),
-                          jnp.float32)
+        rule = (k,)
+        if self.route.biased:
+            bias = self.param("correction_bias", nn.initializers.zeros, (e,),
+                              jnp.float32)
+            rule = (jax.lax.stop_gradient(bias), k, self.routed_scale)
         cap = buffer_capacity(t * k, held, e)
         with jax.named_scope("moe_dispatch"):
             # the choice is made in float32 as the published code makes it
             # (a float32 product on the MXU needs ``highest`` to be one)
-            scores = jax.nn.sigmoid(jnp.dot(
+            scores = self.route.scores(jnp.dot(
                 tokens.astype(jnp.float32), w_r,
                 precision=jax.lax.Precision.HIGHEST))
-            idx, weights = sigmoid_route(scores, jax.lax.stop_gradient(bias),
-                                         k, self.routed_scale)
+            idx, weights = self.route(scores, *rule)
             local = idx.reshape(-1) - self.first
             here = (local >= 0) & (local < held)
             key = jnp.where(here, local, held)        # absent rows sort last

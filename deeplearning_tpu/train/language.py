@@ -6,7 +6,8 @@ model reads tokens ``0 .. S-1``; the main head predicts token ``i + 1`` and,
 where the model has a multi-token-prediction module, that head predicts
 token ``i + 2``. Loss = CE(main) + ``mtp_weight`` x CE(mtp), each a mean
 over the positions that have a target (the MTP head's last position has
-none). The cross entropy is taken over blocks of ``block_rows`` positions so
+none); a decoder with one head (``mellum``) hands back one hidden state and
+its loss is CE(main) alone, with no ``loss_mtp`` among the metrics. The cross entropy is taken over blocks of ``block_rows`` positions so
 that the float32 logits of all positions (16,384 x 19,360 x 4 bytes a head
 in the benchmark's cell) never stand whole: a block's logits are recomputed
 in the backward pass.

@@ -102,6 +102,20 @@ def _causal_grad(q, k, v):
         *qkv, 256 ** -0.5, "fused").astype(jnp.float32)), (0, 1, 2))(q, k, v)
 
 
+def _gqa_grad(window):
+    """Mellum2's attention core (32 query heads reading 4 key/value heads of
+    128), fused, forward + backward; with a window, a sliding layer's."""
+    def grad(q, k, v):
+        return jax.grad(lambda *qkv: jnp.sum(flash.causal_attention(
+            *qkv, 128 ** -0.5, "fused", window).astype(jnp.float32)),
+            (0, 1, 2))(q, k, v)
+    return grad
+
+
+_GQA_QKV = [((1, 32, 8192, 128), jnp.bfloat16)] \
+    + [((1, 4, 8192, 128), jnp.bfloat16)] * 2
+
+
 def _grouped_grad(rows, experts, sizes):
     """The routed experts' grouped product by the Pallas route, forward +
     both gradients."""
@@ -164,8 +178,20 @@ CASES = {
     # (one sequence of the cell's four), blocks of 512
     "flash_causal_n4096_d256_grad": (
         _causal_grad, [((1, 20, 4096, 256), jnp.bfloat16)] * 3),
-    # its routed experts: 8 held, 2,048 -> gate and up of 1,536, half the
-    # cell's row buffer
+    # Mellum2's: 8,192 tokens (one sequence of the cell's two), a sliding
+    # layer's window of 1,024 and a full layer
+    "flash_sliding_gqa_n8192_d128_grad": (_gqa_grad(1024), _GQA_QKV),
+    "flash_full_gqa_n8192_d128_grad": (_gqa_grad(None), _GQA_QKV),
+    # its routed experts: 16 held, 2,304 -> gate and up of 896 (tiles of 768
+    # deep and 896 wide) and 896 -> 2,304, a quarter of the cell's row buffer
+    "grouped_megablox_16x2304x1792_grad": (
+        _grouped_grad, [((16384, 2304), jnp.bfloat16),
+                        ((16, 2304, 1792), jnp.bfloat16), ((16,), jnp.int32)]),
+    "grouped_megablox_16x896x2304_grad": (
+        _grouped_grad, [((16384, 896), jnp.bfloat16),
+                        ((16, 896, 2304), jnp.bfloat16), ((16,), jnp.int32)]),
+    # GLM-4.7-Flash's routed experts: 8 held, 2,048 -> gate and up of 1,536,
+    # half the cell's row buffer
     "grouped_megablox_8x2048x3072_grad": (
         _grouped_grad, [((8192, 2048), jnp.bfloat16),
                         ((8, 2048, 3072), jnp.bfloat16), ((8,), jnp.int32)]),

@@ -107,18 +107,6 @@ class TestFlashBackward:
                                        atol=5e-4, rtol=5e-4)
 
 
-class TestLayoutWrapper:
-    def test_bnhd_wrapper(self):
-        q, k, v = rand_qkv(n=64, d=32)
-        out1 = fa.flash_attention(q, k, v)
-        out2 = fa.flash_attention_bnhd(q.transpose(0, 2, 1, 3),
-                                       k.transpose(0, 2, 1, 3),
-                                       v.transpose(0, 2, 1, 3))
-        np.testing.assert_allclose(np.asarray(out1),
-                                   np.asarray(out2.transpose(0, 2, 1, 3)),
-                                   atol=1e-6)
-
-
 class TestChunkGrads:
     def test_single_chunk_equals_full_gradient(self):
         # flash_chunk_grads with the GLOBAL lse/delta over one chunk that
