@@ -1,12 +1,17 @@
 """The causal decoder's skeleton, shared by the language families
 (``glm_moe_lite.py``, ``mellum.py``): RMSNorm, the bias-free dense layer, the
 rotary embedding, ``CausalLM`` (embedding, a family's blocks under
-``nn.remat``, final norm, untied head, ``return_hidden``) and the registry
+``remat_block``, final norm, untied head, ``return_hidden``) and the registry
 factory. A family brings its config (a frozen dataclass of ``config.json``'s
 keys with the chip's share beside them), its attention module and its block,
 and tells the skeleton two things through the config: ``block(i, dtype,
 name)``, layer ``i``'s module, and ``mtp(dtype, name)``, its
 multi-token-prediction module or None.
+
+What a block keeps for its backward pass: its input, and the two residuals
+of the fused attention core that only the kernel can make, its output and
+the per-row logsumexp (``flash_attention.py`` names them ``ATTENTION_OUT``
+and ``ATTENTION_LSE``). Everything else in the block is computed again.
 """
 
 from __future__ import annotations
@@ -19,11 +24,39 @@ import jax
 import jax.numpy as jnp
 
 from ...core.registry import MODELS
+from ...ops.pallas.flash_attention import ATTENTION_LSE, ATTENTION_OUT
 
 
 def _dense(features: int, dtype, name: str) -> nn.Dense:
     return nn.Dense(features, use_bias=False, dtype=dtype, name=name,
                     kernel_init=nn.initializers.normal(0.02))
+
+
+# what a block's remat keeps: the fused attention core's two named residuals
+KEEP_ATTENTION_CORE = jax.checkpoint_policies.save_only_these_names(
+    ATTENTION_OUT, ATTENTION_LSE)
+
+
+def remat_block(block):
+    """A family's block, rematerialised in the backward pass but for the
+    attention core's output, O(N D) bytes a layer, and logsumexp, O(N): the
+    backward kernels read both, and making them again is the forward kernel
+    run a second time, O(N^2 D). Only the fused path
+    (``flash_attention.select_path``) names them; on the lax path nothing is
+    kept and the block is computed again whole."""
+    # flax's lift runs the class it is handed, so the mark that
+    # ``forward_kept`` looks for goes on a subclass made before the lift
+    marked = type(block.__name__, (block,), {"keeps_attention_core": True})
+    return nn.remat(marked, policy=KEEP_ATTENTION_CORE)
+
+
+def forward_kept(attn: nn.Module, path: str) -> bool:
+    """Whether the backward pass of a block's attention module ``attn`` finds
+    its core's output and logsumexp kept: the core took the fused ``path``
+    and the block is ``remat_block``'s. What the ``kernel`` flight events
+    report."""
+    return path == "fused" and getattr(attn.parent, "keeps_attention_core",
+                                       False)
 
 
 class RMSNorm(nn.Module):
@@ -68,7 +101,7 @@ class CausalLM(nn.Module):
     back the normed hidden states before the head, one a head, for a loss
     that never holds the logits whole (``train/language.py``). Every block
     is rematerialised in the backward pass (the config's ``block`` wraps it
-    in ``nn.remat``)."""
+    in ``remat_block``)."""
     cfg: Any
     dtype: Any = jnp.bfloat16
 

@@ -25,9 +25,11 @@ the vocabulary. The attention core picks its own path
 (``ops/pallas/flash_attention.py::select_path``): the fused causal kernels on
 a TPU at whole 128-row blocks and 128-lane head widths, the lax mathematics
 elsewhere. Each layer tallies a ``kernel`` flight event (``mla_attention``)
-with the path it took. The skeleton round the blocks (embedding, remat, final
-norm, head, the registry factory) is ``decoder.py``'s, shared with
-``mellum.py``.
+with the path it took and ``forward_kept``: whether the block's backward pass
+finds the core's output and logsumexp kept (``decoder.remat_block``: all else
+in a block is computed again). The skeleton round the blocks (embedding,
+remat, final norm, head, the registry factory) is ``decoder.py``'s, shared
+with ``mellum.py``.
 """
 
 from __future__ import annotations
@@ -43,7 +45,7 @@ from ...obs import flight
 from ...ops.pallas import flash_attention as fused
 from ...parallel.moe import HeldExpertsMlp, SwiGLU
 from . import decoder
-from .decoder import RMSNorm, _dense, _factory
+from .decoder import RMSNorm, _dense, _factory, forward_kept, remat_block
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,9 +116,10 @@ class MLA(nn.Module):
         path = fused.select_path(
             n, nope + rope, initializing=self.is_initializing()) \
             if nope + rope == dv else "lax"
-        flight.tally("kernel", ("mla_attention", path, n, h, dv),
+        kept = forward_kept(self, path)
+        flight.tally("kernel", ("mla_attention", path, kept, n, h, dv),
                      member="/".join(self.path), name="mla_attention",
-                     path=path, shape=[b, h, n, dv])
+                     path=path, shape=[b, h, n, dv], forward_kept=kept)
         with jax.named_scope("mla_core"):
             out = fused.causal_attention(q, k, v, (nope + rope) ** -0.5, path)
         out = out.transpose(0, 2, 1, 3).reshape(b, n, h * dv)
@@ -146,7 +149,7 @@ class DecoderBlock(nn.Module):
         return h + ffn(norm("ffn_norm")(h))
 
 
-_RematBlock = nn.remat(DecoderBlock)
+_RematBlock = remat_block(DecoderBlock)
 
 
 class MTP(nn.Module):

@@ -25,8 +25,11 @@ A chip holds its share of a stated deployment: ``experts_held`` of
 vocabulary. The attention core picks its own path
 (``ops/pallas/flash_attention.py::select_path``) and runs under the scope
 ``sliding_core`` or ``full_core``; each layer tallies a ``kernel`` flight
-event (``gqa_attention``) with the path it took and its window. The skeleton
-round the blocks is ``decoder.py``'s, shared with ``glm_moe_lite.py``.
+event (``gqa_attention``) with the path it took, its window and
+``forward_kept``: whether the block's backward pass finds the core's output
+and logsumexp kept (``decoder.remat_block``: all else in a block is computed
+again). The skeleton round the blocks is ``decoder.py``'s, shared with
+``glm_moe_lite.py``.
 """
 
 from __future__ import annotations
@@ -43,7 +46,8 @@ import numpy as np
 from ...obs import flight
 from ...ops.pallas import flash_attention as fused
 from ...parallel.moe import HeldExpertsMlp, softmax_route
-from .decoder import RMSNorm, _dense, _factory, rotary
+from .decoder import (RMSNorm, _dense, _factory, forward_kept, remat_block,
+                      rotary)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -138,9 +142,12 @@ class GQAttention(nn.Module):
         k = rotary(norm("k_norm")(heads("k", kv)), inv_freq, factor)
         v = heads("v", kv)
         path = fused.select_path(n, d, initializing=self.is_initializing())
-        flight.tally("kernel", ("gqa_attention", path, window, n, h, kv, d),
+        kept = forward_kept(self, path)
+        flight.tally("kernel",
+                     ("gqa_attention", path, kept, window, n, h, kv, d),
                      member="/".join(self.path), name="gqa_attention",
-                     path=path, window=window, shape=[b, h, kv, n, d])
+                     path=path, window=window, shape=[b, h, kv, n, d],
+                     forward_kept=kept)
         with jax.named_scope("sliding_core" if window else "full_core"):
             out = fused.causal_attention(q, k, v, d ** -0.5, path, window)
         out = out.transpose(0, 2, 1, 3).reshape(b, n, h * d)
@@ -166,7 +173,7 @@ class MellumBlock(nn.Module):
         return h + moe(norm("ffn_norm")(h))
 
 
-_RematBlock = nn.remat(MellumBlock)
+_RematBlock = remat_block(MellumBlock)
 
 
 # Mellum2-12B-A2.5B (``MellumConfig``'s defaults are its published config,

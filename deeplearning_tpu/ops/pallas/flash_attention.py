@@ -30,6 +30,13 @@ query head each and are summed over a group after it (PERF.md §6, PR 34, for
 the in-kernel sum that lost). N must be a multiple of the block size —
 wrappers pad and mask via ``kv_len`` (the number of valid key tokens).
 ``window`` (causal only) lets query ``i`` see keys ``i - window + 1 .. i``.
+
+What the backward pass keeps of the forward: ``(q, k, v, out, lse)``, the
+logsumexp as ``(BH, N)`` float32 (the kernels read and write it 8 lanes wide,
+lane 0 meaningful; the wide form is made round each call). ``out`` and
+``lse`` carry names (``ATTENTION_OUT``, ``ATTENTION_LSE``) that a
+``jax.checkpoint`` policy can save: a decoder block does (PR 35), so its
+rematerialised backward pass does not run the forward kernel again.
 """
 
 from __future__ import annotations
@@ -40,6 +47,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -52,6 +60,10 @@ NEG_INF = -1e30
 # logsumexp and delta) in VMEM, double-buffered: at 4,096 tokens of width 256
 # that is 16.5 MB, over the compiler's 16 MB default on a v5e (128 MB there).
 _VMEM_LIMIT = 96 * 2 ** 20
+# ``_flash_fwd``'s names for its output and logsumexp (module docstring);
+# outside a ``jax.checkpoint`` whose policy saves them they do nothing.
+ATTENTION_OUT = "attention_out"
+ATTENTION_LSE = "attention_lse"
 
 
 def _compiler_params():
@@ -265,7 +277,10 @@ def _flash_fwd(q, k, v, sm_scale, kv_len, causal, block_q, block_k,
         interpret=interpret_mode(),
         compiler_params=_compiler_params(),
     )(qf, kf, vf)
-    out = out.reshape(b, h, n, d)
+    out = checkpoint_name(out.reshape(b, h, n, d), ATTENTION_OUT)
+    # the residual is lane 0 alone, (BH, N): the 8 lanes are padded to 128
+    # wherever the array stands, as many bytes as ``out`` if it were kept
+    lse = checkpoint_name(lse[:, :, 0], ATTENTION_LSE)
     return out, (q, k, v, out, lse)
 
 
@@ -276,10 +291,12 @@ def _flash_bwd(sm_scale, kv_len, causal, block_q, block_k, window, res, dout):
     qf, kf, vf = map(_flatten_bh, (q, k, v))
     dof = _flatten_bh(dout)
     of = _flatten_bh(out)
-    # delta_i = rowsum(dO_i * O_i); stored (bh, n, 8) like lse
+    # delta_i = rowsum(dO_i * O_i); the kernels read it and the logsumexp
+    # as (bh, n, 8), lane 0 meaningful
     delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
                     axis=-1, keepdims=True)
     delta = jnp.broadcast_to(delta, (b * h, n, 8))
+    lse = jnp.broadcast_to(lse[:, :, None], (b * h, n, 8))
     dqf, dkf, dvf = _bwd_calls(qf, kf, vf, dof, lse, delta,
                                sm_scale=sm_scale, kv_len=kv_len,
                                causal=causal, block_q=block_q,
@@ -400,7 +417,7 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     block_q, block_k, n_pad, (q, k, v) = _blocks_and_pad(
         n, block_q, block_k, q, k, v)
     out, res = _flash_fwd(q, k, v, sm_scale, n, causal, block_q, block_k)
-    lse = res[4][:, :, 0].reshape(b, h, n + n_pad)
+    lse = res[4].reshape(b, h, n + n_pad)
     return out[:, :, :n, :], lse[:, :, :n]
 
 

@@ -17,11 +17,14 @@ import functools
 import os
 import re
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import pytest
 
+from deeplearning_tpu.core.registry import MODELS
 from deeplearning_tpu.models.classification import swin
+from deeplearning_tpu.models.language import glm_moe_lite, mellum
 from deeplearning_tpu.ops.pallas import flash_attention as flash
 from deeplearning_tpu.ops.pallas import global_attention as global_attn
 from deeplearning_tpu.ops.pallas import nms as pallas_nms
@@ -240,6 +243,57 @@ def test_compact_expert_layer_compiles_for_v5e(chip, monkeypatch):
             params, x).compile()
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') == 6 + 8
+
+
+# (registry entry of the cell, un-remat'd block, what its constructor takes
+# after the config, the cell's (rows, tokens), Mosaic calls of value and
+# gradient under a plain ``nn.remat``): GLM-4.7-Flash's dense block holds the
+# attention core's forward, forward again, ``dq`` and ``dkv``; Mellum2's
+# sliding block those four and 16 grouped products of its expert layer
+DECODER_BLOCKS = {
+    "glm47_flash_dense": ("glm47_flash_ep8", glm_moe_lite.DecoderBlock, True,
+                          (4, 4096), 4),
+    "mellum2_sliding": ("mellum2_ep4", mellum.MellumBlock, mellum.SLIDING,
+                        (2, 8192), 20),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DECODER_BLOCKS))
+def test_a_decoder_block_keeps_its_attention_core(name, chip, compiled_mode,
+                                                  monkeypatch):
+    """One block of each language family at its cell's shape, value and
+    gradient, compiled for the described v5e: under ``decoder.remat_block``
+    the program holds exactly one Mosaic call fewer than under a plain
+    ``nn.remat`` of the same block: the attention core's forward kernel is
+    not run a second time in the backward pass, and nothing else changes."""
+    entry, block_cls, kind, (rows, tokens), plain_calls = DECODER_BLOCKS[name]
+    monkeypatch.setattr(
+        moe, "grouped_route", lambda rows, initializing=False:
+        "ragged_dot" if initializing else "megablox")
+    cfg = MODELS.build(entry).cfg
+    x = jax.ShapeDtypeStruct((rows, tokens, cfg.hidden_size), jnp.bfloat16,
+                             sharding=chip)
+
+    def mosaic_calls(wrap):
+        block = wrap(block_cls)(cfg, kind, jnp.bfloat16)
+        shapes = jax.eval_shape(lambda: block.init(
+            jax.random.key(0),
+            jnp.zeros((1, 128, cfg.hidden_size), jnp.bfloat16)))["params"]
+        params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=chip), shapes)
+
+        def loss(p, x):
+            return jnp.mean(
+                block.apply({"params": p}, x).astype(jnp.float32) ** 2)
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+                params, x).compile()
+        return compiled.as_text().count(
+            'custom_call_target="tpu_custom_call"')
+
+    from deeplearning_tpu.models.language.decoder import remat_block
+    assert mosaic_calls(nn.remat) == plain_calls
+    assert mosaic_calls(remat_block) == plain_calls - 1
 
 
 # (token grid, C, heads) of Swin-T's four stages; a stage's second block is

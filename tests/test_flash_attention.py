@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deeplearning_tpu.analysis import jaxpr as audit
 from deeplearning_tpu.ops.pallas import flash_attention as fa
 
 
@@ -133,3 +134,54 @@ class TestChunkGrads:
                                    atol=1e-4, rtol=1e-4)
         np.testing.assert_allclose(np.asarray(dv), np.asarray(rv),
                                    atol=1e-4, rtol=1e-4)
+
+
+def _count_pallas_calls(traced) -> int:
+    """``pallas_call`` equations of a traced function, those inside its
+    equations' own jaxprs (``checkpoint``, ``custom_vjp_call``) included."""
+    return sum(e.primitive.name == "pallas_call"
+               for e in audit.iter_eqns(traced))
+
+
+class TestKeptAcrossRemat:
+    """Under a ``jax.checkpoint`` whose policy saves the two names
+    ``_flash_fwd`` places (the decoder skeleton's ``remat_block``), the
+    backward pass holds the two backward kernels and no second forward
+    kernel; the gradients are the plain checkpoint's bit for bit."""
+
+    # a call's keywords and its key/value heads under 3 query heads
+    CASES = {
+        "causal": (dict(causal=True), 3),
+        "windowed": (dict(causal=True, window=40), 3),
+        "grouped": (dict(causal=True, window=40), 1),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_forward_kernel_runs_once(self, case):
+        from deeplearning_tpu.models.language import decoder
+        kwargs, kv_heads = self.CASES[case]
+        q, k, v = rand_qkv(b=1, h=3, n=128, d=32, seed=3)
+        k, v = k[:, :kv_heads], v[:, :kv_heads]
+
+        def loss(q, k, v):
+            out = fa.flash_attention(q, k, v, block_q=64, block_k=64,
+                                     **kwargs)
+            return jnp.sum(jnp.square(out))
+
+        grads = {}
+        for name, fn, calls in (
+                ("kept", jax.checkpoint(
+                    loss, policy=decoder.KEEP_ATTENTION_CORE), 3),
+                ("plain", jax.checkpoint(loss), 4)):
+            grad = jax.grad(fn, argnums=(0, 1, 2))
+            assert _count_pallas_calls(
+                jax.make_jaxpr(grad)(q, k, v)) == calls, name
+            grads[name] = grad(q, k, v)
+        for kept, plain in zip(grads["kept"], grads["plain"]):
+            np.testing.assert_array_equal(np.asarray(kept),
+                                          np.asarray(plain))
+
+    def test_logsumexp_residual_is_compact(self):
+        q, k, v = rand_qkv(b=2, h=3, n=128, d=32)
+        _, res = fa._flash_fwd(q, k, v, 32 ** -0.5, 128, True, 64, 64)
+        assert res[4].shape == (6, 128) and res[4].dtype == jnp.float32

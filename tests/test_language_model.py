@@ -22,6 +22,7 @@ from benchmarks.flops import glm_moe_lite as flops           # noqa: E402
 from benchmarks.references import glm_moe_lite as ref        # noqa: E402
 from benchmarks.references import ops as ref_ops             # noqa: E402
 from benchmarks.references import train_ref, train_ref_lm    # noqa: E402
+from deeplearning_tpu.analysis import jaxpr as audit         # noqa: E402
 from deeplearning_tpu.core.registry import MODELS            # noqa: E402
 from deeplearning_tpu.models.language import glm_moe_lite as glm  # noqa: E402
 from deeplearning_tpu.obs import flight                      # noqa: E402
@@ -277,7 +278,6 @@ def test_no_full_size_rows_and_no_scatter():
     rows at the model's width stands in either direction, one pass or
     several (the token side sums top_k gathers of one row a token), and
     nothing scatters."""
-    from deeplearning_tpu.analysis import jaxpr as audit
     fn, p, x = _layer_value_and_grad(*_steered(40, 5), held=2)
     p = {k: v[:2] if k.startswith("experts_") else v for k, v in p.items()}
     traced = jax.make_jaxpr(fn)(p, x)
@@ -383,10 +383,48 @@ def test_two_steps_through_build_trainer_on_a_token_npz(tmp_path):
     kernels = {(e["name"], e["path"]) for e in recorder.events("kernel")
                if "name" in e}
     assert {("mla_attention", "lax"), ("expert_matmul", "ragged_dot")} <= kernels
+    # the CPU's lax path names nothing: every block is computed again whole
+    cores = [e for e in recorder.events("kernel")
+             if e.get("name") == "mla_attention"]
+    assert cores and not any(e["forward_kept"] for e in cores)
     feeds = recorder.events("feed")
     assert feeds and feeds[0]["route"] == "array_gather"
     assert feeds[0]["wire_dtype"] == {"tokens": "int32"}
     assert "loss_sum" in trainer.evaluate()
+
+
+@pytest.mark.parametrize("path", ["lax", "fused"])
+def test_a_block_under_the_policy_is_the_block(setup, monkeypatch, path):
+    """``decoder.remat_block``'s block against the un-remat'd one on the same
+    weights: the same loss and leaf gradients; on the fused path (kernels
+    interpreted) its gradient holds the un-remat'd block's three kernels and
+    not a fourth, and its flight tally reads ``forward_kept``."""
+    if path == "fused":
+        monkeypatch.setattr(
+            flash, "select_path", lambda tokens, width, initializing=False:
+            "lax" if initializing else "fused")
+    _, params, _ = setup
+    cfg = MODELS.build("glm_moe_lite_micro").cfg
+    x = jax.random.normal(jax.random.key(21), (2, 32, 64), jnp.float32)
+    recorder = flight.get_recorder()
+    results = {}
+    for name, cls in (("kept", glm._RematBlock), ("whole", glm.DecoderBlock)):
+        recorder.clear()
+        block = cls(cfg, False, jnp.float32)
+        grad = jax.value_and_grad(lambda p, x: jnp.sum(jnp.sin(
+            block.apply({"params": p}, x))), (0, 1))
+        calls = sum(e.primitive.name == "pallas_call" for e in
+                    audit.iter_eqns(jax.make_jaxpr(grad)(params["layers_1"], x)))
+        assert calls == (3 if path == "fused" else 0), (name, calls)
+        results[name] = grad(params["layers_1"], x)
+        tally, = [e for e in recorder.events("kernel")
+                  if e.get("name") == "mla_attention"]
+        assert tally["path"] == path
+        assert tally["forward_kept"] is (name == "kept" and path == "fused")
+    assert float(results["kept"][0]) == float(results["whole"][0])
+    for kept, whole in zip(*(jax.tree.leaves(results[n][1])
+                             for n in ("kept", "whole"))):
+        _close(kept, whole, 1e-6)
 
 
 def test_flops_functions_against_a_count_of_the_references_products(

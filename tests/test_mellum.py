@@ -22,6 +22,7 @@ from benchmarks.flops import mellum as flops                  # noqa: E402
 from benchmarks.references import mellum as ref               # noqa: E402
 from benchmarks.references import ops as ref_ops              # noqa: E402
 from benchmarks.references import train_ref, train_ref_lm     # noqa: E402
+from deeplearning_tpu.analysis import jaxpr as audit          # noqa: E402
 from deeplearning_tpu.core.registry import MODELS             # noqa: E402
 from deeplearning_tpu.models.language import mellum           # noqa: E402
 from deeplearning_tpu.obs import flight                       # noqa: E402
@@ -280,12 +281,51 @@ def test_two_steps_through_build_trainer_with_the_one_head_loss(tmp_path):
     by_window = {e["window"]: e for e in events if e["shape"][3] == 32}
     assert set(by_window) == {8, None}
     assert all(e["path"] == "lax" and e["shape"] == [8, 4, 2, 32, 16]
-               for e in by_window.values())
+               and e["forward_kept"] is False for e in by_window.values())
     assert {m.split("/")[0] for m in by_window[8]["members"]} == {
         "layers_0", "layers_1", "layers_2"}
     assert {m.split("/")[0] for m in by_window[None]["members"]} == {
         "layers_3"}
     assert "loss_sum" in trainer.evaluate()
+
+
+@pytest.mark.parametrize("path", ["lax", "fused"])
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_block_under_the_policy_is_the_block(setup, monkeypatch, kind, path):
+    """``decoder.remat_block``'s block against the un-remat'd one on the same
+    weights, a sliding and a full layer: the same loss and leaf gradients; on
+    the fused path (kernels interpreted) its gradient holds the un-remat'd
+    block's three kernels and not a fourth, and its flight tally reads
+    ``forward_kept``."""
+    if path == "fused":
+        monkeypatch.setattr(
+            flash, "select_path", lambda tokens, width, initializing=False:
+            "lax" if initializing else "fused")
+    _, params, _ = setup
+    cfg = MODELS.build("mellum_micro").cfg
+    p = params["layers_0" if kind == mellum.SLIDING else "layers_3"]
+    x = jax.random.normal(jax.random.key(21), (2, 32, 64), jnp.float32)
+    recorder = flight.get_recorder()
+    results = {}
+    for name, cls in (("kept", mellum._RematBlock),
+                      ("whole", mellum.MellumBlock)):
+        recorder.clear()
+        block = cls(cfg, kind, jnp.float32)
+        grad = jax.value_and_grad(lambda p, x: jnp.sum(jnp.sin(
+            block.apply({"params": p}, x))), (0, 1))
+        calls = sum(e.primitive.name == "pallas_call" for e in
+                    audit.iter_eqns(jax.make_jaxpr(grad)(p, x)))
+        assert calls == (3 if path == "fused" else 0), (name, calls)
+        results[name] = grad(p, x)
+        tally, = [e for e in recorder.events("kernel")
+                  if e.get("name") == "gqa_attention"]
+        assert tally["path"] == path
+        assert tally["window"] == (8 if kind == mellum.SLIDING else None)
+        assert tally["forward_kept"] is (name == "kept" and path == "fused")
+    assert float(results["kept"][0]) == float(results["whole"][0])
+    for kept, whole in zip(*(jax.tree.leaves(results[n][1])
+                             for n in ("kept", "whole"))):
+        _close(kept, whole, 1e-6)
 
 
 def test_flops_functions_against_a_count_of_the_references_products(
