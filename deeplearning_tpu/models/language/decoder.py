@@ -84,12 +84,13 @@ def rotary(x: jax.Array, inv_freq: jax.Array, factor: float = 1.0
     cos and sin both times ``factor`` (YaRN's attention factor); float32
     inside."""
     n, r = x.shape[-2], x.shape[-1]
-    ang = jnp.arange(n, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
-    x32 = x.astype(jnp.float32)
-    a, b = x32[..., : r // 2], x32[..., r // 2:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
+    with jax.named_scope("rotary"):     # train/steps.py::STEP_SCOPES
+        ang = jnp.arange(n, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+        cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
+        x32 = x.astype(jnp.float32)
+        a, b = x32[..., : r // 2], x32[..., r // 2:]
+        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                               axis=-1).astype(x.dtype)
 
 
 class CausalLM(nn.Module):

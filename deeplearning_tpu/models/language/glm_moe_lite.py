@@ -100,19 +100,30 @@ class MLA(nn.Module):
                              c.qk_rope_head_dim, c.v_head_dim)
         norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)
         c_q = norm("q_a_norm")(_dense(c.q_lora_rank, self.dtype, "q_a")(x))
+        # ``head_split`` (train/steps.py::STEP_SCOPES): the layout work at
+        # the core's boundary that is the model's own; opened round the ops,
+        # never round a module or the core
         q = _dense(h * (nope + rope), self.dtype, "q_b")(c_q)
-        q = q.reshape(b, n, h, nope + rope).transpose(0, 2, 1, 3)
+        with jax.named_scope("head_split"):
+            q = q.reshape(b, n, h, nope + rope).transpose(0, 2, 1, 3)
         kv = _dense(c.kv_lora_rank + rope, self.dtype, "kv_a")(x)
-        c_kv = norm("kv_a_norm")(kv[..., : c.kv_lora_rank])
-        k_rope = rotary(kv[..., c.kv_lora_rank:], c.rope_theta)   # (b, n, r)
+        with jax.named_scope("head_split"):
+            c_kv = kv[..., : c.kv_lora_rank]
+        c_kv = norm("kv_a_norm")(c_kv)
+        with jax.named_scope("head_split"):
+            k_rope = kv[..., c.kv_lora_rank:]
+        k_rope = rotary(k_rope, c.rope_theta)                     # (b, n, r)
         kv = _dense(h * (nope + dv), self.dtype, "kv_b")(c_kv)
-        kv = kv.reshape(b, n, h, nope + dv).transpose(0, 2, 1, 3)
-        q = jnp.concatenate(
-            [q[..., :nope], rotary(q[..., nope:], c.rope_theta)], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :nope],
-             jnp.broadcast_to(k_rope[:, None], (b, h, n, rope))], axis=-1)
-        v = kv[..., nope:]
+        with jax.named_scope("head_split"):
+            kv = kv.reshape(b, n, h, nope + dv).transpose(0, 2, 1, 3)
+            q_nope, q_rope = q[..., :nope], q[..., nope:]
+        q_rope = rotary(q_rope, c.rope_theta)
+        with jax.named_scope("head_split"):
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope[:, None], (b, h, n, rope))], axis=-1)
+            v = kv[..., nope:]
         path = fused.select_path(
             n, nope + rope, initializing=self.is_initializing()) \
             if nope + rope == dv else "lax"
@@ -122,7 +133,8 @@ class MLA(nn.Module):
                      path=path, shape=[b, h, n, dv], forward_kept=kept)
         with jax.named_scope("mla_core"):
             out = fused.causal_attention(q, k, v, (nope + rope) ** -0.5, path)
-        out = out.transpose(0, 2, 1, 3).reshape(b, n, h * dv)
+        with jax.named_scope("head_split"):
+            out = out.transpose(0, 2, 1, 3).reshape(b, n, h * dv)
         return _dense(c.hidden_size, self.dtype, "o")(out)
 
 

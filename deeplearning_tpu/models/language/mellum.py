@@ -134,9 +134,12 @@ class GQAttention(nn.Module):
         h, kv, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
         inv_freq, factor, window = self.rope()
 
+        # ``head_split`` (train/steps.py::STEP_SCOPES): the layout work at
+        # the core's boundary that is the model's own
         def heads(name, count):
-            y = _dense(count * d, self.dtype, name)(x).reshape(b, n, count, d)
-            return y.transpose(0, 2, 1, 3)
+            y = _dense(count * d, self.dtype, name)(x)
+            with jax.named_scope("head_split"):
+                return y.reshape(b, n, count, d).transpose(0, 2, 1, 3)
         norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)
         q = rotary(norm("q_norm")(heads("q", h)), inv_freq, factor)
         k = rotary(norm("k_norm")(heads("k", kv)), inv_freq, factor)
@@ -150,7 +153,8 @@ class GQAttention(nn.Module):
                      forward_kept=kept)
         with jax.named_scope("sliding_core" if window else "full_core"):
             out = fused.causal_attention(q, k, v, d ** -0.5, path, window)
-        out = out.transpose(0, 2, 1, 3).reshape(b, n, h * d)
+        with jax.named_scope("head_split"):
+            out = out.transpose(0, 2, 1, 3).reshape(b, n, h * d)
         return _dense(c.hidden_size, self.dtype, "o")(out)
 
 

@@ -39,10 +39,10 @@ fleet timeline.
 from . import flight, metrics, spans, threads, xla
 from .flight import FlightRecorder
 from .metrics import MetricsRegistry, MetricsServer
-from .spans import SpanTracer, span, step_span, traced
+from .spans import SpanTracer, span, step_span
 from .xla import HbmWatermark, hbm_snapshot, tracked_compile
 
 __all__ = ["spans", "xla", "flight", "metrics", "threads", "SpanTracer",
-           "span", "step_span", "traced", "FlightRecorder",
+           "span", "step_span", "FlightRecorder",
            "HbmWatermark", "hbm_snapshot", "tracked_compile",
            "MetricsRegistry", "MetricsServer"]

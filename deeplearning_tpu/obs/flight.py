@@ -72,6 +72,9 @@ class FlightRecorder:
         self.dumps = 0
         self.recorded = 0
         self._tallies: Dict[Any, Dict[str, Any]] = {}
+        # the run-level ``phase`` events (``obs.spans.phase``: a dozen a
+        # run) also go where a step event a step cannot evict them
+        self._phases: collections.deque = collections.deque(maxlen=capacity)
 
     # ------------------------------------------------------- recording
     def record(self, kind: str, **data: Any) -> None:
@@ -80,6 +83,8 @@ class FlightRecorder:
         with self._lock:
             self.recorded += 1
             self._ring.append(event)
+            if kind == "phase":
+                self._phases.append(event)
 
     def tally(self, kind: str, key: Any, member: Optional[str] = None,
               **data: Any) -> None:
@@ -102,7 +107,11 @@ class FlightRecorder:
                 event["members"].append(member)
 
     def events(self, kind: Optional[str] = None) -> List[Dict[str, Any]]:
+        """The ring's events, oldest first, or those of one ``kind``;
+        ``"phase"`` reads the phases kept apart, every one of the run."""
         with self._lock:
+            if kind == "phase":
+                return list(self._phases)
             ring = list(self._ring)
         return ring if kind is None else [e for e in ring
                                           if e["kind"] == kind]
@@ -111,6 +120,7 @@ class FlightRecorder:
         with self._lock:
             self._ring.clear()
             self._tallies.clear()
+            self._phases.clear()
             self.recorded = 0
 
     # --------------------------------------------------------- dumping

@@ -37,7 +37,6 @@ from __future__ import annotations
 
 import collections
 import contextlib
-import functools
 import json
 import os
 import threading
@@ -47,8 +46,7 @@ from typing import Any, Dict, Iterator, List, Optional
 from . import flight
 
 __all__ = ["SpanTracer", "enable", "disable", "get_tracer", "enabled",
-           "last", "span", "step_span", "traced", "phase", "record_phase",
-           "phases"]
+           "last", "span", "step_span", "phase", "record_phase", "phases"]
 
 # module-level pointer: the `is None` check is the entire disabled-path
 # cost, so spans stay near-free in un-instrumented processes
@@ -92,11 +90,6 @@ class SpanTracer:
             self._ring.append((name, th.ident, th.name,
                                self._abs_us(t_start), duration * 1e6,
                                args))
-
-    def record_instant(self, name: str,
-                       args: Optional[Dict[str, Any]] = None) -> None:
-        """A zero-duration marker (rendered as an instant event)."""
-        self.record(name, time.perf_counter(), 0.0, args)
 
     # -------------------------------------------------------- snapshot
     def events(self) -> List[Dict[str, Any]]:
@@ -249,8 +242,9 @@ def phase(name: str, **args: Any) -> Iterator[None]:
     ``perf_counter`` read at entry), where ``phases()`` finds it. Two
     clock reads, a lock and a dict: for the dozen phases of a run's set-up,
     never inside the step loop (that is what ``span`` is for). The flight
-    ring is bounded (256 events), so a long run with obs on (one ``step``
-    event per step) pushes the set-up's phases out of it."""
+    recorder keeps phase events apart from its ring of 256, so a long run
+    with obs on (one ``step`` event per step) does not push the set-up's
+    phases out before someone reads them."""
     t0 = time.perf_counter()
     try:
         yield
@@ -259,20 +253,6 @@ def phase(name: str, **args: Any) -> Iterator[None]:
 
 
 def phases() -> List[Dict[str, Any]]:
-    """The recorded phase events, oldest first."""
+    """The recorded phase events, oldest first (the recorder keeps them
+    where its ring's eviction by other kinds cannot reach)."""
     return flight.get_recorder().events("phase")
-
-
-def traced(name: Optional[str] = None):
-    """Decorator form: ``@traced("checkpoint")`` wraps calls in a span."""
-    def deco(fn):
-        span_name = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            if _TRACER is None:       # fast path: no span object at all
-                return fn(*args, **kwargs)
-            with span(span_name):
-                return fn(*args, **kwargs)
-        return wrapper
-    return deco

@@ -37,25 +37,26 @@ def make_loss_fn(label_smoothing: float = 0.0, has_batch_stats: bool = False,
             **kwargs)
         if has_batch_stats:
             aux["batch_stats"] = mutated["batch_stats"]
-        model_aux_losses = jax.tree.leaves(mutated.get("losses", {}))
-        aux_logits = ()
-        if isinstance(logits, tuple):
-            logits, aux_logits = logits
-        labels = batch["label"]
-        if labels.ndim == logits.ndim:          # mixup soft targets
-            loss = losses.soft_target_cross_entropy(logits, labels)
-            acc_labels = jnp.argmax(labels, -1)
-        else:
-            loss = losses.cross_entropy(logits, labels, label_smoothing)
-            acc_labels = labels
-        for a in aux_logits:
-            if a is not None and labels.ndim < logits.ndim + 1:
-                loss = loss + aux_weight * losses.cross_entropy(
-                    a, acc_labels, label_smoothing)
-        for al in model_aux_losses:
-            loss = loss + al
-        acc = jnp.mean((jnp.argmax(logits, -1) == acc_labels).astype(
-            jnp.float32))
+        with jax.named_scope("loss_head"):     # steps.py::STEP_SCOPES
+            model_aux_losses = jax.tree.leaves(mutated.get("losses", {}))
+            aux_logits = ()
+            if isinstance(logits, tuple):
+                logits, aux_logits = logits
+            labels = batch["label"]
+            if labels.ndim == logits.ndim:          # mixup soft targets
+                loss = losses.soft_target_cross_entropy(logits, labels)
+                acc_labels = jnp.argmax(labels, -1)
+            else:
+                loss = losses.cross_entropy(logits, labels, label_smoothing)
+                acc_labels = labels
+            for a in aux_logits:
+                if a is not None and labels.ndim < logits.ndim + 1:
+                    loss = loss + aux_weight * losses.cross_entropy(
+                        a, acc_labels, label_smoothing)
+            for al in model_aux_losses:
+                loss = loss + al
+            acc = jnp.mean((jnp.argmax(logits, -1) == acc_labels).astype(
+                jnp.float32))
         aux["metrics"] = {"accuracy": acc}
         # surface per-layer MoE routing health as step metrics (mean over
         # layers for drop/util, max over layers for load imbalance)

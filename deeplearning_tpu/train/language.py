@@ -68,12 +68,13 @@ def _head_sums(params: Any, state: TrainState, tokens: jax.Array,
     for ahead, h in enumerate(hidden, start=1):
         # the head `ahead` tokens on: position i has a target while
         # i + ahead <= S
-        targets = jnp.pad(tokens[:, ahead:], ((0, 0), (0, ahead - 1)))
-        weights = jnp.broadcast_to(
-            (jnp.arange(s) <= s - ahead).astype(jnp.float32), (b, s))
-        loss_sum, hits = blocked_cross_entropy(
-            h.reshape(b * s, -1), kernel, targets.reshape(-1),
-            weights.reshape(-1), block_rows)
+        with jax.named_scope("loss_head"):     # steps.py::STEP_SCOPES
+            targets = jnp.pad(tokens[:, ahead:], ((0, 0), (0, ahead - 1)))
+            weights = jnp.broadcast_to(
+                (jnp.arange(s) <= s - ahead).astype(jnp.float32), (b, s))
+            loss_sum, hits = blocked_cross_entropy(
+                h.reshape(b * s, -1), kernel, targets.reshape(-1),
+                weights.reshape(-1), block_rows)
         out.append((loss_sum, hits, float(b * (s - ahead + 1))))
     return out, mutated.get("moe_metrics", {})
 

@@ -46,16 +46,20 @@ class TrainState(flax.struct.PyTreeNode):
 
     def apply_gradients(self, grads: Any, new_batch_stats: Any = None
                         ) -> "TrainState":
-        updates, new_opt_state = self.tx.update(grads, self.opt_state,
-                                                self.params)
-        new_params = optax.apply_updates(self.params, updates)
+        # scopes of ``train/steps.py::STEP_SCOPES``
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = self.tx.update(grads, self.opt_state,
+                                                    self.params)
+            new_params = optax.apply_updates(self.params, updates)
         new_ema = self.ema_params
         if new_ema is not None:
             # YOLOX-style warmup-aware decay: d = decay*(1-exp(-step/2000))
             # (yolox/utils/ema.py:40) keeps early EMA close to raw params.
-            d = self.ema_decay * (1.0 - jnp.exp(-(self.step + 1) / 2000.0))
-            new_ema = jax.tree.map(lambda e, p: e * d + p.astype(e.dtype) * (1 - d),
-                                   new_ema, new_params)
+            with jax.named_scope("ema"):
+                d = self.ema_decay * (1.0 - jnp.exp(-(self.step + 1) / 2000.0))
+                new_ema = jax.tree.map(
+                    lambda e, p: e * d + p.astype(e.dtype) * (1 - d),
+                    new_ema, new_params)
         return self.replace(
             step=self.step + 1,
             params=new_params,

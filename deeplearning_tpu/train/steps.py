@@ -37,6 +37,15 @@ from .state import TrainState
 # aux: {'batch_stats': new_stats (optional), 'metrics': {...} (optional)}
 LossFn = Callable[[Any, TrainState, Any, jax.Array], Tuple[jax.Array, Dict]]
 
+# The step's own ``jax.named_scope``s: what a device trace calls the work
+# that no Flax module names. Each is opened where the work happens (the
+# loss_fns, here, ``TrainState.apply_gradients``, the decoders' attention)
+# and read by a per-layer metric of the benchmark (PERF.md, section 3); they
+# are metadata of the compiled step and cost nothing at run time. The
+# kernels' scopes (``mla_core``, ``moe_dispatch``, ...) are their layers'.
+STEP_SCOPES = ("loss_head", "grad_cast", "optimizer", "ema", "step_metrics",
+               "rotary", "head_split")
+
 
 def _microbatch(batch: Any, accum_steps: int, i: jax.Array) -> Any:
     def slice_leaf(x):
@@ -118,8 +127,9 @@ def make_train_step(
             # fp32 gradient policy: the scan path below accumulates in
             # fp32; hand optax the same dtype here so bf16-param runs see
             # identical optimizer numerics at accum_steps 1 and N.
-            grads = jax.tree.map(
-                lambda g: g.astype(jnp.float32), grads)
+            with jax.named_scope("grad_cast"):
+                grads = jax.tree.map(
+                    lambda g: g.astype(jnp.float32), grads)
         else:
             # batch_stats thread through the scan carry so every
             # microbatch's forward sees the stats advanced by the previous
@@ -192,14 +202,15 @@ def make_train_step(
                 ema_params=ema)
 
         metrics = {"loss": loss, **aux.get("metrics", {})}
-        metrics["grad_norm"] = jnp.sqrt(sum(
-            jnp.sum(jnp.square(g.astype(jnp.float32)))
-            for g in jax.tree.leaves(grads)))
-        # device-side divergence flag: the Trainer's deferred-metrics
-        # pipeline reads this from the stale snapshot instead of syncing
-        # the in-flight loss, so a non-finite step aborts training within
-        # the metrics lag with zero extra D2H round-trips
-        metrics["bad_step"] = (~jnp.isfinite(loss)).astype(jnp.int32)
+        with jax.named_scope("step_metrics"):
+            metrics["grad_norm"] = jnp.sqrt(sum(
+                jnp.sum(jnp.square(g.astype(jnp.float32)))
+                for g in jax.tree.leaves(grads)))
+            # device-side divergence flag: the Trainer's deferred-metrics
+            # pipeline reads this from the stale snapshot instead of syncing
+            # the in-flight loss, so a non-finite step aborts training
+            # within the metrics lag with zero extra D2H round-trips
+            metrics["bad_step"] = (~jnp.isfinite(loss)).astype(jnp.int32)
         return state, metrics
 
     donate_argnums: Tuple[int, ...] = ()

@@ -18,6 +18,7 @@ for p in (ROOT, os.path.join(ROOT, "tools")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+import _step_scopes                                          # noqa: E402
 from benchmarks.flops import glm_moe_lite as flops           # noqa: E402
 from benchmarks.references import glm_moe_lite as ref        # noqa: E402
 from benchmarks.references import ops as ref_ops             # noqa: E402
@@ -350,26 +351,17 @@ def test_grouped_route_by_backend_and_rows(monkeypatch):
     assert moe.buffer_capacity(65536, 64, 64) == 65536
 
 
-def test_two_steps_through_build_trainer_on_a_token_npz(tmp_path):
+@pytest.fixture(scope="module")
+def micro_run(tmp_path_factory):
+    return _step_scopes.two_steps("glm_moe_lite_micro",
+                                  tmp_path_factory.mktemp("glm_micro"))
+
+
+def test_two_steps_through_build_trainer_on_a_token_npz(micro_run):
     import train as train_cli
-    from deeplearning_tpu.core.config import config_cli
-    tokens = np.random.default_rng(0).integers(0, 512, (16, 33), np.int32)
-    np.savez(tmp_path / "data.npz", tokens=tokens)
-    recorder = flight.get_recorder()
-    recorder.clear()
     assert train_cli.model_task("glm_moe_lite_micro") == "language"
     assert train_cli.model_task("vit_micro_patch4_56") == "classification"
-    trainer = train_cli.build_trainer(config_cli(train_cli.Config(), [
-        "model.name=glm_moe_lite_micro", "model.num_classes=512",
-        f"data.npz={tmp_path / 'data.npz'}", "data.synthetic=false",
-        "data.global_batch=8", "data.val_rate=0", "optim.name=adamw",
-        "optim.lr=1e-3", "optim.clip_grad_norm=1.0", "train.epochs=1"]),
-        devices=jax.devices()[:1])
-    seen = []
-    trainer.callbacks.register(
-        "after_iter", lambda tr, metrics: seen.append(jax.device_get(metrics)))
-    trainer.train()
-    trainer.close_feed()
+    trainer, seen = micro_run.trainer, micro_run.seen
     assert len(seen) == 2 and int(trainer.state.step) == 2
     assert all(np.isfinite(m["loss"]) and 5.5 < m["loss_main"] < 7 for m in seen)
     # the expert layers' counters arrive as the step's metrics, by layer
@@ -380,17 +372,24 @@ def test_two_steps_through_build_trainer_on_a_token_npz(tmp_path):
         # 4 of 16 experts held: half the choices' rows are the buffer, and a
         # batch that sends more goes through it twice
         assert seen[0][f"moe/buffer_rows/{layer}"] in (8 * 32 * 2, 8 * 32 * 4)
-    kernels = {(e["name"], e["path"]) for e in recorder.events("kernel")
+    kernels = {(e["name"], e["path"]) for e in micro_run.kernels
                if "name" in e}
     assert {("mla_attention", "lax"), ("expert_matmul", "ragged_dot")} <= kernels
     # the CPU's lax path names nothing: every block is computed again whole
-    cores = [e for e in recorder.events("kernel")
-             if e.get("name") == "mla_attention"]
+    cores = [e for e in micro_run.kernels if e.get("name") == "mla_attention"]
     assert cores and not any(e["forward_kept"] for e in cores)
-    feeds = recorder.events("feed")
+    feeds = micro_run.feeds
     assert feeds and feeds[0]["route"] == "array_gather"
     assert feeds[0]["wire_dtype"] == {"tokens": "int32"}
     assert "loss_sum" in trainer.evaluate()
+
+
+def test_every_scope_of_the_vocabulary_names_ops_of_the_step(micro_run):
+    _step_scopes.check_decoder_vocabulary(micro_run.paths)
+
+
+def test_glue_stays_outside_the_cores_and_little_is_unplaced(micro_run):
+    _step_scopes.check_glue_and_unplaced(micro_run.paths, ("mla_core",))
 
 
 @pytest.mark.parametrize("path", ["lax", "fused"])

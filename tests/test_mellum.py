@@ -18,6 +18,7 @@ for p in (ROOT, os.path.join(ROOT, "tools")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+import _step_scopes                                           # noqa: E402
 from benchmarks.flops import mellum as flops                  # noqa: E402
 from benchmarks.references import mellum as ref               # noqa: E402
 from benchmarks.references import ops as ref_ops              # noqa: E402
@@ -247,26 +248,17 @@ def test_gmm_tiles_follow_the_products_shape():
     assert moe.grouped_route(65536) == "ragged_dot"          # this is a CPU
 
 
-def test_two_steps_through_build_trainer_with_the_one_head_loss(tmp_path):
+@pytest.fixture(scope="module")
+def micro_run(tmp_path_factory):
+    return _step_scopes.two_steps("mellum_micro",
+                                  tmp_path_factory.mktemp("mellum_micro"))
+
+
+def test_two_steps_through_build_trainer_with_the_one_head_loss(micro_run):
     import train as train_cli
-    from deeplearning_tpu.core.config import config_cli
-    tokens = np.random.default_rng(0).integers(0, 512, (16, 33), np.int32)
-    np.savez(tmp_path / "data.npz", tokens=tokens)
-    recorder = flight.get_recorder()
-    recorder.clear()
     assert train_cli.model_task("mellum_micro") == "language"
     assert train_cli.model_task("mellum2_ep4") == "language"
-    trainer = train_cli.build_trainer(config_cli(train_cli.Config(), [
-        "model.name=mellum_micro", "model.num_classes=512",
-        f"data.npz={tmp_path / 'data.npz'}", "data.synthetic=false",
-        "data.global_batch=8", "data.val_rate=0", "optim.name=adamw",
-        "optim.lr=1e-3", "optim.clip_grad_norm=1.0", "train.epochs=1"]),
-        devices=jax.devices()[:1])
-    seen = []
-    trainer.callbacks.register(
-        "after_iter", lambda tr, metrics: seen.append(jax.device_get(metrics)))
-    trainer.train()
-    trainer.close_feed()
+    trainer, seen = micro_run.trainer, micro_run.seen
     assert len(seen) == 2 and int(trainer.state.step) == 2
     assert all(np.isfinite(m["loss"]) and 5.5 < m["loss_main"] < 7
                and "loss_mtp" not in m and m["loss"] == m["loss_main"]
@@ -275,7 +267,7 @@ def test_two_steps_through_build_trainer_with_the_one_head_loss(tmp_path):
         assert seen[0][f"moe/rows_held/{layer}"] \
             + seen[0][f"moe/rows_absent/{layer}"] == 8 * 32 * 4
         assert seen[0][f"moe/buffer_rows/{layer}"] in (8 * 32 * 2, 8 * 32 * 4)
-    events = [e for e in recorder.events("kernel")
+    events = [e for e in micro_run.kernels
               if e.get("name") == "gqa_attention"]
     # one tally a kind, by window; the sliding one names three blocks
     by_window = {e["window"]: e for e in events if e["shape"][3] == 32}
@@ -287,6 +279,14 @@ def test_two_steps_through_build_trainer_with_the_one_head_loss(tmp_path):
     assert {m.split("/")[0] for m in by_window[None]["members"]} == {
         "layers_3"}
     assert "loss_sum" in trainer.evaluate()
+
+
+def test_every_scope_of_the_vocabulary_names_ops_of_the_step(micro_run):
+    _step_scopes.check_decoder_vocabulary(micro_run.paths)
+
+
+def test_glue_stays_outside_the_cores_and_little_is_unplaced(micro_run):
+    _step_scopes.check_glue_and_unplaced(micro_run.paths, ("sliding_core", "full_core"))
 
 
 @pytest.mark.parametrize("path", ["lax", "fused"])

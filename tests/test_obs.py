@@ -25,7 +25,7 @@ from deeplearning_tpu.data import ArraySource, DataLoader
 from deeplearning_tpu.obs import flight, spans
 from deeplearning_tpu.obs import xla as obs_xla
 from deeplearning_tpu.obs.flight import FlightRecorder
-from deeplearning_tpu.obs.spans import SpanTracer, span, step_span, traced
+from deeplearning_tpu.obs.spans import SpanTracer, span, step_span
 from deeplearning_tpu.train import (TrainState, make_eval_step,
                                     make_train_step)
 from deeplearning_tpu.train.classification import make_loss_fn, make_metric_fn
@@ -83,7 +83,7 @@ class TestSpanTracer:
         tracer = spans.enable()
         with span("dispatch"):
             pass
-        tracer.record_instant("marker", {"k": 1})
+        tracer.record("marker", time.perf_counter(), 0.0, {"k": 1})
         path = tracer.dump(str(tmp_path / "nested" / "trace.json"))
         with open(path) as f:
             doc = json.load(f)
@@ -102,27 +102,24 @@ class TestSpanTracer:
         assert tracer.dropped == 6
         assert len([e for e in tracer.events() if e["ph"] != "M"]) == 4
 
-    def test_step_span_and_traced_decorator(self):
+    def test_step_span_carries_the_step_beside_a_plain_span(self):
         tracer = spans.enable()
         with step_span("dispatch", 7):
             pass
-
-        @traced("my_phase")
-        def fn(x):
-            return x + 1
-
-        assert fn(1) == 2
+        with span("my_phase"):
+            pass
         names = [e["name"] for e in tracer.events() if e["ph"] == "X"]
         assert "dispatch" in names and "my_phase" in names
         disp = next(e for e in tracer.events()
                     if e["ph"] == "X" and e["name"] == "dispatch")
         assert disp["args"] == {"step": 7}
 
-    def test_decorator_fast_path_when_disabled(self):
-        @traced()
-        def fn():
-            return 42
-        assert fn() == 42                       # no tracer, plain call
+    def test_step_span_is_inert_when_disabled(self):
+        with step_span("dispatch", 3) as s:
+            s.args["late"] = True               # args may be added inside
+        assert spans.get_tracer() is None       # it switched nothing on
+        tracer = spans.enable()
+        assert [e for e in tracer.events() if e["ph"] == "X"] == []
 
 
 # ---------------------------------------------------------- run phases
@@ -150,6 +147,22 @@ class TestPhases:
             with spans.phase("setup/data"):
                 raise KeyError("no such file")
         assert [e["name"] for e in spans.phases()] == ["setup/data"]
+
+    def test_phases_outlive_the_flight_rings_eviction(self):
+        # a long run with obs on: one ``step`` event a step pushes the
+        # set-up's phases out of the ring of 256; ``phases()`` still has them
+        with spans.phase("setup/model_init"):
+            pass
+        spans.record_phase("setup/lower", time.perf_counter())
+        rec = flight.get_recorder()
+        for i in range(300):
+            flight.record("step", step=i)
+        assert len(rec.events()) == rec.capacity == 256
+        assert not [e for e in rec.events() if e["kind"] == "phase"]
+        assert [e["name"] for e in spans.phases()] == [
+            "setup/model_init", "setup/lower"]
+        rec.clear()
+        assert spans.phases() == []
 
     def test_record_phase_takes_an_earlier_start(self):
         t0 = time.perf_counter() - 1.5

@@ -127,3 +127,64 @@ class TestEndToEndSlice:
         e1 = jax.tree.leaves(new_state.ema_params)[0]
         assert not np.allclose(p0, p1)
         assert not np.allclose(e1, p1)
+
+
+class TestStepScopes:
+    """``train/steps.py::STEP_SCOPES`` on an image model's step (the
+    decoders' are in ``test_language_model.py`` / ``test_mellum.py``)."""
+
+    def test_vit_step_names_its_weight_update_and_the_names_are_metadata(
+            self, monkeypatch):
+        import contextlib
+
+        import _step_scopes
+        from deeplearning_tpu.train import steps
+
+        model = MODELS.build("vit_micro_patch4_56", num_classes=10)
+        params = model.init(jax.random.key(0), jnp.zeros((1, 56, 56, 3)),
+                            train=False)["params"]
+        # bfloat16 parameters, so that the gradients' cast has work to do
+        params = jax.tree.map(lambda p: p.astype(jnp.bfloat16), params)
+        tx = build_optimizer("adamw", build_schedule(
+            "warmup_cosine", base_lr=1e-3, total_steps=10, warmup_steps=2),
+            clip_grad_norm=1.0, weight_decay=0.05, params=params)
+        state = TrainState.create(apply_fn=model.apply, params=params, tx=tx,
+                                  use_ema=True)
+        batch = {"image": jnp.zeros((8, 56, 56, 3)),
+                 "label": jnp.zeros((8,), jnp.int32)}
+
+        def lowered():
+            step = make_train_step(make_loss_fn(0.1), donate=False)
+            return step.lower(state, batch, rng_mod.root_key(0))
+
+        with _step_scopes.fresh_compiles():
+            named = lowered()
+            # XLA drops a float32 -> bfloat16 -> float32 round trip, so the
+            # cast is in the program as traced and not in the compiled step
+            assert "/grad_cast/convert_element_type" in named.as_text(
+                debug_info=True)
+            named = named.compile().as_text()
+            scope = jax.named_scope
+            monkeypatch.setattr(
+                jax, "named_scope",
+                lambda name: contextlib.nullcontext()
+                if name in steps.STEP_SCOPES else scope(name))
+            bare = lowered().compile().as_text()
+        paths = _step_scopes.program_paths(named)
+        for name in ("loss_head", "optimizer", "ema", "step_metrics"):
+            forward, backward = _step_scopes.under(paths, name)
+            assert forward, name
+            # only the loss head is differentiated
+            assert bool(backward) == (name == "loss_head"), name
+        assert not any(_step_scopes.under(paths, s)[0]
+                       for s in ("rotary", "head_split"))
+        # what no name places: ``state.step + 1`` and the root module's own
+        # ops (class token, position embedding, and their transposes)
+        assert _step_scopes.unplaced_primitives(paths) <= {
+            "add", "broadcast_in_dim", "concatenate", "convert_element_type",
+            "slice", "pad", "reduce_sum", "split"}
+        without = _step_scopes.program_paths(bare)
+        assert not any(_step_scopes.under(without, s)[0]
+                       for s in steps.STEP_SCOPES)
+        assert _step_scopes.instruction_count(named) \
+            == _step_scopes.instruction_count(bare) > 100
