@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import flight
+from ..ops.pallas import choice_sum
 from .mesh import EXPERT_AXIS
 from .sharding import Rules
 from jax.sharding import PartitionSpec as P
@@ -284,12 +285,23 @@ def _sum_choices(src, slot, here, w):
     """Token side of the row buffer: ``sum_j w[t, j] * src[slot[t, j]]`` over
     the choices that are ``here``, in float32: (C, D) -> (T, D). A token has
     ``top_k`` choices wherever they lie, so this reads ``top_k`` x T rows;
-    the absent ones all read one row and are masked."""
+    the absent ones all read one row and are masked. The CPU's path and the
+    oracle of ``ops/pallas/choice_sum.py``, which reads the present ones
+    alone."""
     out = 0.0
     for j in range(slot.shape[1]):
         row = jnp.where(here[:, j, None], src[slot[:, j]], 0)
         out = out + row.astype(jnp.float32) * w[:, j, None]
     return out
+
+
+def _token_sum(path, src, slot, here, w):
+    """``_sum_choices`` by ``path``: ``fused``, the Pallas kernel that reads
+    the rows of the choices that are here and no other
+    (``ops/pallas/choice_sum.py``, its ``select_path``), or ``lax``."""
+    if path == "fused":
+        return choice_sum.choice_sum(src, slot, here, w)
+    return _sum_choices(src, slot, here, w)
 
 
 def _pass_index(cap, k, lo, order, inverse, sizes):
@@ -308,8 +320,9 @@ def _pass_index(cap, k, lo, order, inverse, sizes):
 
 # jitted so that a step's expert layers, and both branches of each, trace and
 # lower a pass once (un-jitted, lowering the cell's step took 44 % longer)
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _pass_fwd(route, cap, lo, tokens, w, gate_up, down, order, inverse, sizes):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _pass_fwd(route, sums, cap, lo, tokens, w, gate_up, down, order, inverse,
+              sizes):
     """What sorted rows ``lo .. lo + cap - 1`` add to the routed experts'
     output, (T, D) in float32, and what ``_pass_bwd`` reads beside. Every
     array on the rows side has ``cap`` rows."""
@@ -322,12 +335,12 @@ def _pass_fwd(route, cap, lo, tokens, w, gate_up, down, order, inverse, sizes):
     out_rows = grouped_matmul(act, down, in_pass, route)
     with jax.named_scope("moe_combine"):
         out_rows = jnp.where(present, out_rows, 0)
-        routed = _sum_choices(out_rows, slot, here, w)
+        routed = _token_sum(sums, out_rows, slot, here, w)
     return routed, (rows, both, act, out_rows)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _pass_bwd(route, cap, lo, args, saved, g):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _pass_bwd(route, sums, cap, lo, args, saved, g):
     """Cotangents of ``_pass_fwd``'s tokens, weights and expert kernels.
     Rows-side directions are ``cap``-row gathers from (T, D) arrays, the
     weights' cotangent a dot product a row sent back as a scalar; the
@@ -353,7 +366,8 @@ def _pass_bwd(route, cap, lo, args, saved, g):
         d_rows, d_gate_up = jax.vjp(product, rows, gate_up)[1](d_both)
     with jax.named_scope("moe_dispatch"):
         d_rows = jnp.where(present, d_rows, 0)
-        d_tokens = _sum_choices(d_rows, slot, here, here.astype(jnp.float32))
+        d_tokens = _token_sum(sums, d_rows, slot, here,
+                              here.astype(jnp.float32))
     return d_tokens, d_w, d_gate_up, d_down
 
 
@@ -362,8 +376,8 @@ def _passes(cap, sizes):
     return -(-jnp.sum(sizes) // cap)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _routed(route, cap, tokens, w, gate_up, down, order, inverse, sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _routed(route, sums, cap, tokens, w, gate_up, down, order, inverse, sizes):
     """The routed experts' part: (T, D) tokens, (T, k) float32 weights that
     are 0 where the choice is absent, rows sorted by expert through ``order``
     and ``inverse`` -> (T, D). The rows present go through a buffer of ``cap``
@@ -371,8 +385,8 @@ def _routed(route, cap, tokens, w, gate_up, down, order, inverse, sizes):
     in as many as it takes where they do not (summed in float32; the backward
     makes each pass's forward again). Chosen on the device from the row
     count of the batch at hand; every row is computed either way."""
-    return _routed_fwd(route, cap, tokens, w, gate_up, down, order, inverse,
-                       sizes)[0]
+    return _routed_fwd(route, sums, cap, tokens, w, gate_up, down, order,
+                       inverse, sizes)[0]
 
 
 def _one_pass_or_more(cap, order, sizes, one_pass, passes, *operands):
@@ -383,17 +397,18 @@ def _one_pass_or_more(cap, order, sizes, one_pass, passes, *operands):
     return jax.lax.cond(_passes(cap, sizes) <= 1, one_pass, passes, *operands)
 
 
-def _routed_fwd(route, cap, *args):
+def _routed_fwd(route, sums, cap, *args):
     tokens, _, _, _, order, _, sizes = args
 
     def one_pass(*args):
-        routed, saved = _pass_fwd(route, cap, jnp.int32(0), *args)
+        routed, saved = _pass_fwd(route, sums, cap, jnp.int32(0), *args)
         return routed.astype(tokens.dtype), saved
 
     def passes(*args):
         routed = jax.lax.fori_loop(
             0, _passes(cap, sizes),
-            lambda i, sum_: sum_ + _pass_fwd(route, cap, i * cap, *args)[0],
+            lambda i, sum_: sum_ + _pass_fwd(route, sums, cap, i * cap,
+                                             *args)[0],
             jnp.zeros(tokens.shape, jnp.float32))
         return routed.astype(tokens.dtype), jax.tree.map(
             lambda a: jnp.zeros(a.shape, a.dtype),
@@ -404,20 +419,21 @@ def _routed_fwd(route, cap, *args):
     return routed, (args, saved)
 
 
-def _routed_bwd(route, cap, res, g):
+def _routed_bwd(route, sums, cap, res, g):
     args, saved = res
     *primal, order, _, sizes = args
     like_primal = lambda grads: tuple(
         d.astype(a.dtype) for d, a in zip(grads, primal))
 
     def one_pass(args, saved, g):
-        return like_primal(_pass_bwd(route, cap, jnp.int32(0), args, saved, g))
+        return like_primal(_pass_bwd(route, sums, cap, jnp.int32(0), args,
+                                     saved, g))
 
     def passes(args, saved, g):
-        def add(i, sums):
-            saved = _pass_fwd(route, cap, i * cap, *args)[1]
-            grads = _pass_bwd(route, cap, i * cap, args, saved, g)
-            return tuple(a + d.astype(a.dtype) for a, d in zip(sums, grads))
+        def add(i, totals):
+            saved = _pass_fwd(route, sums, cap, i * cap, *args)[1]
+            grads = _pass_bwd(route, sums, cap, i * cap, args, saved, g)
+            return tuple(a + d.astype(a.dtype) for a, d in zip(totals, grads))
         # the tokens' cotangent is summed over the passes in float32 like
         # the output; the kernels' in their own dtype, as steps of gradient
         # accumulation are (float32 sums of them put 0.7 GB on the step's
@@ -516,11 +532,17 @@ class HeldExpertsMlp(nn.Module):
         up = self.param("experts_up", init, (held, d, f), jnp.float32)
         down = self.param("experts_down", init, (held, f, d), jnp.float32)
         route = grouped_route(cap, self.is_initializing())
+        sums = choice_sum.select_path(t, k, d, self.dtype,
+                                      initializing=self.is_initializing())
+        member = "/".join(self.path)
         flight.tally("kernel", ("expert_matmul", route, cap, d, f, held),
-                     member="/".join(self.path), name="expert_matmul",
+                     member=member, name="expert_matmul",
                      path=route, shape=[cap, d, f, held])
+        flight.tally("kernel", ("choice_sum", sums, t, k, d, cap),
+                     member=member, name="choice_sum", path=sums,
+                     shape=[t, k, d, cap])
         routed = _routed(
-            route, cap, tokens.astype(self.dtype), w,
+            route, sums, cap, tokens.astype(self.dtype), w,
             jnp.concatenate([gate, up], -1).astype(self.dtype),
             down.astype(self.dtype), order, inverse, sizes)
         y = routed.reshape(b, n, d)

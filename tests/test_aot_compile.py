@@ -25,6 +25,7 @@ import pytest
 from deeplearning_tpu.core.registry import MODELS
 from deeplearning_tpu.models.classification import swin
 from deeplearning_tpu.models.language import glm_moe_lite, mellum
+from deeplearning_tpu.ops.pallas import choice_sum
 from deeplearning_tpu.ops.pallas import flash_attention as flash
 from deeplearning_tpu.ops.pallas import global_attention as global_attn
 from deeplearning_tpu.ops.pallas import nms as pallas_nms
@@ -59,7 +60,7 @@ def chip():
 
 @pytest.fixture
 def compiled_mode(monkeypatch):
-    for module in (flash, global_attn, pallas_nms, window):
+    for module in (choice_sum, flash, global_attn, pallas_nms, window):
         monkeypatch.setattr(module, "interpret_mode", lambda: False)
 
 
@@ -198,6 +199,17 @@ CASES = {
     "grouped_megablox_8x2048x3072_grad": (
         _grouped_grad, [((8192, 2048), jnp.bfloat16),
                         ((8, 2048, 3072), jnp.bfloat16), ((8,), jnp.int32)]),
+    # the token side of both cells' row buffers (tokens x top_k slots, width,
+    # buffer rows): Mellum2's 16,384 x 8 over 65,536 rows of 2,304, GLM's
+    # 16,384 x 4 over 16,384 of 2,048; a packing call and the gather kernel
+    "choice_sum_16384x8_2304_65536": (
+        choice_sum.choice_sum, [((65536, 2304), jnp.bfloat16),
+                                ((16384, 8), jnp.int32), ((16384, 8), bool),
+                                ((16384, 8), jnp.float32)]),
+    "choice_sum_16384x4_2048_16384": (
+        choice_sum.choice_sum, [((16384, 2048), jnp.bfloat16),
+                                ((16384, 4), jnp.int32), ((16384, 4), bool),
+                                ((16384, 4), jnp.float32)]),
 }
 
 
@@ -245,16 +257,71 @@ def test_compact_expert_layer_compiles_for_v5e(chip, monkeypatch):
         'custom_call_target="tpu_custom_call"') == 6 + 8
 
 
+# the cells' expert layers: (constructor keywords, input (rows, tokens, width))
+EXPERT_LAYERS = {
+    "mellum2": (dict(num_experts=64, held=16, top_k=8, hidden=896,
+                     shared_experts=0, route=moe.softmax_route), (2, 8192, 2304)),
+    "glm47_flash": (dict(shared_experts=0), (4, 4096, 2048)),
+}
+_MOSAIC_CALL = re.compile(
+    r'custom_call_target="tpu_custom_call".*op_name="([^"]*)"')
+# a gather of the token side: the forward's under ``moe_combine``, the
+# backward's under ``moe_dispatch`` (the rows side's are the other way round)
+_TOKEN_GATHER = re.compile(
+    r'= \w+\[(\d+),(\d+)\].*op_name="[^"]*'
+    r'jit\(_pass_(?:fwd\)/moe_combine|bwd\)/moe_dispatch)/gather"')
+
+
+@pytest.mark.parametrize("name", sorted(EXPERT_LAYERS))
+def test_the_expert_layers_token_side_is_the_kernel(name, chip, compiled_mode,
+                                                    monkeypatch):
+    """Each cell's expert layer, value and gradient, compiled for the
+    described v5e with both kernels' routes a TPU takes: the token side is
+    a packing call and the gather kernel in each direction of each branch (8
+    Mosaic calls beside megablox's 14), each under ``moe_combine`` (forward)
+    or ``moe_dispatch`` (backward), where ``moe_dispatch_ms*`` counts it, and
+    no gather of a row a token, ``(T, D)``, stands on the token side (the
+    lax path has ``top_k`` of them a direction: 8 in Mellum2's, 4 in GLM's)."""
+    keywords, (rows, tokens, width) = EXPERT_LAYERS[name]
+    monkeypatch.setattr(
+        moe, "grouped_route", lambda rows, initializing=False:
+        "ragged_dot" if initializing else "megablox")
+    layer = moe.HeldExpertsMlp(**keywords)
+    x = jax.ShapeDtypeStruct((rows, tokens, width), jnp.bfloat16,
+                             sharding=chip)
+    shapes = jax.eval_shape(lambda: layer.init(
+        jax.random.key(0), jnp.zeros((1, 128, width), jnp.bfloat16)))["params"]
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=chip), shapes)
+
+    def loss(p, x):
+        return jnp.mean(layer.apply({"params": p}, x).astype(jnp.float32) ** 2)
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, x).compile().as_text()
+    calls = [m.group(1) for m in map(_MOSAIC_CALL.search, text.splitlines())
+             if m]
+    token_side = [c for c in calls if "/choice_sum" in c]
+    assert len(calls) == 14 + 8 and len(token_side) == 8, calls
+    assert all(re.search(r"/moe_(combine|dispatch)/", c) for c in token_side)
+    gathers = [m.groups() for m in map(_TOKEN_GATHER.search,
+                                       text.splitlines()) if m]
+    assert (str(rows * tokens), str(width)) not in gathers, gathers
+
+
 # (registry entry of the cell, un-remat'd block, what its constructor takes
 # after the config, the cell's (rows, tokens), Mosaic calls of value and
 # gradient under a plain ``nn.remat``): GLM-4.7-Flash's dense block holds the
 # attention core's forward, forward again, ``dq`` and ``dkv``; Mellum2's
-# sliding block those four and 16 grouped products of its expert layer
+# sliding block those four, 16 grouped products of its expert layer and 8
+# calls of its token side (a packing and a gather in each direction of each
+# branch)
 DECODER_BLOCKS = {
     "glm47_flash_dense": ("glm47_flash_ep8", glm_moe_lite.DecoderBlock, True,
                           (4, 4096), 4),
     "mellum2_sliding": ("mellum2_ep4", mellum.MellumBlock, mellum.SLIDING,
-                        (2, 8192), 20),
+                        (2, 8192), 28),
 }
 
 
