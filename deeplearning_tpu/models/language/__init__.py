@@ -1,1 +1,1 @@
-from . import glm_moe_lite, mellum  # noqa: F401
+from . import glm_moe_lite, granite, mellum  # noqa: F401

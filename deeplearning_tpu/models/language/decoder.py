@@ -1,12 +1,16 @@
 """The causal decoder's skeleton, shared by the language families
-(``glm_moe_lite.py``, ``mellum.py``): RMSNorm, the bias-free dense layer, the
-rotary embedding, ``CausalLM`` (embedding, a family's blocks under
-``remat_block``, final norm, untied head, ``return_hidden``) and the registry
-factory. A family brings its config (a frozen dataclass of ``config.json``'s
-keys with the chip's share beside them), its attention module and its block,
-and tells the skeleton two things through the config: ``block(i, dtype,
-name)``, layer ``i``'s module, and ``mtp(dtype, name)``, its
-multi-token-prediction module or None.
+(``glm_moe_lite.py``, ``mellum.py``, ``granite.py``): RMSNorm, the bias-free
+dense layer, the rotary embedding, ``CausalLM`` (embedding, a family's blocks
+under ``remat_block``, final norm, the head, ``return_hidden``) and the
+registry factory. A family brings its config (a frozen dataclass of
+``config.json``'s keys with the chip's share beside them), its attention
+module and its block, and tells the skeleton two things through the config:
+``block(i, dtype, name)``, layer ``i``'s module, and ``mtp(dtype, name)``,
+its multi-token-prediction module or None. Three keys of the config are read
+where it has them, and mean nothing where it has not: ``tie_word_embeddings``
+(the head is the embedding, transposed: no ``head`` parameter),
+``embedding_multiplier`` (on the embedding's rows as they are looked up) and
+``logits_scaling`` (the logits are divided by it).
 
 What a block keeps for its backward pass: its input, and the two residuals
 of the fused attention core that only the kernel can make, its output and
@@ -17,7 +21,7 @@ and ``ATTENTION_LSE``). Everything else in the block is computed again.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -93,6 +97,24 @@ def rotary(x: jax.Array, inv_freq: jax.Array, factor: float = 1.0
                                axis=-1).astype(x.dtype)
 
 
+class _Embed(nn.Embed):
+    """``nn.Embed`` whose rows come out times ``multiplier``: the op is the
+    embedding's own (its module places it on a device trace)."""
+    multiplier: float = 1.0
+
+    def __call__(self, inputs):
+        rows = super().__call__(inputs)
+        return rows if self.multiplier == 1.0 else rows * self.multiplier
+
+
+def head_form(cfg) -> Tuple[bool, float]:
+    """(whether the head is the embedding, transposed; the divisor of the
+    logits), as the config says: an untied head and 1 where it says
+    nothing."""
+    return (getattr(cfg, "tie_word_embeddings", False),
+            getattr(cfg, "logits_scaling", 1.0))
+
+
 class CausalLM(nn.Module):
     """tokens (B, S) int -> float32 logits (B, S, V) of the next token.
 
@@ -102,19 +124,35 @@ class CausalLM(nn.Module):
     back the normed hidden states before the head, one a head, for a loss
     that never holds the logits whole (``train/language.py``). Every block
     is rematerialised in the backward pass (the config's ``block`` wraps it
-    in ``remat_block``)."""
+    in ``remat_block``). ``head`` (``apply(..., method="head")``) gives a
+    loss what the logits are made of."""
     cfg: Any
     dtype: Any = jnp.bfloat16
+
+    def head(self) -> Tuple[jax.Array, float]:
+        """(the head's kernel (D, V), float32: the embedding transposed where
+        the head is tied, the divisor of the logits)."""
+        p = self.variables["params"]
+        tied, scaling = head_form(self.cfg)
+        return (p["embed"]["embedding"].T if tied
+                else p["head"]["kernel"]), scaling
 
     @nn.compact
     def __call__(self, tokens, train: bool = False,
                  next_tokens: Optional[jax.Array] = None,
                  return_hidden: bool = False):
         c = self.cfg
-        embed = nn.Embed(c.vocab_size, c.hidden_size, dtype=self.dtype,
-                         embedding_init=nn.initializers.normal(0.02),
-                         name="embed")
-        head = _dense(c.vocab_size, self.dtype, "head")
+        embed = _Embed(c.vocab_size, c.hidden_size, dtype=self.dtype,
+                       embedding_init=nn.initializers.normal(0.02),
+                       multiplier=getattr(c, "embedding_multiplier", 1.0),
+                       name="embed")
+        tied, scaling = head_form(c)
+        dense = None if tied else _dense(c.vocab_size, self.dtype, "head")
+
+        def head(h):
+            logits = (embed.attend(h) if tied else dense(h)).astype(
+                jnp.float32)
+            return logits if scaling == 1.0 else logits / scaling
         x = embed(tokens)
         for i in range(c.num_hidden_layers):
             x = c.block(i, self.dtype, name=f"layers_{i}")(x)
@@ -125,7 +163,7 @@ class CausalLM(nn.Module):
         if mtp is not None and next_tokens is not None:
             hidden.append(mtp(x, embed(next_tokens)))
         if not return_hidden or self.is_initializing():
-            logits = [head(h).astype(jnp.float32) for h in hidden]
+            logits = [head(h) for h in hidden]
             if not return_hidden:
                 return logits[0] if len(logits) == 1 else tuple(logits)
         return tuple(hidden)

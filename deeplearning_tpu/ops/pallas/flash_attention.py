@@ -11,7 +11,9 @@ row skipped: GLM-4.7-Flash's latent attention at 4,096 tokens of head width
 256, the benchmark's ``glm47_flash_ep8`` cell, PR 32; and Mellum2's grouped
 heads at 8,192 tokens of width 128, 8 query heads reading one key/value
 head, in sliding layers the key blocks before a query block's window
-skipped too, the ``mellum2_ep4`` cell, PR 34). And what
+skipped too, the ``mellum2_ep4`` cell; and Granite 4.0-H's heads
+of width 64, half a lane tile, 4 query heads reading one key/value head,
+the ``granite4_h_micro_vocab8`` cell). And what
 sequence parallelism calls, which stays until ROADMAP W8's long-sequence
 row decides it on the chip: ``flash_attention_with_lse`` and
 ``flash_chunk_grads`` are the ring's ``use_flash`` chunks
@@ -500,10 +502,12 @@ def select_path(tokens: int, head_width: int, *,
                 initializing: bool = False) -> str:
     """``"fused"`` where the kernels compile (not the CPU backend, where they
     would run interpreted) and the shape fills their tiles (whole 128-row
-    blocks, a head width of whole 128-lane tiles) with enough rows that the
-    ``T x T`` scores are worth keeping off HBM; ``"lax"`` everywhere else, and
-    while ``model.init`` runs the layer once, eagerly."""
-    covered = tokens % 128 == 0 and tokens >= 512 and head_width % 128 == 0
+    blocks, a head width of whole 128-lane tiles or of 64, half of one, in
+    a block as wide as the head) with enough rows that the ``T x T`` scores
+    are worth keeping off HBM; ``"lax"`` everywhere else, and while
+    ``model.init`` runs the layer once, eagerly."""
+    covered = tokens % 128 == 0 and tokens >= 512 and (
+        head_width % 128 == 0 or head_width == 64)
     return ("fused" if covered and not (initializing or interpret_mode())
             else "lax")
 

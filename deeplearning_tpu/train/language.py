@@ -10,7 +10,9 @@ none); a decoder with one head (``mellum``) hands back one hidden state and
 its loss is CE(main) alone, with no ``loss_mtp`` among the metrics. The cross entropy is taken over blocks of ``block_rows`` positions so
 that the float32 logits of all positions (16,384 x 19,360 x 4 bytes a head
 in the benchmark's cell) never stand whole: a block's logits are recomputed
-in the backward pass.
+in the backward pass. The head's kernel and the divisor of its logits are
+the model's to say (``decoder.CausalLM.head``): a tied head is the embedding,
+transposed.
 """
 
 from __future__ import annotations
@@ -27,11 +29,13 @@ _COUNTERS = ("rows_held", "rows_absent", "buffer_rows", "load_max_over_mean")
 
 def blocked_cross_entropy(hidden: jax.Array, kernel: jax.Array,
                           targets: jax.Array, weights: jax.Array,
-                          block_rows: int = 2048) -> Tuple[jax.Array, jax.Array]:
+                          block_rows: int = 2048, logits_scaling: float = 1.0
+                          ) -> Tuple[jax.Array, jax.Array]:
     """Sum over positions of ``weights`` x cross entropy of ``hidden @
-    kernel`` against ``targets``, and of ``weights`` x (argmax == target).
-    hidden (N, D) in the compute dtype, kernel (D, V); the logits are float32
-    (the product accumulates in float32) and live a block at a time."""
+    kernel / logits_scaling`` against ``targets``, and of ``weights`` x
+    (argmax == target). hidden (N, D) in the compute dtype, kernel (D, V);
+    the logits are float32 (the product accumulates in float32) and live a
+    block at a time."""
     n, d = hidden.shape
     rows = block_rows if n % block_rows == 0 else n
     kernel = kernel.astype(hidden.dtype)
@@ -40,6 +44,8 @@ def blocked_cross_entropy(hidden: jax.Array, kernel: jax.Array,
     def block(carry, xs):
         h, t, w = xs
         logits = jnp.dot(h, kernel, preferred_element_type=jnp.float32)
+        if logits_scaling != 1.0:
+            logits = logits / logits_scaling
         lse = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
         hit = (jnp.argmax(logits, axis=-1) == t).astype(jnp.float32)
@@ -54,6 +60,12 @@ def blocked_cross_entropy(hidden: jax.Array, kernel: jax.Array,
     return loss_sum, hits
 
 
+def _head(params: Any, state: TrainState) -> Tuple[jax.Array, float]:
+    """(the head's kernel (D, V), the divisor of its logits), from the model
+    (``decoder.CausalLM.head``): its own ``head`` or, tied, the embedding."""
+    return state.apply_fn(state.variables(params), method="head")
+
+
 def _head_sums(params: Any, state: TrainState, tokens: jax.Array,
                block_rows: int):
     """((loss_sum, hits, positions) per head, the sown ``moe_metrics``) of a
@@ -63,7 +75,8 @@ def _head_sums(params: Any, state: TrainState, tokens: jax.Array,
         state.variables(params), tokens[:, :-1], train=True,
         next_tokens=tokens[:, 1:], return_hidden=True,
         mutable=["moe_metrics"])
-    kernel = params["head"]["kernel"]
+    with jax.named_scope("loss_head"):         # steps.py::STEP_SCOPES
+        kernel, scaling = _head(params, state)
     out = []
     for ahead, h in enumerate(hidden, start=1):
         # the head `ahead` tokens on: position i has a target while
@@ -74,7 +87,7 @@ def _head_sums(params: Any, state: TrainState, tokens: jax.Array,
                 (jnp.arange(s) <= s - ahead).astype(jnp.float32), (b, s))
             loss_sum, hits = blocked_cross_entropy(
                 h.reshape(b * s, -1), kernel, targets.reshape(-1),
-                weights.reshape(-1), block_rows)
+                weights.reshape(-1), block_rows, scaling)
         out.append((loss_sum, hits, float(b * (s - ahead + 1))))
     return out, mutated.get("moe_metrics", {})
 
@@ -112,10 +125,10 @@ def make_metric_fn(block_rows: int = 2048):
         b, s = tokens.shape[0], tokens.shape[1] - 1
         hidden = state.apply_fn(state.variables(params), tokens[:, :-1],
                                 train=False, return_hidden=True)[0]
+        kernel, scaling = _head(params, state)
         loss_sum, hits = blocked_cross_entropy(
-            hidden.reshape(b * s, -1), params["head"]["kernel"],
-            tokens[:, 1:].reshape(-1), jnp.ones((b * s,), jnp.float32),
-            block_rows)
+            hidden.reshape(b * s, -1), kernel, tokens[:, 1:].reshape(-1),
+            jnp.ones((b * s,), jnp.float32), block_rows, scaling)
         return {"loss_sum": loss_sum, "top1": hits,
                 "count": jnp.asarray(b * s, jnp.float32)}
     return metric_fn

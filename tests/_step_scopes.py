@@ -97,22 +97,30 @@ def two_steps(model_name: str, tmp) -> types.SimpleNamespace:
         paths=program_paths(trainer.compiled_step_text()))
 
 
-def check_decoder_vocabulary(paths: list) -> None:
+def check_decoder_vocabulary(paths: list, rotary: bool = True) -> None:
     """``STEP_SCOPES`` on a decoder's step as ``build_trainer`` compiles it:
     the loss head and the attention's glue forward and backward, the weight
     update forward only; float32 parameters leave ``grad_cast`` nothing to
     do and the decoders train without a moving average
-    (``tests/test_train_slice.py`` has both)."""
+    (``tests/test_train_slice.py`` has both). ``rotary`` false: a decoder
+    with no positional embedding has no ``rotary``, and the CPU's compiler
+    folds the bare head split of its first forward pass into the core's
+    products, so ``head_split`` is asked for in one direction."""
     from deeplearning_tpu.train.steps import STEP_SCOPES
     found = {s: under(paths, s) for s in STEP_SCOPES}
-    for scope in ("loss_head", "rotary", "head_split"):
+    for scope in ("loss_head",) + ("rotary", "head_split") * rotary:
         assert found[scope][0] and found[scope][1], scope
+    if not rotary:
+        assert found["rotary"] == ([], [])
+        assert found["head_split"][0] or found["head_split"][1]
     for scope in ("optimizer", "step_metrics"):
         assert found[scope][0] and not found[scope][1], scope
     assert found["grad_cast"] == found["ema"] == ([], [])
     # the blocked loss's scan, both directions, is the loss head's (one
-    # block at this size, so XLA drops the loop and keeps its body's ops)
-    body = [p for p in paths if "/while/body/" in p and "/moe/" not in p]
+    # block at this size, so XLA drops the loop and keeps its body's ops);
+    # the expert layers' and the state-space scan's loops are their own
+    body = [p for p in paths if "/while/body/" in p and "/moe/" not in p
+            and "/ssd_scan/" not in p]
     assert body and all(re.search(r"\(loss_head\)+/while/body/", p)
                         for p in body)
     assert {"transpose(" in p for p in body} == {False, True}

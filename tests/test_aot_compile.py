@@ -24,7 +24,7 @@ import pytest
 
 from deeplearning_tpu.core.registry import MODELS
 from deeplearning_tpu.models.classification import swin
-from deeplearning_tpu.models.language import glm_moe_lite, mellum
+from deeplearning_tpu.models.language import glm_moe_lite, granite, mellum
 from deeplearning_tpu.ops.pallas import choice_sum
 from deeplearning_tpu.ops.pallas import flash_attention as flash
 from deeplearning_tpu.ops.pallas import global_attention as global_attn
@@ -316,12 +316,14 @@ def test_the_expert_layers_token_side_is_the_kernel(name, chip, compiled_mode,
 # attention core's forward, forward again, ``dq`` and ``dkv``; Mellum2's
 # sliding block those four, 16 grouped products of its expert layer and 8
 # calls of its token side (a packing and a gather in each direction of each
-# branch)
+# branch); Granite 4.0-H's attention block, heads of 64, the core's four
 DECODER_BLOCKS = {
     "glm47_flash_dense": ("glm47_flash_ep8", glm_moe_lite.DecoderBlock, True,
                           (4, 4096), 4),
     "mellum2_sliding": ("mellum2_ep4", mellum.MellumBlock, mellum.SLIDING,
                         (2, 8192), 28),
+    "granite4_attention": ("granite4_h_micro_vocab8", granite.GraniteBlock,
+                           granite.ATTENTION, (1, 8192), 4),
 }
 
 

@@ -185,3 +185,44 @@ class TestKeptAcrossRemat:
         q, k, v = rand_qkv(b=2, h=3, n=128, d=32)
         _, res = fa._flash_fwd(q, k, v, 32 ** -0.5, 128, True, 64, 64)
         assert res[4].shape == (6, 128) and res[4].dtype == jnp.float32
+
+
+class TestCausalCoreAtHeadWidth64:
+    """The decoder's causal kernels at a head width of 64, half a lane tile
+    (Granite 4.0-H's NoPE attention: 32 query heads over 8 key/value heads,
+    scores times 0.015625), interpreted, against ``causal_attention_lax``:
+    forward, ``dq``, ``dk`` and ``dv``, blocks smaller than the sequence so
+    that the causal key loop's end matters. float32 throughout, so the kernels
+    differ from the oracle by the order of their sums alone: 1e-5 of the
+    largest output, 1e-4 of the largest gradient."""
+
+    @pytest.mark.parametrize("heads", [(4, 4), (8, 2)], ids=["h4kv4", "h8kv2"])
+    def test_kernels_match_the_lax_oracle(self, monkeypatch, heads):
+        monkeypatch.setattr(fa, "CAUSAL_BLOCK_Q", 32)
+        monkeypatch.setattr(fa, "CAUSAL_BLOCK_K", 16)
+        h, kv = heads
+        q = rand_qkv(b=1, h=h, n=128, d=64, seed=5)[0]
+        k, v = (x[:, :kv] for x in rand_qkv(b=1, h=h, n=128, d=64, seed=6)[1:])
+
+        def loss(path):
+            return lambda q, k, v: jnp.sum(jnp.sin(fa.causal_attention(
+                q, k, v, 0.015625, path)))
+        out = fa.causal_attention(q, k, v, 0.015625, "fused")
+        want = fa.causal_attention(q, k, v, 0.015625, "lax")
+        assert out.shape == (1, h, 128, 64)
+        assert float(jnp.abs(out - want).max()) <= 1e-5 * float(
+            jnp.abs(want).max())
+        for a, b in zip(jax.grad(loss("fused"), (0, 1, 2))(q, k, v),
+                        jax.grad(loss("lax"), (0, 1, 2))(q, k, v)):
+            assert a.shape == b.shape
+            assert float(jnp.abs(a - b).max()) <= 1e-4 * float(
+                jnp.abs(b).max())
+
+    def test_the_path_takes_half_tile_heads(self, monkeypatch):
+        monkeypatch.setattr(fa, "interpret_mode", lambda: False)
+        assert fa.select_path(8192, 64) == "fused"
+        assert fa.select_path(8192, 32) == "lax"
+        assert fa.select_path(8192, 96) == fa.select_path(8192, 192) == "lax"
+        # the accepted cells' widths keep their path
+        assert fa.select_path(4096, 256) == fa.select_path(8192, 128) \
+            == "fused"
