@@ -31,9 +31,11 @@ A chip holds its share of a stated deployment: ``vocab_size`` rows of the
 tied embedding and head. The scan runs under the scope ``ssd_scan`` (from
 ``Δ`` and ``A`` to ``y`` before the skip), the convolution and its silu under
 ``short_conv``, the skip, the gate and the norm under ``gated_norm``; each
-Mamba layer tallies a ``kernel`` flight event ``ssd_scan`` (path ``lax``,
-shape ``[B, N, H, P, S, chunk]``). The attention core picks its own path
-(``ops/pallas/flash_attention.py::select_path``) and runs under
+Mamba layer tallies a ``kernel`` flight event ``ssd_scan`` (path ``fused``
+or ``lax``, shape ``[B, N, H, P, S, chunk]``): the scan takes the Pallas
+kernels of ``ops/pallas/ssd_scan.py`` where its ``select_path`` says so and
+``ops/ssd.py``'s ``jax.numpy`` form elsewhere. The attention core picks its
+own path (``ops/pallas/flash_attention.py::select_path``) and runs under
 ``full_core``, tallying ``gqa_attention`` as Mellum2's does. The skeleton
 round the blocks is ``decoder.py``'s.
 """
@@ -51,6 +53,7 @@ import jax.numpy as jnp
 from ...obs import flight
 from ...ops import ssd
 from ...ops.pallas import flash_attention as fused
+from ...ops.pallas import ssd_scan as fused_scan
 from .decoder import RMSNorm, _dense, _factory, forward_kept, remat_block
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -142,12 +145,16 @@ class MambaMixer(nn.Module):
         x, b, cc = jnp.split(xbc, [inner, inner + g * s], axis=-1)
         x = x.reshape(bsz, n, h, p)
         delta = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-        flight.tally("kernel", ("ssd_scan", "lax", n, h, p, s, g,
+        path = fused_scan.select_path(n, h, p, s, g, c.mamba_chunk_size,
+                                      x.dtype,
+                                      initializing=self.is_initializing())
+        flight.tally("kernel", ("ssd_scan", path, n, h, p, s, g,
                                 c.mamba_chunk_size),
-                     member="/".join(self.path), name="ssd_scan", path="lax",
+                     member="/".join(self.path), name="ssd_scan", path=path,
                      shape=[bsz, n, h, p, s, c.mamba_chunk_size])
-        y = ssd.ssd_scan(x, delta, -jnp.exp(a_log), b.reshape(bsz, n, g, s),
-                         cc.reshape(bsz, n, g, s), c.mamba_chunk_size)
+        scan = fused_scan.ssd_scan if path == "fused" else ssd.ssd_scan
+        y = scan(x, delta, -jnp.exp(a_log), b.reshape(bsz, n, g, s),
+                 cc.reshape(bsz, n, g, s), c.mamba_chunk_size)
         with jax.named_scope("gated_norm"):
             y = y.astype(jnp.float32) + x.astype(jnp.float32) * skip[:, None]
             y = ssd.gated_rms_norm(y.reshape(bsz, n, inner), z, norm_scale,

@@ -29,6 +29,7 @@ from deeplearning_tpu.ops.pallas import choice_sum
 from deeplearning_tpu.ops.pallas import flash_attention as flash
 from deeplearning_tpu.ops.pallas import global_attention as global_attn
 from deeplearning_tpu.ops.pallas import nms as pallas_nms
+from deeplearning_tpu.ops.pallas import ssd_scan
 from deeplearning_tpu.ops.pallas import window_attention as window
 from deeplearning_tpu.parallel import moe
 
@@ -60,7 +61,8 @@ def chip():
 
 @pytest.fixture
 def compiled_mode(monkeypatch):
-    for module in (choice_sum, flash, global_attn, pallas_nms, window):
+    for module in (choice_sum, flash, global_attn, pallas_nms, ssd_scan,
+                   window):
         monkeypatch.setattr(module, "interpret_mode", lambda: False)
 
 
@@ -125,6 +127,23 @@ def _grouped_grad(rows, experts, sizes):
     both gradients."""
     return jax.grad(lambda x, w: jnp.sum(moe.grouped_matmul(
         x, w, sizes, "megablox").astype(jnp.float32)), (0, 1))(rows, experts)
+
+
+def _scan(x, dt, a, b, c):
+    """Granite 4.0-H's chunked scan (chunk 256), the forward kernel."""
+    return ssd_scan.ssd_scan(x, dt, a, b, c, 256)
+
+
+def _scan_grad(*args):
+    """The same, forward + the backward kernel: the gradients of x, Δ, A, B
+    and C."""
+    return jax.grad(lambda *t: jnp.sum(_scan(*t).astype(jnp.float32)),
+                    range(5))(*args)
+
+
+# one row of 8,192 tokens, 64 heads of 64, one group, a state of 128
+_SCAN = [((1, 8192, 64, 64), jnp.bfloat16), ((1, 8192, 64), jnp.float32),
+         ((64,), jnp.float32)] + [((1, 8192, 1, 128), jnp.bfloat16)] * 2
 
 
 _NMS = functools.partial(pallas_nms.nms_pallas, iou_threshold=0.5,
@@ -210,6 +229,10 @@ CASES = {
         choice_sum.choice_sum, [((16384, 2048), jnp.bfloat16),
                                 ((16384, 4), jnp.int32), ((16384, 4), bool),
                                 ((16384, 4), jnp.float32)]),
+    # Granite 4.0-H Micro's Mamba-2 scan at the cell's row, value and
+    # gradient
+    "ssd_scan_granite4_n8192": (_scan, _SCAN),
+    "ssd_scan_granite4_n8192_grad": (_scan_grad, _SCAN),
 }
 
 
@@ -401,3 +424,39 @@ def test_no_layout_copy_at_the_window_kernels(stage, chip, compiled_mode):
               if re.search(r"= \S+ copy\(", line)
               and _KERNEL_BOUNDARY.search(line)]
     assert not copies, copies
+
+
+# a float32 array whose last two dimensions are a chunk's (256, 256): the lax
+# path's decays and their gradients
+_CHUNK_SQUARE = re.compile(r"f32\[[\d,]*256,256\]")
+
+
+def test_a_granite_mamba_block_scans_in_the_kernels(chip, compiled_mode):
+    """A Granite 4.0-H Mamba block at the cell's row (8,192 tokens of
+    2,048), value and gradient, compiled for the described v5e: the scan is
+    two Mosaic calls, the forward and the backward kernel, both under the
+    scope ``ssd_scan`` that ``ssd_scan_roofline`` reads, and no float32
+    (..., 256, 256) array of a chunk's decays stands in the program (the lax
+    path's holds dozens)."""
+    cfg = MODELS.build("granite4_h_micro_vocab8").cfg
+    block = granite.GraniteBlock(cfg, granite.MAMBA, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), jnp.bfloat16,
+                             sharding=chip)
+    shapes = jax.eval_shape(lambda: block.init(
+        jax.random.key(0),
+        jnp.zeros((1, 256, cfg.hidden_size), jnp.bfloat16)))["params"]
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=chip), shapes)
+
+    def loss(p, x):
+        return jnp.mean(block.apply({"params": p}, x).astype(jnp.float32) ** 2)
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            params, x).compile().as_text()
+    calls = [m.group(1) for m in map(_MOSAIC_CALL.search, text.splitlines())
+             if m]
+    assert [c.rsplit("/", 2)[-2] for c in calls] == [
+        "ssd_scan_fwd", "ssd_scan_bwd"], calls
+    assert all("/ssd_scan/" in c for c in calls), calls
+    assert not _CHUNK_SQUARE.search(text)
