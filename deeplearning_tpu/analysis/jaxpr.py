@@ -173,12 +173,19 @@ def collective_bytes(fn: Callable, *args,
 # SPMD partitioning, AFTER tracing — a jitted step's jaxpr shows none of
 # them, so proving "zero1 lowers the gradient all-reduce to
 # reduce-scatter + all-gather" requires reading the post-optimization
-# HLO. One platform wart is handled here: XLA's ReduceScatterCreator
-# combiner runs on TPU/GPU only, so on CPU the reduce-scatter appears as
-# all-reduce followed by a partition dynamic-slice (full result, then
-# each replica keeps its 1/n). ``hlo_collectives`` reclassifies that
-# pair as ``reduce_scatter`` — it IS the reduce-scatter this program
-# lowers to on TPU — which keeps the audit meaningful on the CPU CI box.
+# HLO. Three spellings of this XLA are handled here:
+# - XLA's ReduceScatterCreator runs on TPU/GPU only, so on CPU the
+#   reduce-scatter appears as all-reduce followed by a partition
+#   dynamic-slice (full result, then each replica keeps its 1/n).
+#   ``hlo_collectives`` reclassifies that pair as ``reduce_scatter`` —
+#   it IS the reduce-scatter this program lowers to on TPU — which keeps
+#   the audit meaningful on the CPU CI box.
+# - The all-reduce combiner merges a step's gradient all-reduces into
+#   one op whose result is a tuple, one element per merged all-reduce;
+#   each element counts as the all-reduce it was.
+# - Operands are printed by name alone (``dynamic-slice(%param_0.3,
+#   ...)``), and the slice usually sits inside a fusion: an operand's
+#   shape is looked up where the name is defined.
 # ---------------------------------------------------------------------------
 
 _HLO_DTYPE_BYTES = {
@@ -186,11 +193,20 @@ _HLO_DTYPE_BYTES = {
     "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8,
 }
 
+# one instruction that is a collective or a dynamic-slice: its result (an
+# array, or a tuple of arrays: a combined collective, an async pair's
+# state; a TPU layout's tiling nests one level of parentheses), op, and
+# first operand (typed in older texts, a bare name now)
 _HLO_OP_RE = re.compile(
-    r"=\s*(?P<dt>[a-z]+\d*)\[(?P<shape>[\d,]*)\](?:\{[^}]*\})?\s*"
+    r"=\s*(?P<type>\((?:[^()]|\([^()]*\))*\)"
+    r"|[a-z]+\d*\[[\d,]*\](?:\{[^}]*\})?)\s*"
     r"(?P<op>all-reduce|reduce-scatter|all-gather|all-to-all|"
     r"collective-permute|dynamic-slice)(?P<suffix>-start|-done)?"
-    r"\(\s*(?:(?P<odt>[a-z]+\d*)\[(?P<oshape>[\d,]*)\])?")
+    r"\((?:\s*(?:[a-z]+\d*\[(?P<oshape>[\d,]*)\](?:\{[^}]*\})?\s*)?"
+    r"%?(?P<oname>[\w.-]+))?")
+_HLO_ARRAY_RE = re.compile(r"(?P<dt>[a-z]+\d*)\[(?P<shape>[\d,]*)\]")
+# where a name is defined as an array
+_HLO_DEF_RE = re.compile(r"%([\w.-]+)\s*=\s*[a-z]+\d*\[([\d,]*)\]")
 
 
 def _shape_elems(shape_str: str) -> int:
@@ -217,38 +233,45 @@ def hlo_collectives(obj: Any, *args, reclassify_scatter: bool = True,
     """Collectives in compiled HLO: ``{op: {count, bytes, max_bytes}}``
     with underscore op names (``all_reduce``, ``reduce_scatter``, ...);
     bytes are the op's OUTPUT buffer (sum / max over occurrences).
-    Async ``-start``/``-done`` pairs count once. With
-    ``reclassify_scatter`` (default), an all-reduce whose full result
-    feeds a dynamic-slice producing exactly 1/num_partitions of it is
-    reported as ``reduce_scatter`` (see module comment: XLA:CPU lacks
-    the reduce-scatter combiner pass)."""
+    Async ``-start``/``-done`` pairs count once, at the ``-done``, whose
+    result is the collective's; a combined collective counts each
+    element of its tuple result. With ``reclassify_scatter`` (default),
+    an all-reduce whose full result feeds a dynamic-slice producing
+    exactly 1/num_partitions of it is reported as ``reduce_scatter``
+    (see module comment: XLA:CPU lacks the reduce-scatter combiner
+    pass)."""
     text = _hlo_module_text(obj, *args, **kwargs)
     m = re.search(r"num_partitions=(\d+)", text)
     n_part = int(m.group(1)) if m else 1
 
     colls: List[Tuple[str, str, str]] = []   # (op, dtype, shape)
-    slices: List[Tuple[str, str]] = []       # (out_shape, operand_shape)
+    # (out_shape, operand_shape or None, operand name)
+    slices: List[Tuple[str, Optional[str], str]] = []
     for mo in _HLO_OP_RE.finditer(text):
-        if mo.group("suffix") == "-done":
-            continue
-        op = mo.group("op")
+        op, arrays = mo.group("op"), _HLO_ARRAY_RE.findall(mo.group("type"))
         if op == "dynamic-slice":
-            if mo.group("oshape") is not None:
-                slices.append((mo.group("shape"), mo.group("oshape")))
-            continue
-        colls.append((op, mo.group("dt"), mo.group("shape")))
+            slices.append((arrays[0][1], mo.group("oshape"),
+                           mo.group("oname")))
+        elif mo.group("suffix") != "-start":
+            colls.extend((op, dt, shape) for dt, shape in arrays)
 
-    def is_scattered(shape: str) -> bool:
-        if n_part <= 1:
-            return False
-        elems = _shape_elems(shape)
-        return any(osh == shape and _shape_elems(sh) * n_part == elems
-                   for sh, osh in slices)
+    # shapes some dynamic-slice cuts to exactly 1/num_partitions; a bare
+    # operand's shape is read where it is defined, and only where some
+    # all-reduce could be a scatter (one device's program has none)
+    scattered = set()
+    if reclassify_scatter and n_part > 1 and any(
+            op == "all-reduce" for op, _, _ in colls):
+        defined = (dict(_HLO_DEF_RE.findall(text))
+                   if any(o is None for _, o, _ in slices) else {})
+        for sh, osh, name in set(slices):
+            osh = osh if osh is not None else defined.get(name)
+            if osh is not None and \
+                    _shape_elems(sh) * n_part == _shape_elems(osh):
+                scattered.add(osh)
 
     out: Dict[str, Dict[str, int]] = {}
     for op, dt, shape in colls:
-        if (reclassify_scatter and op == "all-reduce"
-                and is_scattered(shape)):
+        if op == "all-reduce" and shape in scattered:
             op = "reduce-scatter"
         name = op.replace("-", "_")
         nbytes = _shape_elems(shape) * _HLO_DTYPE_BYTES.get(dt, 4)

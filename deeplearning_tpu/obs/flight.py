@@ -29,7 +29,8 @@ import traceback
 from typing import Any, Dict, List, Optional
 
 __all__ = ["FlightRecorder", "get_recorder", "record", "tally",
-           "configure", "dump", "install_signal_handler", "flush_pending"]
+           "configure", "dump", "install_signal_handler", "flush_pending",
+           "watch_pending"]
 
 
 def _jsonable(obj: Any, depth: int = 0) -> Any:
@@ -172,8 +173,11 @@ class FlightRecorder:
             }
             os.makedirs(os.path.dirname(os.path.abspath(path)),
                         exist_ok=True)
-            with open(path, "w") as f:
+            # tmp + replace: a SIGKILL mid-write leaves the last whole dump
+            tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
+            with open(tmp, "w") as f:
                 json.dump(doc, f, indent=1)
+            os.replace(tmp, path)
             self.dumps += 1
             return path
         except Exception:  # noqa: BLE001 - a dump failure must not mask
@@ -211,13 +215,15 @@ def dump(reason: str = "manual", *,
 
 
 _PENDING = threading.Event()
+_PENDING_LOCK = threading.RLock()      # re-entered by the handler's chain
 
 
 def _sigterm_dump(signum: int, frame) -> None:
     # Signal-handler discipline (DLT103): mark the dump pending and get
     # out. When a graceful subscriber owns this signal the process
-    # keeps running to its next step boundary, where flush_pending()
-    # does the open()/json work on the normal call stack.
+    # keeps running, and flush_pending() does the open()/json work on a
+    # normal call stack: the Trainer's next step boundary, or the
+    # ``watch_pending`` thread when the main thread never gets there.
     _PENDING.set()
     from ..elastic import signals
     if any(graceful for _fn, graceful
@@ -233,11 +239,35 @@ def _sigterm_dump(signum: int, frame) -> None:
 def flush_pending() -> Optional[str]:
     """Write a dump the SIGTERM handler deferred; no-op when none is
     pending. Called from the Trainer's step boundary (next to the
-    preemption poll) and from its graceful-exit path."""
-    if not _PENDING.is_set():
-        return None
-    _PENDING.clear()
-    return _RECORDER.dump("sigterm")
+    preemption poll), its graceful-exit path and the ``watch_pending``
+    thread; whichever comes first writes it, and the others return once it
+    is written (so a later dump, such as the preemption's, lands after)."""
+    with _PENDING_LOCK:
+        if not _PENDING.is_set():
+            return None
+        _PENDING.clear()
+        return _RECORDER.dump("sigterm")
+
+
+def watch_pending():
+    """Start a thread that writes a deferred SIGTERM dump as soon as the
+    handler marks it: a wedged main thread (a device call that never
+    returns) reaches no step boundary, and the supervisor's SIGKILL
+    follows its grace. Returns the function that stops the thread."""
+    from . import threads as obs_threads
+    stop = threading.Event()
+
+    def run() -> None:
+        while not stop.is_set():
+            if _PENDING.wait(0.05):
+                flush_pending()
+
+    thread = obs_threads.spawn(run, name="flight-pending", daemon=True)
+
+    def halt() -> None:
+        stop.set()
+        thread.join(2.0)
+    return halt
 
 
 def install_signal_handler() -> bool:

@@ -167,6 +167,7 @@ class Trainer:
         self._hbm = None
         self._obs_owns_tracer = False
         self._obs_started = False
+        self._stop_flight_watch = None
         # fleet scrape surface: "auto" serves /metrics + /healthz only
         # when DLTPU_METRICS_PORT names a port (the supervisor/fleet
         # contract); an int forces that port (0 = ephemeral); None/False
@@ -355,6 +356,7 @@ class Trainer:
             flight.configure(os.path.join(self.workdir, "flightrec.json"),
                              config=self._obs_config())
             flight.install_signal_handler()
+            self._stop_flight_watch = flight.watch_pending()
         self._hbm = HbmWatermark(interval_s=self.hbm_sample_s,
                                  alert_frac=self.hbm_alert_frac).start()
         # metrics registry: always on with obs (the push helpers in
@@ -387,6 +389,9 @@ class Trainer:
         if self._hbm is not None:
             self._hbm.stop()
             self.hbm_watermark = self._hbm.watermark()
+        if self._stop_flight_watch is not None:
+            self._stop_flight_watch()
+            self._stop_flight_watch = None
         tracer = spans.get_tracer()
         if tracer is not None and self.workdir:
             tracer.dump(os.path.join(self.workdir, "trace.json"))

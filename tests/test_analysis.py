@@ -435,6 +435,32 @@ class TestJaxprAuditor:
                              "f32[100]{0} dynamic-slice")
         assert ana_jaxpr.hlo_collectives(other)["all_reduce"]["count"] == 1
 
+    def test_hlo_reads_combined_all_reduces_and_bare_operands(self):
+        """This XLA's spellings: the combiner's one all-reduce with a tuple
+        result counts each element as the all-reduce it was; a slice inside
+        a fusion names its operand bare (its shape is where the name is
+        defined); an async pair counts once, at its ``-done``; a TPU
+        layout's tiling nests parentheses in a tuple result."""
+        text = """HloModule jit_step, num_partitions=8
+
+%fused (param_0.3: f32[512,256]) -> f32[64,256] {
+  %param_0.3 = f32[512,256]{1,0} parameter(0)
+  ROOT %dynamic-slice.1 = f32[64,256]{1,0} dynamic-slice(%param_0.3, %c, %c)
+}
+  %ar = (f32[512,256]{1,0}, f32[4]{0}, /*index=2*/f32[]) all-reduce(%g, %h, %l), to_apply=%add
+  %ags = ((bf16[32,768]{1,0}), bf16[256,768]{1,0:T(8,128)(2,1)}) all-gather-start(bf16[32,768]{1,0} %c), dimensions={0}
+  %agd = bf16[256,768]{1,0:T(8,128)(2,1)} all-gather-done(%ags)
+  %crs = (f32[8]{0:T(256)}, f32[8]{0:T(256)}) all-reduce-start(f32[8]{0} %x)
+  %crd = (f32[8]{0:T(256)}, f32[8]{0:T(256)}) all-reduce-done(%crs)
+"""
+        got = ana_jaxpr.hlo_collectives(text)
+        assert got["reduce_scatter"] == {"count": 1, "bytes": 512 * 256 * 4,
+                                         "max_bytes": 512 * 256 * 4}
+        assert got["all_reduce"] == {"count": 4, "bytes": 16 + 4 + 32 + 32,
+                                     "max_bytes": 32}
+        assert got["all_gather"] == {"count": 1, "bytes": 256 * 768 * 2,
+                                     "max_bytes": 256 * 768 * 2}
+
     @pytest.mark.skipif(len(jax.devices()) < 2,
                         reason="zero1 audits need >= 2 devices")
     def test_zero1_audit_rows_prove_the_lowering(self):

@@ -215,6 +215,12 @@ def _bench(fn, *args, reps=3):
     return min(times)
 
 
+# the suite compiles at a low XLA:CPU optimisation level (conftest.py); a
+# timed program is compiled as it is deployed, at XLA's defaults
+DEPLOYED = {"xla_backend_optimization_level": 2,
+            "xla_cpu_use_fusion_emitters": True}
+
+
 class TestWallClock:
     """CPU wall-clock acceptance (style of the prefetcher's 1.15x test
     in test_device_prefetch.py): the asymptotics must show up as real
@@ -226,9 +232,11 @@ class TestWallClock:
         boxes, scores = make_cases(rng, 1, n, span=2000.0, wh_max=64.0)
         boxes, scores = boxes[0], scores[0]
         ref = jax.jit(functools.partial(nms_ops.nms_reference,
-                                        iou_threshold=0.5, max_out=mo))
+                                        iou_threshold=0.5, max_out=mo),
+                      compiler_options=DEPLOYED)
         blk = jax.jit(functools.partial(nms_ops.nms_blocked,
-                                        iou_threshold=0.5, max_out=mo))
+                                        iou_threshold=0.5, max_out=mo),
+                      compiler_options=DEPLOYED)
         assert_same_keeps(ref(boxes, scores), blk(boxes, scores),
                           "20k pre-timing")
         t_ref = _bench(ref, boxes, scores)
@@ -248,9 +256,10 @@ class TestWallClock:
         rois = jnp.asarray(np.clip(np.concatenate(
             [ctr - size / 2, ctr + size / 2], -1), 0, 511).astype(
                 np.float32))
-        one = jax.jit(lambda q: roi_ops.multiscale_roi_align(pyr, q))
+        one = jax.jit(lambda q: roi_ops.multiscale_roi_align(pyr, q),
+                      compiler_options=DEPLOYED)
         msk = jax.jit(lambda q: roi_ops.multiscale_roi_align(
-            pyr, q, impl="masked"))
+            pyr, q, impl="masked"), compiler_options=DEPLOYED)
         np.testing.assert_allclose(np.asarray(one(rois)),
                                    np.asarray(msk(rois)), atol=1e-5)
         t_one = _bench(one, rois)

@@ -113,7 +113,7 @@ def _layer(monkeypatch, path, capacity=None):
     def loss(p, x):
         y, sown = layer.apply({"params": p}, x, mutable=["moe_metrics"])
         return jnp.sum(y.astype(jnp.float32) * mix), (y, sown)
-    return jax.value_and_grad(loss, (0, 1), has_aux=True)(p, x)
+    return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(p, x)
 
 
 @pytest.mark.parametrize("capacity", [None, 256], ids=["one_pass", "passes"])

@@ -31,6 +31,14 @@ def synthetic_cls(n=96, seed=0):
     return images, labels
 
 
+def _until(cond, timeout_s=10.0):
+    """Poll ``cond`` until it holds; fail after ``timeout_s``."""
+    deadline = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < deadline, "the condition never held"
+        time.sleep(0.005)
+
+
 def make_state(seed=0):
     model = MODELS.build("mnist_fcn", num_classes=4, dtype=jnp.float32)
     params = model.init(jax.random.key(seed),
@@ -193,9 +201,10 @@ class TestDevicePrefetcher:
             np.testing.assert_array_equal(a, b)
 
     def test_started_pipeline_discarded_on_epoch_change(self):
-        pf = DevicePrefetcher(CountingLoader(n=4), depth=2)
+        src = CountingLoader(n=4)
+        pf = DevicePrefetcher(src, depth=2)
         pf.start()             # eagerly producing epoch 0
-        time.sleep(0.05)
+        _until(lambda: src.produced > 0)
         pf.set_epoch(1)        # stale pipeline must be thrown away
         vals = [float(np.asarray(b["x"]).ravel()[0]) for b in pf]
         assert vals == [1000.0, 1001.0, 1002.0, 1003.0]
@@ -204,8 +213,7 @@ class TestDevicePrefetcher:
         src = CountingLoader(n=4)
         pf = DevicePrefetcher(src, depth=2)
         pf.start()
-        time.sleep(0.1)        # queue fills while "compiling"
-        assert src.produced > 0
+        _until(lambda: src.produced > 0)   # it fills while "compiling"
         vals = [float(np.asarray(b["x"]).ravel()[0]) for b in pf]
         assert vals == [0.0, 1.0, 2.0, 3.0]
         assert src.produced == 4    # one pipeline, not two
@@ -357,8 +365,7 @@ class TestPrecompile:
             train_step=make_train_step(make_loss_fn(), donate=False),
             train_loader=pf, epochs=1, log_every=100)
         assert trainer.precompile() is None  # no spec, but feed started
-        time.sleep(0.1)
-        assert src.produced > 0              # worker ran during "compile"
+        _until(lambda: src.produced > 0)     # worker ran during "compile"
         pf.reseed(0)     # stops the started pipeline: its worker would
         #                  outlive the test, parked on the full queue
 

@@ -56,14 +56,25 @@ class TestExpSystem:
         assert exp.model_name == "resnet18" and exp.base_lr == 0.3
 
 
+@pytest.fixture(scope="module")
+def swin_moe():
+    """The tiny Swin-MoE, its weights (``init`` does not depend on the
+    batch's size or values) and its jitted loss gradient, built once for
+    the tests below."""
+    model = _tiny_swin_moe()
+    variables = jax.jit(lambda x: model.init(jax.random.key(0), x,
+                                             train=False))(
+        jnp.zeros((1, 56, 56, 3)))
+    return model, variables, jax.jit(jax.grad(_moe_loss(model)))
+
+
 class TestSwinMoE:
-    def test_forward_with_aux_losses(self):
-        model = _tiny_swin_moe()
+    def test_forward_with_aux_losses(self, swin_moe):
+        model, variables, _ = swin_moe
         x = jnp.asarray(np.random.default_rng(0).normal(
             size=(2, 56, 56, 3)), jnp.float32)
-        variables = model.init(jax.random.key(0), x, train=False)
-        out, aux = model.apply(variables, x, train=False,
-                               mutable=["losses"])
+        out, aux = jax.jit(lambda v, x: model.apply(
+            v, x, train=False, mutable=["losses"]))(variables, x)
         assert out.shape == (2, 4)
         auxes = jax.tree.leaves(aux["losses"])
         assert len(auxes) >= 2             # one per MoE block
@@ -76,32 +87,27 @@ class TestSwinMoE:
                        and l.ndim == 3]
         assert moe_kernels and all(k.shape[0] == 2 for k in moe_kernels)
 
-    def test_trainable_with_aux_in_loss(self):
-        model = _tiny_swin_moe()
-        x = jnp.zeros((2, 56, 56, 3))
-        variables = model.init(jax.random.key(0), x, train=False)
-
-        loss = _moe_loss(model)
-        g = jax.grad(lambda p: loss(p, x))(variables["params"])
+    def test_trainable_with_aux_in_loss(self, swin_moe):
+        _, variables, grad = swin_moe
+        # the batch of the test below: one compile of the gradient for both
+        g = grad(variables["params"], jnp.zeros((4, 56, 56, 3)))
         leaves = [np.asarray(v, np.float64) for v in jax.tree.leaves(g)]
         assert all(np.isfinite(l).all() for l in leaves)
         assert max(np.abs(l).max() for l in leaves) > 0
 
-    def test_expert_parallel_grads_match_unsharded(self):
+    def test_expert_parallel_grads_match_unsharded(self, swin_moe):
         """EP training: MOE_RULES shard expert kernels over the expert
         axis; gradients match the unsharded run exactly."""
         from deeplearning_tpu.parallel import MeshConfig, build_mesh
         from deeplearning_tpu.parallel.moe import MOE_RULES
         from deeplearning_tpu.parallel.sharding import (batch_sharding,
                                                         shard_params_tree)
-        model = _tiny_swin_moe()
+        _, variables, grad = swin_moe
         x = jnp.asarray(np.random.default_rng(0).normal(
             size=(4, 56, 56, 3)), jnp.float32)
-        variables = model.init(jax.random.key(0), x, train=False)
         params = variables["params"]
 
-        loss = _moe_loss(model)
-        g_ref = jax.jit(jax.grad(loss))(params, x)
+        g_ref = grad(params, x)
 
         mesh = build_mesh(MeshConfig(data=-1, expert=2))
         shardings = shard_params_tree(params, mesh, MOE_RULES)
@@ -111,7 +117,7 @@ class TestSwinMoE:
                           if not l.sharding.is_fully_replicated]
         assert sharded_leaves, "MOE_RULES sharded nothing"
         xs = jax.device_put(x, batch_sharding(mesh))
-        g_ep = jax.jit(jax.grad(loss))(ps, xs)
+        g_ep = grad(ps, xs)
         for a, b in zip(jax.tree.leaves(g_ep), jax.tree.leaves(g_ref)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-4, rtol=1e-3)
@@ -135,7 +141,8 @@ class TestMoEObservability:
         x = jnp.asarray(np.random.default_rng(0).normal(
             size=(8, 28, 28, 3)), jnp.float32)
         y = jnp.asarray(np.random.default_rng(1).integers(0, 4, 8))
-        variables = model.init(jax.random.key(0), x, train=False)
+        variables = jax.jit(lambda x: model.init(jax.random.key(0), x,
+                                                 train=False))(x)
         state = TrainState.create(apply_fn=model.apply,
                                   params=variables["params"],
                                   tx=optax.adam(1e-3))

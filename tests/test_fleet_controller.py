@@ -323,11 +323,12 @@ class TestSupervisorDirectives:
                 "os.environ.get('DLTPU_RESTART_ATTEMPT','?')+'\\n');"
                 "time.sleep(60)", str(marks)]
         sup, t, box = self._start(self._cfg(tmp_path / "s", argv=argv))
-        _wait(lambda: sup.launches >= 1, msg="first launch")
-        time.sleep(0.2)
+
+        def attempts():
+            return marks.read_text().splitlines() if marks.exists() else []
+        _wait(lambda: attempts() == ["0"], msg="first child's mark")
         sup.request_restart("controller_wedged")
-        _wait(lambda: sup.launches >= 2, msg="relaunch")
-        time.sleep(0.2)
+        _wait(lambda: attempts() == ["0", "1"], msg="relaunched child's mark")
         sup.request_stop("done")
         t.join(30)
         assert not t.is_alive()
@@ -669,7 +670,17 @@ class TestFleetControllerE2E:
             assert pre["replica"] == 2 and pre["verdict"] == "replace"
 
             # both replacements warm: 3 live replicas again, fresh pids
+            def relaunched(i):
+                try:
+                    with open(os.path.join(wd, f"replica-{i}",
+                                           "endpoint.json")) as f:
+                        return int(json.load(f)["pid"]) != first_pids[i]
+                except (OSError, ValueError, KeyError):
+                    return False
+
             def recovered():
+                if not (relaunched(1) and relaunched(2)):
+                    return False
                 urls = discover_endpoints(wd, live_only=True)
                 if len(urls) < 3:
                     return False

@@ -23,7 +23,7 @@ import _step_scopes                                           # noqa: E402
 from benchmarks.flops import granite as flops                 # noqa: E402
 from benchmarks.references import granite as ref              # noqa: E402
 from benchmarks.references import ops as ref_ops              # noqa: E402
-from benchmarks.references import train_ref, train_ref_lm     # noqa: E402
+from benchmarks.references import train_ref_lm                # noqa: E402
 from deeplearning_tpu.core.registry import MODELS             # noqa: E402
 from deeplearning_tpu.models.language import granite          # noqa: E402
 from deeplearning_tpu.ops import ssd                          # noqa: E402
@@ -356,49 +356,3 @@ def test_flops_and_parameters_of_the_cell():
     assert mine.mamba_n_heads * mine.mamba_d_head \
         == cfg["mamba_expand"] * cfg["hidden_size"]
     assert mine.tie_word_embeddings is cfg["tie_word_embeddings"] is True
-
-
-def test_token_driver_rehearsal_and_its_controls():
-    """A whole run of the benchmark's token driver on the CPU at the
-    rehearsal size comes out correct; the faults ``tools/readings_row.py``
-    plants in the reference in the program's place (the second half of each
-    row's positions left out, and the family's own: the state dropped between
-    chunks) and a state left unchanged do not. The fp8 control and the half
-    batch are the GLM rehearsal's (``test_language_model.py``)."""
-    import importlib.util
-    from benchmarks import run as bench_run
-    from benchmarks.drivers.train_tokens import compare
-    from benchmarks.harness import check
-    spec = importlib.util.spec_from_file_location("readings_row", os.path.join(
-        ROOT, "benchmarks", "tools", "readings_row.py"))
-    readings_row = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(readings_row)
-    line, run = bench_run.execute(
-        "rehearse_granite_micro", 424243, 0.3, False, require_tpu=False,
-        bench_file=os.path.join(ROOT, "benchmarks", "tests",
-                                "bench_rehearse_granite.json"))
-    assert line["correct"] is True, line["compared"]
-    assert run.facts["tokens_per_s"] > 0
-    batches = run.reference_inputs
-    half = readings_row.half_positions(batches)
-    n = batches[0].shape[1] - 1
-    assert [b.shape for b in half] == [(len(b), n // 2 + 1) for b in batches]
-    assert all(np.array_equal(h, b[:, :n // 2 + 1])
-               for h, b in zip(half, batches))
-    fam = train_ref.family(run.config["family"])
-    common = dict(fam_name=run.config["family"], shapes=run.config["shapes"],
-                  recipe=run.config["recipe"], batches=batches,
-                  rows=run.traffic["reference_rows"])
-    faults = readings_row.planted(batches)
-    for name in ("fault_half_positions", "fault_family_own"):
-        out = train_ref_lm.follow(params=fam.make_params(
-            fam.param_spec(run.config["shapes"]), 424243),
-            **{**common, **faults[name]})
-        ok, rows = check.judge({**compare(out, run.reference),
-                                "rows_wrong": 0.0}, run.checks["limits"])
-        assert not ok, (name, rows)
-    still = dict(run.reference,
-                 change=[0.0 * x for x in run.reference["change"]])
-    ok, _ = check.judge({**compare(still, run.reference), "rows_wrong": 0.0},
-                        run.checks["limits"])
-    assert not ok

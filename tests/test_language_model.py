@@ -3,7 +3,8 @@ share of the expert layer (``parallel/moe.py::HeldExpertsMlp``), the causal
 attention path and the language-model task, at a small size on the CPU with
 seeded weights, against the benchmark's plain reference
 (``benchmarks/references/glm_moe_lite.py``), which imports nothing of the
-program."""
+program; and every language family's rehearsal of the benchmark's token
+driver (``test_token_driver_rehearsal_and_its_controls``)."""
 import json
 import os
 import sys
@@ -22,7 +23,7 @@ import _step_scopes                                          # noqa: E402
 from benchmarks.flops import glm_moe_lite as flops           # noqa: E402
 from benchmarks.references import glm_moe_lite as ref        # noqa: E402
 from benchmarks.references import ops as ref_ops             # noqa: E402
-from benchmarks.references import train_ref, train_ref_lm    # noqa: E402
+from benchmarks.references import train_ref_lm               # noqa: E402
 from deeplearning_tpu.analysis import jaxpr as audit         # noqa: E402
 from deeplearning_tpu.core.registry import MODELS            # noqa: E402
 from deeplearning_tpu.models.language import glm_moe_lite as glm  # noqa: E402
@@ -489,37 +490,87 @@ def test_flops_of_the_cell_are_the_issues_arithmetic():
             assert cfg[key] == value, key
 
 
-def test_token_driver_rehearsal_and_its_controls():
+def _readings_tool(name):
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(name, os.path.join(
+        ROOT, "benchmarks", "tools", name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _token_controls(batches):
+    """``readings_tokens.py``'s: the fp8 control, half of the batch's rows
+    and the family's own fault."""
+    return _readings_tool("readings_tokens").planted(len(batches[0]))
+
+
+def _row_controls(batches):
+    """``readings_row.py``'s: the second half of each row's positions left
+    out, and the family's own fault."""
+    readings_row = _readings_tool("readings_row")
+    half = readings_row.half_positions(batches)
+    n = batches[0].shape[1] - 1
+    assert [b.shape for b in half] == [(len(b), n // 2 + 1) for b in batches]
+    assert all(np.array_equal(h, b[:, :n // 2 + 1])
+               for h, b in zip(half, batches))
+    return readings_row.planted(batches)
+
+
+# each language family: (bench file, workload, what the readings tool plants
+# in the program's place, expert layers a step or None). The family's own
+# fault is the keyword the harness hands every family: for GLM the correction
+# bias left out of the choice, for Mellum2 the window left off the sliding
+# layers, for Granite the state not carried from one chunk to the next.
+REHEARSALS = {
+    "glm": ("bench_rehearse_tokens.json", "rehearse_glm_micro",
+            _token_controls, 3),
+    "mellum": ("bench_rehearse_mellum.json", "rehearse_mellum_micro",
+               _token_controls, 4),
+    "granite": ("bench_rehearse_granite.json", "rehearse_granite_micro",
+                _row_controls, None),
+}
+
+
+@pytest.mark.parametrize("family", sorted(REHEARSALS))
+def test_token_driver_rehearsal_and_its_controls(family):
     """A whole run of the benchmark's token driver on the CPU at the
-    rehearsal size comes out correct; the fp8 control, the half batch, the
-    correction bias left out of the choice (planted in the reference, in the
-    program's place) and a state left unchanged do not."""
+    family's rehearsal size comes out correct; what the readings tool plants
+    in the program's place (the reference in fp8, half of the batch, or of
+    each row's positions for the one-row granite batch, and the family's own
+    fault) and a state left unchanged do not."""
     from benchmarks import run as bench_run
     from benchmarks.drivers.train_tokens import compare
     from benchmarks.harness import check
+    from benchmarks.references import train_ref
+    bench_file, workload, controls, expert_layers = REHEARSALS[family]
     line, run = bench_run.execute(
-        "rehearse_glm_micro", 424243, 0.3, False, require_tpu=False,
-        bench_file=os.path.join(ROOT, "benchmarks", "tests",
-                                "bench_rehearse_tokens.json"))
+        workload, 424243, 0.3, False, require_tpu=False,
+        bench_file=os.path.join(ROOT, "benchmarks", "tests", bench_file))
     assert line["correct"] is True, line["compared"]
     assert line["compared"]["rows_wrong"]["value"] == 0.0
-    assert run.facts["expert_layer_steps"] == 3 * run.facts["steps"]
+    if expert_layers is not None:
+        assert run.facts["expert_layer_steps"] == \
+            expert_layers * run.facts["steps"]
     assert run.facts["tokens_per_s"] > 0
+    batches = run.reference_inputs
     fam = train_ref.family(run.config["family"])
-    n = len(run.reference_inputs[0])
-    for name, kw in (("fp8", {"mode": "fp8"}),
-                     ("half batch", {"skip_rows": range(n // 2, n)}),
-                     ("bias", {"bias_in_choice": False})):
-        out = train_ref_lm.follow(
-            fam_name=run.config["family"], shapes=run.config["shapes"],
-            recipe=run.config["recipe"], batches=run.reference_inputs,
-            rows=run.traffic["reference_rows"], params=fam.make_params(
-                fam.param_spec(run.config["shapes"]), 424243), **kw)
+    # one draw of the starting weights for every control; ``follow``
+    # consumes what it is handed, so each takes a copy
+    params = fam.make_params(fam.param_spec(run.config["shapes"]), 424243)
+    common = dict(fam_name=run.config["family"], shapes=run.config["shapes"],
+                  recipe=run.config["recipe"], batches=batches,
+                  rows=run.traffic["reference_rows"])
+    for name, kw in controls(batches).items():
+        out = train_ref_lm.follow(params=jax.tree.map(jnp.copy, params),
+                                  **{**common, **kw})
         ok, rows = check.judge({**compare(out, run.reference),
                                 "rows_wrong": 0.0}, run.checks["limits"])
         assert not ok, (name, rows)
+        del out
     still = dict(run.reference,
                  change=[0.0 * x for x in run.reference["change"]])
     ok, _ = check.judge({**compare(still, run.reference), "rows_wrong": 0.0},
                         run.checks["limits"])
     assert not ok
+

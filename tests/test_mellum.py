@@ -22,7 +22,7 @@ import _step_scopes                                           # noqa: E402
 from benchmarks.flops import mellum as flops                  # noqa: E402
 from benchmarks.references import mellum as ref               # noqa: E402
 from benchmarks.references import ops as ref_ops              # noqa: E402
-from benchmarks.references import train_ref, train_ref_lm     # noqa: E402
+from benchmarks.references import train_ref_lm                # noqa: E402
 from deeplearning_tpu.analysis import jaxpr as audit          # noqa: E402
 from deeplearning_tpu.core.registry import MODELS             # noqa: E402
 from deeplearning_tpu.models.language import mellum           # noqa: E402
@@ -421,40 +421,3 @@ def test_flops_and_parameters_of_the_cell_are_the_issues_arithmetic():
         assert getattr(mine, key) == s[key], key
     assert (mine.num_experts, mine.experts_held) == (64, 16)
     assert [mine.kind(i) for i in range(4)] == cfg["layer_types"]
-
-
-def test_token_driver_rehearsal_and_its_controls():
-    """A whole run of the benchmark's token driver on the CPU at the
-    rehearsal size comes out correct; the fp8 control, the half batch, the
-    window left off the sliding layers (planted in the reference, in the
-    program's place) and a state left unchanged do not."""
-    from benchmarks import run as bench_run
-    from benchmarks.drivers.train_tokens import compare
-    from benchmarks.harness import check
-    line, run = bench_run.execute(
-        "rehearse_mellum_micro", 424243, 0.3, False, require_tpu=False,
-        bench_file=os.path.join(ROOT, "benchmarks", "tests",
-                                "bench_rehearse_mellum.json"))
-    assert line["correct"] is True, line["compared"]
-    assert line["compared"]["rows_wrong"]["value"] == 0.0
-    assert run.facts["expert_layer_steps"] == 4 * run.facts["steps"]
-    assert run.facts["tokens_per_s"] > 0
-    fam = train_ref.family(run.config["family"])
-    n = len(run.reference_inputs[0])
-    for name, kw in (("fp8", {"mode": "fp8"}),
-                     ("half batch", {"skip_rows": range(n // 2, n)}),
-                     # the harness hands a family's own fault this keyword
-                     ("window left off", {"bias_in_choice": False})):
-        out = train_ref_lm.follow(
-            fam_name=run.config["family"], shapes=run.config["shapes"],
-            recipe=run.config["recipe"], batches=run.reference_inputs,
-            rows=run.traffic["reference_rows"], params=fam.make_params(
-                fam.param_spec(run.config["shapes"]), 424243), **kw)
-        ok, rows = check.judge({**compare(out, run.reference),
-                                "rows_wrong": 0.0}, run.checks["limits"])
-        assert not ok, (name, rows)
-    still = dict(run.reference,
-                 change=[0.0 * x for x in run.reference["change"]])
-    ok, _ = check.judge({**compare(still, run.reference), "rows_wrong": 0.0},
-                        run.checks["limits"])
-    assert not ok

@@ -33,17 +33,26 @@ def _data(n=8, seed=0):
     return jnp.asarray(images), jnp.asarray(labels)
 
 
+@pytest.fixture(scope="module")
+def vit_pipe():
+    """The mesh, the tiny ViT, its data and weights (sequential and split
+    into two stages), built once for the tests below."""
+    mesh = build_mesh(MeshConfig(data=-1, model=2))
+    model = _tiny_vit()
+    images, labels = _data()
+    ref_params = jax.jit(lambda x: model.init(
+        jax.random.key(0), x, train=False))(images[:1])["params"]
+    outer, stages, k_per = split_vit_params(ref_params, 2)
+    return dict(mesh=mesh, model=model, images=images, labels=labels,
+                ref_params=ref_params, k_per=k_per,
+                pp_params={"outer": outer, "stages": stages})
+
+
 class TestPipelineTraining:
-    def setup_method(self, _):
-        self.mesh = build_mesh(MeshConfig(data=-1, model=2))
-        self.model = _tiny_vit()
-        images, labels = _data()
-        self.images, self.labels = images, labels
-        variables = self.model.init(jax.random.key(0), images[:1],
-                                    train=False)
-        self.ref_params = variables["params"]
-        outer, stages, self.k_per = split_vit_params(self.ref_params, 2)
-        self.pp_params = {"outer": outer, "stages": stages}
+    @pytest.fixture(autouse=True)
+    def _setup(self, vit_pipe):
+        for k, v in vit_pipe.items():
+            setattr(self, k, v)
 
     def _restructure(self, tree):
         outer, stages, _ = split_vit_params(tree, 2)
@@ -52,9 +61,9 @@ class TestPipelineTraining:
     def test_forward_matches_sequential(self):
         forward = make_vit_pipeline_forward(self.model, self.mesh, 2,
                                             self.k_per, microbatches=4)
-        got = forward(self.pp_params, self.images)
-        want = self.model.apply({"params": self.ref_params}, self.images,
-                                train=False)
+        got = jax.jit(forward)(self.pp_params, self.images)
+        want = jax.jit(lambda p, x: self.model.apply(
+            {"params": p}, x, train=False))(self.ref_params, self.images)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
@@ -75,8 +84,8 @@ class TestPipelineTraining:
             return optax.softmax_cross_entropy_with_integer_labels(
                 logits, self.labels).mean()
 
-        pp_l, pp_g = jax.value_and_grad(pp_loss)(self.pp_params)
-        ref_l, ref_g = jax.value_and_grad(ref_loss)(self.ref_params)
+        pp_l, pp_g = jax.jit(jax.value_and_grad(pp_loss))(self.pp_params)
+        ref_l, ref_g = jax.jit(jax.value_and_grad(ref_loss))(self.ref_params)
         np.testing.assert_allclose(float(pp_l), float(ref_l), rtol=1e-5)
         ref_g_pp = self._restructure(ref_g)
         flat_pp = jax.tree_util.tree_leaves_with_path(pp_g)
@@ -117,7 +126,7 @@ class TestPipelineTraining:
 def test_pipeline_cli():
     """train.py train.pipeline_stages=2 end to end on the CPU mesh."""
     from tools.train import main
-    rc = main(["model.name=vit_base_patch16_224", "model.num_classes=3",
+    rc = main(["model.name=vit_micro_patch4_56", "model.num_classes=3",
                "model.precision=f32",
                "data.image_size=16", "data.channels=3", "data.n_train=32",
                "data.global_batch=8",

@@ -472,5 +472,4 @@ class TestServeSupervision:
             if server is not None:
                 server.shutdown()
                 server.server_close()
-            mb._q.queue.clear()
             mb.close()

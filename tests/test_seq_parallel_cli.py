@@ -11,7 +11,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
-COMMON = ["model.name=vit_base_patch16_224", "model.num_classes=4",
+COMMON = ["model.name=vit_micro_patch4_56", "model.num_classes=4",
           "data.synthetic=true", "data.image_size=32", "data.channels=3",
           "data.n_train=16", "data.global_batch=8", "train.epochs=1"]
 

@@ -189,12 +189,20 @@ class _SlowFakeEngine:
         return images.sum(axis=(1, 2, 3))   # one scalar per row
 
 
+def _until(cond, timeout_s=10.0):
+    """Poll ``cond`` until it holds; fail after ``timeout_s``."""
+    deadline = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < deadline, "the condition never held"
+        time.sleep(0.005)
+
+
 def test_backpressure_on_saturated_queue():
     eng = _SlowFakeEngine()
     img = np.ones((4, 4, 3), np.float32)
     with MicroBatcher(eng, max_wait_ms=1.0, max_queue=2) as mb:
         first = mb.submit(img)              # dispatcher blocks in run()
-        time.sleep(0.1)                     # let it pop the first request
+        _until(lambda: mb.busy)             # it has popped the request
         held = [mb.submit(img), mb.submit(img)]   # fills max_queue=2
         with pytest.raises(Rejected) as ei:
             mb.submit(img)
@@ -211,7 +219,7 @@ def test_deadline_cancels_before_dispatch():
     img = np.ones((4, 4, 3), np.float32)
     with MicroBatcher(eng, max_wait_ms=1.0) as mb:
         blocker = mb.submit(img)            # occupies the dispatcher
-        time.sleep(0.1)
+        _until(lambda: mb.busy)
         doomed = mb.submit(img, timeout_s=0.01)   # expires in queue
         time.sleep(0.1)
         eng.release.set()

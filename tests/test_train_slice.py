@@ -140,7 +140,8 @@ class TestStepScopes:
         import _step_scopes
         from deeplearning_tpu.train import steps
 
-        model = MODELS.build("vit_micro_patch4_56", num_classes=10)
+        # two blocks: what one names, every one does
+        model = MODELS.build("vit_micro_patch4_56", num_classes=10, depth=2)
         params = model.init(jax.random.key(0), jnp.zeros((1, 56, 56, 3)),
                             train=False)["params"]
         # bfloat16 parameters, so that the gradients' cast has work to do

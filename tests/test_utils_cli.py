@@ -96,8 +96,8 @@ class TestProfiling:
 
 class TestTrainCLI:
     def test_end_to_end_cli(self, tmp_path):
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   XLA_FLAGS="--xla_force_host_platform_device_count=8")
+        # the suite's XLA_FLAGS (conftest.py): eight devices, quick compiles
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         out = subprocess.run(
             [sys.executable, os.path.join(REPO, "tools", "train.py"),
              "--cfg", os.path.join(REPO, "configs", "mnist_smoke.yaml"),

@@ -117,11 +117,10 @@ class TestYOLOX:
         x = jnp.zeros((1, 64, 64, 3))
         variables = model.init(jax.random.key(0), x, train=False)
         raw = model.apply(variables, x, train=False)
-        losses = Y.yolox_loss(raw, jnp.asarray(centers),
-                              jnp.asarray(strides),
-                              jnp.asarray([[[8.0, 8, 40, 40]]]),
-                              jnp.asarray([[2]]), jnp.asarray([[True]]),
-                              num_classes=4, use_l1=True)
+        losses = jax.jit(lambda r: Y.yolox_loss(
+            r, jnp.asarray(centers), jnp.asarray(strides),
+            jnp.asarray([[[8.0, 8, 40, 40]]]), jnp.asarray([[2]]),
+            jnp.asarray([[True]]), num_classes=4, use_l1=True))(raw)
         for k in ("iou_loss", "obj_loss", "cls_loss", "l1_loss"):
             assert np.isfinite(float(losses[k])), k
         assert int(losses["num_fg"]) >= 1
@@ -135,7 +134,7 @@ class TestYOLOX:
                              jnp.asarray([[2]]), jnp.asarray([[True]]),
                              num_classes=4)
             return l["iou_loss"] + l["obj_loss"] + l["cls_loss"]
-        g = jax.grad(total)(variables["params"])
+        g = jax.jit(jax.grad(total))(variables["params"])
         gn = np.sqrt(sum(float(jnp.sum(v ** 2))
                          for v in jax.tree.leaves(g)))
         assert np.isfinite(gn) and gn > 0

@@ -29,8 +29,11 @@ SMALL = {  # shrunk configs so CPU forward+init stays fast
 @pytest.mark.parametrize("name", sorted(SMALL))
 def test_zoo_backbone_forward(name):
     m = MODELS.build(name, num_classes=7, **SMALL[name])
-    v = m.init(jax.random.key(0), jnp.zeros((1, 96, 96, 3)), train=False)
-    out = m.apply(v, jnp.zeros((2, 96, 96, 3)), train=False)
+    # init at the forward's batch: the eager forward then reuses every op
+    # init compiled (the weights do not depend on the batch)
+    x = jnp.zeros((2, 96, 96, 3))
+    v = m.init(jax.random.key(0), x, train=False)
+    out = m.apply(v, x, train=False)
     assert out.shape == (2, 7)
     assert out.dtype == jnp.float32
     # train mode mutates BN stats
@@ -87,7 +90,7 @@ def test_staged_mask_crop_pipeline(tmp_path):
         labels.append(i % 2)
 
     seg = MODELS.build("unet", num_classes=1, base_features=8)
-    v = seg.init(jax.random.key(0), jnp.zeros((1, 64, 64, 3)),
+    v = seg.init(jax.random.key(0), jnp.zeros((2, 64, 64, 3)),
                  train=False)
     predictor = make_mask_predictor(seg, v)
     n = write_masks(predictor, paths, str(tmp_path / "masks"),
@@ -99,7 +102,7 @@ def test_staged_mask_crop_pipeline(tmp_path):
     assert sample["image"].shape == (32, 32, 3)
 
     retr = MODELS.build("arcface_resnet18", num_classes=2)
-    rv = retr.init(jax.random.key(1), jnp.zeros((1, 32, 32, 3)),
+    rv = retr.init(jax.random.key(1), jnp.zeros((4, 32, 32, 3)),
                    train=False)
     batch = np.stack([src[i]["image"] for i in range(4)])
     out = retr.apply(rv, jnp.asarray(batch), train=False,
