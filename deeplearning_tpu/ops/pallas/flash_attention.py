@@ -1,9 +1,11 @@
 """Flash attention for TPU: fused online-softmax attention in Pallas.
 
 Per-head kernels that never write the (N, N) scores to HBM: forward and
-backward are Pallas kernels with a custom VJP; the backward recomputes
-P = exp(S - LSE) blockwise from the saved logsumexp, FlashAttention-2
-style.
+backward are Pallas kernels with a custom VJP. The backward is one
+pallas_call a head and key block: it makes each visible (query block, key
+block) pair's scores, P = exp(S - LSE) from the saved logsumexp, dP and dS
+once, and from them dV and dK and the head's dQ, which it sums in VMEM
+across the key blocks (FlashAttention-2's backward, dQ kept on the chip).
 
 Two callers. A causal decoder's attention core (``causal_attention``, path
 by ``select_path``, blocks of 512, the key blocks past a query block's last
@@ -59,8 +61,10 @@ DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
 # Every program holds one head's whole K and V (the backward pass: Q, dO,
-# logsumexp and delta) in VMEM, double-buffered: at 4,096 tokens of width 256
-# that is 16.5 MB, over the compiler's 16 MB default on a v5e (128 MB there).
+# logsumexp and delta, its dQ block and the float32 dQ sum) in VMEM: the
+# forward 16.5 MB at 4,096 tokens of width 256, the backward some 31 MB there
+# and 38 MB at 8,192 tokens of width 128 or 64 (``_bwd_calls``), over the
+# compiler's 16 MB default on a v5e (128 MB there).
 _VMEM_LIMIT = 96 * 2 ** 20
 # ``_flash_fwd``'s names for its output and logsumexp (module docstring);
 # outside a ``jax.checkpoint`` whose policy saves them they do nothing.
@@ -151,47 +155,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     lse_ref[0] = jnp.broadcast_to(lse[:, None], (lse.shape[0], 8))
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-                   sm_scale: float, block_k: int, kv_len: int, causal: bool,
-                   q_block: int, window):
-    qi = pl.program_id(1)
-    q = q_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0, :, 0]
-    delta = delta_ref[0, :, 0]
-    n = k_ref.shape[1]
-    nk = n // block_k
-    row = qi * q_block + jax.lax.broadcasted_iota(
-        jnp.int32, (q.shape[0], block_k), 0)
-
-    def body(ki, dq):
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :]
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :]
-        s = sm_scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        col = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (q.shape[0], block_k), 1)
-        p = jnp.where(_visible(row, col, kv_len, causal, window),
-                      jnp.exp(s - lse[:, None]), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dq = dq + jax.lax.dot_general(ds.astype(k.dtype), k,
-                                      (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        return dq
-
-    dq = jax.lax.fori_loop(
-        *_key_loop(qi, q_block, block_k, nk, causal, window), body,
-        jnp.zeros(q.shape, jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, sm_scale: float, block_q: int,
-                    kv_len: int, causal: bool, k_block: int, window):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, *, sm_scale: float,
+                block_q: int, kv_len: int, causal: bool, k_block: int,
+                window):
+    """One key block of a head against the query blocks that see it: each
+    pair's scores, ``P``, ``dP`` and ``dS`` are made once and feed ``dV``,
+    ``dK`` (carried over the query blocks) and the head's ``dQ`` (``dq_acc``,
+    float32 in VMEM across the key blocks, written at the last). Key blocks
+    run in order and query blocks in order inside each, so every gradient
+    is summed in the order a query-block-major ``dQ`` loop would sum it."""
     ki = pl.program_id(1)
+
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
     k = k_ref[0]
     v = v_ref[0]
     n = q_ref.shape[1]
@@ -201,10 +180,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def body(qi, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :]
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(qi * block_q, block_q), 0]
-        delta = delta_ref[0, pl.ds(qi * block_q, block_q), 0]
+        rows = pl.ds(qi * block_q, block_q)
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, rows, 0]
+        delta = delta_ref[0, rows, 0]
         s = sm_scale * jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -221,13 +201,17 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk = dk + jax.lax.dot_general(ds.astype(q.dtype), q,
                                       (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
+        dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         return dk, dv
 
     dk0 = jnp.zeros(k.shape, jnp.float32)
     dv0 = jnp.zeros(v.shape, jnp.float32)
     # causal: query blocks that end before this key block starts see none
     # of it; with a window, neither do those that start more than
-    # ``window - 1`` rows past its last key
+    # ``window - 1`` rows past its last key (the pairs ``_key_loop`` gives
+    # a query block, seen from the key side)
     first, stop = 0, nq
     if causal:
         first = (ki * k_block) // block_q
@@ -237,6 +221,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk, dv = jax.lax.fori_loop(first, stop, body, (dk0, dv0))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _flatten_bh(x):
@@ -293,7 +281,7 @@ def _flash_bwd(sm_scale, kv_len, causal, block_q, block_k, window, res, dout):
     qf, kf, vf = map(_flatten_bh, (q, k, v))
     dof = _flatten_bh(dout)
     of = _flatten_bh(out)
-    # delta_i = rowsum(dO_i * O_i); the kernels read it and the logsumexp
+    # delta_i = rowsum(dO_i * O_i); the kernel reads it and the logsumexp
     # as (bh, n, 8), lane 0 meaningful
     delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
                     axis=-1, keepdims=True)
@@ -316,41 +304,28 @@ def _flash_bwd(sm_scale, kv_len, causal, block_q, block_k, window, res, dout):
 
 def _bwd_calls(qf, kf, vf, dof, lse, delta, *, sm_scale, kv_len, causal,
                block_q, block_k, out_dtype=None, window=None):
-    """The two backward pallas_calls over flattened (BH, N, D) operands
-    with caller-supplied lse/delta (BH, N, 8). Shared by the plain VJP
-    and by ring attention's chunk backward (which passes the GLOBAL
+    """The backward pallas_call over flattened (BH, N, D) operands with
+    caller-supplied lse/delta (BH, N, 8). Shared by the plain VJP and by
+    ring attention's chunk backward (which passes the GLOBAL
     logsumexp/delta so per-chunk gradients sum to the exact full-sequence
     gradient). ``out_dtype`` overrides the gradients' dtype (the ring
     accumulates per-chunk grads in f32, so bf16 round trips per ring
     step would otherwise lose precision). ``kf``, ``vf`` may hold fewer
     heads, (BH / g, N, D): ``dk``, ``dv`` still come back a query head each,
-    (BH, N, D), for the caller to sum over a group."""
+    (BH, N, D), for the caller to sum over a group.
+
+    A program takes one key block of one head and holds, double-buffered,
+    the head's whole Q, dO, logsumexp and delta (the last two padded to 128
+    lanes), its ``dQ`` output block, and beside them the head's float32
+    ``dQ`` sum: at GLM-4.7-Flash's 4,096 tokens of width 256 some 31 MB, at
+    8,192 tokens of width 128 (Mellum2) some 38 MB, and as much at width 64
+    (Granite 4.0-H), whose rows VMEM pads to 128 lanes."""
     bh, n, d = qf.shape
     group = bh // kf.shape[0]
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, block_k=block_k,
-                          kv_len=kv_len, causal=causal, q_block=block_q,
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, sm_scale=sm_scale, block_q=block_q,
+                          kv_len=kv_len, causal=causal, k_block=block_k,
                           window=window),
-        grid=(bh, n // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, n, d), lambda bh, qi: (bh // group, 0, 0)),
-            pl.BlockSpec((1, n, d), lambda bh, qi: (bh // group, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda bh, qi: (bh, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, n, d), out_dtype or qf.dtype),
-        interpret=interpret_mode(),
-        compiler_params=_compiler_params(),
-    )(qf, kf, vf, dof, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
-                          block_q=block_q, kv_len=kv_len, causal=causal,
-                          k_block=block_k, window=window),
         grid=(bh, n // block_k),
         in_specs=[
             pl.BlockSpec((1, n, d), lambda bh, ki: (bh, 0, 0)),
@@ -361,18 +336,19 @@ def _bwd_calls(qf, kf, vf, dof, lse, delta, *, sm_scale, kv_len, causal,
             pl.BlockSpec((1, n, 8), lambda bh, ki: (bh, 0, 0)),
         ],
         out_specs=[
+            pl.BlockSpec((1, n, d), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((bh, n, d), out_dtype or qf.dtype),
             jax.ShapeDtypeStruct((bh, n, d), out_dtype or kf.dtype),
             jax.ShapeDtypeStruct((bh, n, d), out_dtype or vf.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((n, d), jnp.float32)],
         interpret=interpret_mode(),
         compiler_params=_compiler_params(),
     )(qf, kf, vf, dof, lse, delta)
-
-    return dq, dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

@@ -75,65 +75,94 @@ class TestFlashForward:
             atol=2e-2, rtol=2e-2)
 
 
-class TestFlashBackward:
-    def test_grads_match_reference(self):
-        q, k, v = rand_qkv(b=1, h=2, n=197, d=64)
+def _vjp_case(b, h, kv, n, d, sm_scale=None, causal=True, **keywords):
+    """The output and the gradients of ``sum(sin(attention))`` in q, k and v
+    through ``flash_attention`` and through the plain mathematics in float32:
+    causal (with ``window`` if given), ``causal_attention_lax``; else a
+    softmax over every key, the kernels' keys padded past ``n`` to a whole
+    block."""
+    q = rand_qkv(b=b, h=h, n=n, d=d, seed=1)[0]
+    k, v = (x[:, :kv] for x in rand_qkv(b=b, h=h, n=n, d=d, seed=2)[1:])
+    scale = d ** -0.5 if sm_scale is None else sm_scale
 
-        def loss_flash(q, k, v):
-            return jnp.sum(jnp.square(fa.flash_attention(q, k, v)))
+    def fused(q, k, v):
+        return fa.flash_attention(q, k, v, sm_scale=scale, causal=causal,
+                                  **keywords)
 
-        def loss_ref(q, k, v):
-            return jnp.sum(jnp.square(reference_attention(q, k, v)))
+    def oracle(q, k, v):
+        if causal:
+            return fa.causal_attention_lax(q, k, v, scale,
+                                           keywords.get("window"))
+        return reference_attention(q, k, v)
 
-        g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for gf, gr, name in zip(g_flash, g_ref, "qkv"):
-            np.testing.assert_allclose(
-                np.asarray(gf), np.asarray(gr), atol=5e-4, rtol=5e-4,
-                err_msg=f"grad mismatch for {name}")
-
-    def test_causal_grads(self):
-        q, k, v = rand_qkv(b=1, h=1, n=128, d=32)
-
-        def loss_flash(q, k, v):
-            return jnp.sum(fa.flash_attention(q, k, v, causal=True) ** 2)
-
-        def loss_ref(q, k, v):
-            return jnp.sum(reference_attention(q, k, v, causal=True) ** 2)
-
-        g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for gf, gr in zip(g_flash, g_ref):
-            np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
-                                       atol=5e-4, rtol=5e-4)
+    def results(attention):
+        grads = jax.grad(lambda *qkv: jnp.sum(jnp.sin(attention(*qkv))),
+                         (0, 1, 2))(q, k, v)
+        return dict(zip(("out", "dq", "dk", "dv"),
+                        (attention(q, k, v), *grads)))
+    return results(fused), results(oracle)
 
 
-class TestChunkGrads:
-    def test_single_chunk_equals_full_gradient(self):
-        # flash_chunk_grads with the GLOBAL lse/delta over one chunk that
-        # IS the whole sequence must equal the full attention gradient
-        from deeplearning_tpu.ops.pallas.flash_attention import (
-            flash_attention_with_lse, flash_chunk_grads)
-        rng = np.random.default_rng(7)
-        b, h, n, d = 1, 2, 96, 16      # not a block multiple → padded
-        q, k, v, do = (jnp.asarray(rng.normal(0, 1, (b, h, n, d)),
-                                   jnp.float32) for _ in range(4))
-        out, lse = flash_attention_with_lse(q, k, v)
-        delta = jnp.sum(do * out, axis=-1)
-        dq, dk, dv = flash_chunk_grads(q, k, v, do, lse, delta)
+def _chunk_case():
+    """``flash_chunk_grads`` over one chunk that is the whole sequence (96
+    tokens: padded to a block), with the GLOBAL logsumexp and delta, against
+    the full attention's gradient; its gradients come back in float32."""
+    rng = np.random.default_rng(7)
+    b, h, n, d = 1, 2, 96, 16
+    q, k, v, do = (jnp.asarray(rng.normal(0, 1, (b, h, n, d)), jnp.float32)
+                   for _ in range(4))
+    out, lse = fa.flash_attention_with_lse(q, k, v)
+    grads = fa.flash_chunk_grads(q, k, v, do, lse, jnp.sum(do * out, -1))
+    assert all(g.dtype == jnp.float32 for g in grads)
+    names = ("dq", "dk", "dv")
 
-        def ref_loss(q, k, v):
-            s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * (d ** -0.5)
-            p = jax.nn.softmax(s, axis=-1)
-            return jnp.sum(jnp.einsum("bhqk,bhkd->bhqd", p, v) * do)
+    def ref_loss(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * (d ** -0.5)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.sum(jnp.einsum("bhqk,bhkd->bhqd", p, v) * do)
+    return (dict(zip(names, grads)),
+            dict(zip(names, jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v))))
 
-        rq, rk, rv = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
-        np.testing.assert_allclose(np.asarray(dq), np.asarray(rq),
-                                   atol=1e-4, rtol=1e-4)
-        np.testing.assert_allclose(np.asarray(dk), np.asarray(rk),
-                                   atol=1e-4, rtol=1e-4)
-        np.testing.assert_allclose(np.asarray(dv), np.asarray(rv),
-                                   atol=1e-4, rtol=1e-4)
+
+# blocks smaller than the sequence, query blocks and key blocks of different
+# sizes both ways, so that each key block's run of query blocks and the dQ
+# sum across key blocks matter
+BACKWARD_CASES = {
+    "causal": functools.partial(_vjp_case, 1, 4, 4, 128, 32, block_q=32,
+                                block_k=16),
+    "causal_key_blocks_wider": functools.partial(
+        _vjp_case, 1, 2, 2, 128, 32, block_q=16, block_k=32),
+    # a window of 40 ends inside a block of 16 and of 32
+    "window_mid_block": functools.partial(
+        _vjp_case, 1, 4, 4, 128, 32, window=40, block_q=32, block_k=16),
+    # Granite 4.0-H's core: heads of 64, 4 query heads a key/value head,
+    # scores times 0.015625
+    "grouped_g4_width64": functools.partial(
+        _vjp_case, 1, 8, 2, 128, 64, sm_scale=0.015625, block_q=32,
+        block_k=16),
+    # Mellum2's sliding core: 8 query heads a key/value head, a window
+    "grouped_g8_window": functools.partial(
+        _vjp_case, 1, 8, 1, 128, 32, window=40, block_q=32, block_k=32),
+    # not causal, 197 tokens: 59 padded keys masked by ``kv_len``
+    "padded_keys": functools.partial(_vjp_case, 1, 2, 2, 197, 64,
+                                     causal=False),
+    "chunk_grads_f32": _chunk_case,
+}
+
+
+@pytest.mark.parametrize("case", sorted(BACKWARD_CASES))
+def test_the_backward_kernel_matches_the_oracle(case):
+    """The one backward kernel's dq, dk and dv (and the forward kernel's
+    output), interpreted, against the plain mathematics: float32 throughout,
+    so the two differ by the order of their sums alone, within 1e-5 of the
+    largest value."""
+    kernel, oracle = BACKWARD_CASES[case]()
+    assert kernel.keys() == oracle.keys()
+    for name, b in oracle.items():
+        a = kernel[name]
+        assert a.shape == b.shape, name
+        assert float(jnp.abs(a - b).max()) <= 1e-5 * float(
+            jnp.abs(b).max()), name
 
 
 def _count_pallas_calls(traced) -> int:
@@ -146,7 +175,7 @@ def _count_pallas_calls(traced) -> int:
 class TestKeptAcrossRemat:
     """Under a ``jax.checkpoint`` whose policy saves the two names
     ``_flash_fwd`` places (the decoder skeleton's ``remat_block``), the
-    backward pass holds the two backward kernels and no second forward
+    backward pass holds the one backward kernel and no second forward
     kernel; the gradients are the plain checkpoint's bit for bit."""
 
     # a call's keywords and its key/value heads under 3 query heads
@@ -171,8 +200,8 @@ class TestKeptAcrossRemat:
         grads = {}
         for name, fn, calls in (
                 ("kept", jax.checkpoint(
-                    loss, policy=decoder.KEEP_ATTENTION_CORE), 3),
-                ("plain", jax.checkpoint(loss), 4)):
+                    loss, policy=decoder.KEEP_ATTENTION_CORE), 2),
+                ("plain", jax.checkpoint(loss), 3)):
             grad = jax.grad(fn, argnums=(0, 1, 2))
             assert _count_pallas_calls(
                 jax.make_jaxpr(grad)(q, k, v)) == calls, name
@@ -189,34 +218,8 @@ class TestKeptAcrossRemat:
 
 class TestCausalCoreAtHeadWidth64:
     """The decoder's causal kernels at a head width of 64, half a lane tile
-    (Granite 4.0-H's NoPE attention: 32 query heads over 8 key/value heads,
-    scores times 0.015625), interpreted, against ``causal_attention_lax``:
-    forward, ``dq``, ``dk`` and ``dv``, blocks smaller than the sequence so
-    that the causal key loop's end matters. float32 throughout, so the kernels
-    differ from the oracle by the order of their sums alone: 1e-5 of the
-    largest output, 1e-4 of the largest gradient."""
-
-    @pytest.mark.parametrize("heads", [(4, 4), (8, 2)], ids=["h4kv4", "h8kv2"])
-    def test_kernels_match_the_lax_oracle(self, monkeypatch, heads):
-        monkeypatch.setattr(fa, "CAUSAL_BLOCK_Q", 32)
-        monkeypatch.setattr(fa, "CAUSAL_BLOCK_K", 16)
-        h, kv = heads
-        q = rand_qkv(b=1, h=h, n=128, d=64, seed=5)[0]
-        k, v = (x[:, :kv] for x in rand_qkv(b=1, h=h, n=128, d=64, seed=6)[1:])
-
-        def loss(path):
-            return lambda q, k, v: jnp.sum(jnp.sin(fa.causal_attention(
-                q, k, v, 0.015625, path)))
-        out = fa.causal_attention(q, k, v, 0.015625, "fused")
-        want = fa.causal_attention(q, k, v, 0.015625, "lax")
-        assert out.shape == (1, h, 128, 64)
-        assert float(jnp.abs(out - want).max()) <= 1e-5 * float(
-            jnp.abs(want).max())
-        for a, b in zip(jax.grad(loss("fused"), (0, 1, 2))(q, k, v),
-                        jax.grad(loss("lax"), (0, 1, 2))(q, k, v)):
-            assert a.shape == b.shape
-            assert float(jnp.abs(a - b).max()) <= 1e-4 * float(
-                jnp.abs(b).max())
+    (Granite 4.0-H's NoPE attention; its gradients are a case of
+    ``test_the_backward_kernel_matches_the_oracle``)."""
 
     def test_the_path_takes_half_tile_heads(self, monkeypatch):
         monkeypatch.setattr(fa, "interpret_mode", lambda: False)
